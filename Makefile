@@ -7,7 +7,7 @@
 
 PY ?= python
 
-.PHONY: test test-fast gate bench-smoke dryrun lint
+.PHONY: test test-fast gate bench-smoke dryrun lint chip-smoke
 
 # Fast developer loop: skips the subprocess-gang / multi-minute tests.
 test-fast:
@@ -23,8 +23,9 @@ test:
 lint:
 	$(PY) -m polyaxon_tpu.analysis --no-state
 
-# Bench sanity on CPU: the script must run end-to-end and print its JSON
-# line (no TPU required — the CPU fallback path exercises all the code).
+# Bench sanity on CPU: the script must run end-to-end at its toy sizes and
+# print its JSON line.  A check of the script, not a measurement: nothing it
+# prints on the CPU is a device metric.
 bench-smoke:
 	JAX_PLATFORMS=cpu $(PY) bench.py
 
@@ -32,6 +33,12 @@ bench-smoke:
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 		$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+
+# On a machine with a TPU (from a sandbox: `chiprun -- python chip_smoke.py`):
+# the main path — lm_train and lm_server gangs through the Orchestrator — at
+# the full 671M widths.  Fails, and prints no result, when JAX finds no TPU.
+chip-smoke:
+	$(PY) chip_smoke.py
 
 gate: lint test bench-smoke dryrun
 	@echo "GATE PASSED: lint clean, full suite green, bench smoke ok, dryrun ok"

@@ -239,10 +239,11 @@ def _make_lm_handler(engine, cfg, meta: dict, log=lambda line: None):
             if self.path not in ("/healthz", "/"):
                 return self._error(404, "not_found", "not found")
             stats = engine.stats()
+            failed = stats["state"] == "failed"
             self._json(
-                200,
+                503 if failed else 200,
                 {
-                    "ok": True,
+                    "ok": not failed,
                     "model": {
                         "n_params": cfg.n_params,
                         "vocab_size": cfg.vocab_size,
@@ -251,8 +252,11 @@ def _make_lm_handler(engine, cfg, meta: dict, log=lambda line: None):
                     },
                     # "warming" until the start()-time warmup has
                     # pre-compiled the whole bucket family; LBs should
-                    # gate traffic on state == "ready".
+                    # gate traffic on state == "ready".  "failed" (503)
+                    # when the warmup raised — the process is on its way
+                    # out with a non-zero exit.
                     "state": stats["state"],
+                    "start_error": stats.get("start_error"),
                     "engine": {
                         "slots": stats["slots"],
                         "slots_active": stats["slots_active"],
@@ -380,6 +384,28 @@ def _make_lm_handler(engine, cfg, meta: dict, log=lambda line: None):
             self._json(200, payload)
 
     return Handler
+
+
+def serve_engine(server, engine) -> None:
+    """``server.serve_forever()`` for an engine-backed HTTP server, except
+    that a failed engine start ends it: the readiness gate never opens,
+    the server shuts down, and the caller gets the start error — so the
+    process exits non-zero instead of sitting in ``warming`` forever."""
+    import threading
+
+    def _watch():
+        if not engine.wait_ready() and engine.start_error is not None:
+            server.shutdown()
+
+    threading.Thread(target=_watch, name="engine-start-watch", daemon=True).start()
+    try:
+        server.serve_forever()
+    finally:
+        engine.stop()
+    if engine.start_error is not None:
+        raise RuntimeError(
+            f"serving engine failed to start: {engine.start_error}"
+        )
 
 
 def lm_server(ctx: Context) -> None:
@@ -648,10 +674,7 @@ def lm_server(ctx: Context) -> None:
         f"on {host}:{port}"
         + (f" (checkpoint step {step})" if step is not None else " (random init)")
     )
-    try:
-        server.serve_forever()
-    finally:
-        engine.stop()
+    serve_engine(server, engine)
 
 
 def output_server(ctx: Context) -> None:

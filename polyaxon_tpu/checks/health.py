@@ -3,9 +3,11 @@
 Parity: reference ``checks/`` (postgres/redis/rabbitmq/disk/memory probes +
 per-service worker round-trips, ``checks/worker.py:14-40``) surfaced at
 ``/status`` (``api/index/status.py``).  TPU-native: the moving parts are
-the sqlite registry, the task bus, the store filesystem, and the
-accelerator backend — each gets a probe; the report is the ``/status``
-payload.
+the sqlite registry, the task bus and the store filesystem — each gets a
+probe; the report is the ``/status`` payload.  No probe here touches jax:
+the control plane must never initialise an accelerator backend (a chip
+belongs to one process, and that process is a gang worker — which
+verifies its own devices, ``runtime/worker.py:_verify_devices``).
 """
 
 from __future__ import annotations
@@ -61,13 +63,16 @@ def check_heartbeats(orch) -> Tuple[bool, str]:
 
 
 def check_compile_cache(orch) -> Tuple[bool, str]:
-    """Persistent compile cache readiness: the per-layout cache dir must
-    be creatable and writable (workers of every gang root their cache
-    there).  Whether THIS process enabled it is diagnostic only — the
+    """Persistent compile cache readiness: the cache dir (where
+    ``JAX_COMPILATION_CACHE_DIR`` places it, else the fixed path in the
+    checkout — workers inherit the same rule) must be creatable and
+    writable.  Whether THIS process enabled it is diagnostic only — the
     control plane never compiles; workers arm it at boot."""
-    from polyaxon_tpu.runtime.compilecache import cache_status
+    from pathlib import Path
 
-    cache_dir = orch.layout.compile_cache_dir
+    from polyaxon_tpu.runtime import compilecache
+
+    cache_dir = Path(compilecache.cache_dir())
     try:
         cache_dir.mkdir(parents=True, exist_ok=True)
     except OSError as e:
@@ -78,7 +83,7 @@ def check_compile_cache(orch) -> Tuple[bool, str]:
         entries = sum(1 for _ in cache_dir.iterdir())
     except OSError:
         entries = 0
-    st = cache_status()
+    st = compilecache.cache_status()
     local = (
         f"enabled at {st.cache_dir}"
         if st.enabled
@@ -253,19 +258,6 @@ def check_static_analysis(orch) -> Tuple[bool, str]:
     )
 
 
-def check_devices(orch) -> Tuple[bool, str]:
-    """Accelerator visibility — only meaningful in-process on a worker/bench
-    host; the control plane itself may legitimately be CPU-only."""
-    try:
-        import jax
-
-        n = jax.local_device_count()
-        kind = jax.devices()[0].device_kind
-        return True, f"{n}x {kind}"
-    except Exception as e:
-        return False, f"no accelerator backend: {e}"
-
-
 CHECKS: Dict[str, Callable] = {
     "registry": check_registry,
     "bus": check_bus,
@@ -280,13 +272,10 @@ CHECKS: Dict[str, Callable] = {
 }
 
 
-def run_health_checks(orch, include_devices: bool = False) -> Dict[str, Any]:
-    checks = dict(CHECKS)
-    if include_devices:
-        checks["devices"] = check_devices
+def run_health_checks(orch) -> Dict[str, Any]:
     results = {}
     healthy = True
-    for name, fn in checks.items():
+    for name, fn in CHECKS.items():
         try:
             ok, detail = fn(orch)
         except Exception as e:  # a probe crashing is itself a failure

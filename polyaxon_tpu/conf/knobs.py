@@ -109,15 +109,6 @@ _ALL: List[Knob] = [
     Knob("POLYAXON_TPU_SERVICE_PORT", "str", "",
          "dispatch-time allocated port for kind:service gangs",
          "gang-env"),
-    # -- persistent XLA compile cache --------------------------------------
-    Knob("POLYAXON_TPU_COMPILE_CACHE", "bool", True,
-         "persistent XLA compile cache master switch", "compile-cache"),
-    Knob("POLYAXON_TPU_COMPILE_CACHE_DIR", "str", "",
-         "compile cache directory (spawner-resolved from the store "
-         "layout; also part of the gang env contract)", "compile-cache"),
-    Knob("POLYAXON_TPU_COMPILE_CACHE_MIN_COMPILE_S", "float", 0.0,
-         "only persist compiles at least this slow (0 = everything)",
-         "compile-cache"),
     # -- tracing / ledger ---------------------------------------------------
     Knob("POLYAXON_TPU_TRACE_SAMPLE", "float", 1.0,
          "span sampling rate for normal spans", "tracing"),

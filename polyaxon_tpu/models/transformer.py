@@ -235,16 +235,16 @@ def _flash_attention(q, k, v, block: int = 1024):
     sequence): measured 1.9x the jax-bundled pallas kernel in full train
     steps at T=8192 on v5e.
     """
-    from polyaxon_tpu.parallel.flash import _on_tpu, flash_attention
+    from polyaxon_tpu.parallel.flash import flash_attention, pallas_interpret
 
-    cfg = (q.shape[-1] ** -0.5, block, block, not _on_tpu())
+    cfg = (q.shape[-1] ** -0.5, block, block, pallas_interpret())
     return flash_attention(cfg, q, k, v)
 
 
 def _platform_is_tpu() -> bool:
-    from polyaxon_tpu.parallel.flash import _on_tpu
+    from polyaxon_tpu.parallel.flash import on_tpu
 
-    return _on_tpu()
+    return on_tpu()
 
 
 def _use_flash(

@@ -142,7 +142,7 @@ def sample_tpu_utilization() -> Dict[str, float]:
     gpustat analogue — reference ``monitor_resources/monitor.py:30-34``
     polled NVML; on TPU-VMs the equivalent is libtpu's metrics endpoint,
     which ``tpu-info`` wraps).  Gated: returns {} wherever the library or
-    the endpoint is absent (CPU test boxes, tunneled single-chip dev), so
+    the endpoint is absent (CPU test boxes), so
     the sampler composes it unconditionally."""
     out: Dict[str, float] = {}
     try:

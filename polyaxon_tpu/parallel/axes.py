@@ -93,11 +93,8 @@ def with_logical_constraint(
     from jax.sharding import NamedSharding, PartitionSpec
 
     if mesh is None:
-        try:
-            mesh = jax.sharding.get_abstract_mesh()  # jax>=0.4.35
-        except Exception:
-            mesh = None
-        if mesh is None or getattr(mesh, "empty", False):
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
             return x
     spec = logical_to_spec(axes, rules, dict(getattr(mesh, "shape", {}) or {}))
     if getattr(mesh, "_any_axis_manual", False):  # inside shard_map

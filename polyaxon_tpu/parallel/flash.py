@@ -17,9 +17,12 @@ full cycle, while ``dq`` accumulates locally.  Per-block gradients are two
 pallas kernels (dq-pass and dk/dv-pass) using the saved ``lse`` and the
 ``delta = rowsum(do * o)`` trick, so backward memory is O(T_local) too.
 
-Off-TPU the kernels run in pallas interpret mode — numerically exact and
-mesh-compatible, which is how the 8-device virtual-CPU suite verifies ring
-+flash numerics and how ``dryrun_multichip`` validates the sharded path.
+On the ``cpu`` backend the kernels run in pallas interpret mode —
+numerically exact and mesh-compatible, which is how the 8-device
+virtual-CPU suite verifies ring+flash numerics and how
+``dryrun_multichip`` validates the sharded path.  On ``tpu`` they compile
+through Mosaic; any other backend is an error (:func:`pallas_interpret`),
+so a kernel never runs interpreted without anyone asking for it.
 """
 
 from __future__ import annotations
@@ -32,22 +35,61 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from polyaxon_tpu.exceptions import RuntimeLayerError
+
 _NEG_BIG = -1e30  # mask value; finite so masked rows stay NaN-free
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
+class FlashTilingError(RuntimeLayerError):
+    """A sequence length the flash kernels cannot tile for the TPU."""
+
+
+def on_tpu() -> bool:
+    """Whether the default backend is the TPU (``"auto"`` attention picks
+    the flash kernels there).  A backend that cannot be read raises."""
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """Whether the pallas kernels run interpreted: only on ``cpu``.  On
+    ``tpu`` they compile; anything else has no flash path."""
+    backend = jax.default_backend()
+    if backend == "tpu":
         return False
+    if backend == "cpu":
+        return True
+    raise RuntimeLayerError(
+        f"the flash kernels compile on 'tpu' and are interpreted on 'cpu'; "
+        f"the default backend is {backend!r}"
+    )
 
 
 def _pick_block(t: int, want: int) -> int:
-    """Largest divisor of ``t`` that is <= want (prefers powers of two)."""
-    b = min(want, t)
-    while t % b:
-        b -= 1
-    return max(b, 1)
+    """The tile edge for an axis of length ``t``: a tile the Pallas TPU
+    lowering accepts, or a :class:`FlashTilingError` at trace time.
+
+    The lowering wants a block's second-to-last dim divisible by 8 unless
+    the block spans the whole axis (the last dims here are ``head_dim``
+    and the 128-lane row statistics, whole or aligned by construction).
+    So: the whole axis when it fits in ``want``, else the largest divisor
+    of ``t`` that is <= ``want`` and a multiple of 128 (MXU- and
+    lane-aligned score tiles), else one that is a multiple of 8.  The
+    interpreter on the CPU would take any divisor; the same rule holds
+    there so a shape that passes the CPU suite lowers on the chip.
+    """
+    if t % 8 == 0:
+        if t <= want:
+            return t
+        for step in (128, 8):
+            for b in range(want - want % step, 0, -step):
+                if t % b == 0:
+                    return b
+    raise FlashTilingError(
+        f"flash attention cannot tile a sequence axis of length {t}: the "
+        f"TPU lowering needs a tile edge <= {want} that divides it and is "
+        f"a multiple of 8 — pad the sequence to a multiple of 8 or set "
+        f"attention_impl=dense"
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +173,7 @@ def flash_block_fwd(
     share a global offset (the ring's diagonal block).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
     BH, Tq, d = q.shape
     Tk = k.shape[1]
     bq = _pick_block(Tq, block_q)
@@ -165,6 +207,7 @@ def flash_block_fwd(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse_pad[:, :, 0]
 
@@ -271,7 +314,7 @@ def flash_block_bwd(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Gradients for one block pair: returns ``(dq, dk, dv)`` float32."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = pallas_interpret()
     BH, Tq, d = q.shape
     Tk = k.shape[1]
     bq = _pick_block(Tq, block_q)
@@ -297,6 +340,7 @@ def flash_block_bwd(
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse128, delta128)[0]
 
     # dk/dv pass: grid iterates q blocks innermost for each k block.
@@ -319,6 +363,7 @@ def flash_block_bwd(
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse128, delta128)
     return dq, dk, dv
 

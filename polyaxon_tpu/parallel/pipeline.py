@@ -33,7 +33,6 @@ def _pp_body(
     aux_fn: Any = None,
     batch_axis_names: Tuple[str, ...] = (),
     stage_ids: Any = None,
-    unroll: bool = False,
 ):
     """Per-device GPipe schedule. x: [B_local, T, D]; layers: local stages.
 
@@ -43,22 +42,9 @@ def _pp_body(
     body on stale state and must not pollute the sum.
 
     ``stage_ids`` (optional [1] int array, P(axis)-sharded from a global
-    arange) replaces ``lax.axis_index``: under a PARTIAL-manual shard_map
-    the old jax line lowers axis_index to an XLA PartitionId op, which the
-    SPMD partitioner rejects for the remaining auto axes.
-
-    ``unroll`` statically unrolls the schedule and the per-stage layer
-    scan (Python loops, no ``while`` in the HLO), and routes the
-    stage→stage hop through ``psum`` instead of ``ppermute``.  Both are
-    required on the old jax line's PARTIAL-manual path: the transpose of
-    any while loop leaves its scalar carries ``{replicated}`` amid
-    manual-subgroup neighbors, and XLA's sharding propagation never
-    assigns a manual-subgroup sharding to a ``collective-permute`` —
-    either way the SPMD partitioner fatals on the mix.  The psum hop
-    all-reduces a one-hot-stacked send ([S, ...]) and picks slot
-    stage-1 locally: S× the ppermute payload, acceptable at real stage
-    counts.  Tick count is M + S - 1 and stages hold L/S layers, so the
-    unrolled body stays small at realistic microbatch counts.
+    arange) replaces ``lax.axis_index`` for the PARTIAL-manual caller
+    (``pipeline_scan_composed``), which hands every stage its index as
+    data instead of asking the partitioner for it.
     """
     S = lax.psum(1, axis)
     stage = lax.axis_index(axis) if stage_ids is None else stage_ids[0]
@@ -72,24 +58,13 @@ def _pp_body(
             out, aux = block(c, pos, layer)
             return out, (aux_fn(aux) if aux_fn is not None else 0.0)
 
-        if unroll:
-            n_local = jax.tree.leaves(layers)[0].shape[0]
-            out, auxes = inp, []
-            for li in range(n_local):
-                out, a = scan_body(out, jax.tree.map(lambda w: w[li], layers))
-                auxes.append(a)
-            layer_aux = jnp.stack(auxes) if aux_fn is not None else None
-        else:
-            out, layer_aux = lax.scan(scan_body, inp, layers)
+        out, layer_aux = lax.scan(scan_body, inp, layers)
         return out, jnp.mean(layer_aux) if aux_fn is not None else 0.0
 
     outputs = jnp.zeros_like(mb)
     state = jnp.zeros_like(mb[0])
-    # The aux rides as shape [1], never a true scalar: old-jax shard_map
-    # mishandles rank-0 values crossing the manual boundary under AD (its
-    # scalar-residual promotion loses track through partial eval, and the
-    # transpose then stages a rank-0 cotangent with sharded out-names).
-    # A singleton axis sidesteps the whole class; callers squeeze it off.
+    # The aux rides as shape [1] across the manual boundary; callers
+    # squeeze the singleton axis off.
     aux_acc = jnp.zeros((1,), jnp.float32)
 
     def tick(i, carry):
@@ -110,27 +85,12 @@ def _pp_body(
         cur = lax.dynamic_index_in_dim(outputs, jc, 0, keepdims=False)
         val = jnp.where((stage == S - 1) & (j >= 0), out, cur)
         outputs = lax.dynamic_update_index_in_dim(outputs, val, jc, 0)
-        if unroll:
-            basis = (jnp.arange(S) == stage).astype(jnp.float32)
-            stacked = lax.psum(
-                basis.reshape((S,) + (1,) * out.ndim)
-                * out[None].astype(jnp.float32),
-                axis,
-            )
-            state = lax.dynamic_index_in_dim(
-                stacked, (stage - 1) % S, 0, keepdims=False
-            ).astype(out.dtype)
-        else:
-            state = lax.ppermute(out, axis, perm)
+        state = lax.ppermute(out, axis, perm)
         return outputs, state, aux_acc
 
-    carry = (outputs, state, aux_acc)
-    if unroll:
-        for i in range(n_micro + S - 1):
-            carry = tick(i, carry)
-        outputs, _, aux_acc = carry
-    else:
-        outputs, _, aux_acc = lax.fori_loop(0, n_micro + S - 1, tick, carry)
+    outputs, _, aux_acc = lax.fori_loop(
+        0, n_micro + S - 1, tick, (outputs, state, aux_acc)
+    )
     # Only the last stage holds real outputs; broadcast over the pipeline
     # axis so downstream (final norm + unembed) sees replicated activations.
     # The psum rides f32: a bf16 all-reduce over a manual axis inside a
@@ -186,11 +146,6 @@ def pipeline_scan_composed(
         )
     layer_spec = jax.tree.map(lambda _: P(axis), stacked_layers)
     x_dtype = x.dtype
-    # Old jax: the transpose of ANY while loop (fori_loop/scan) inside a
-    # partial-manual region leaves scalar loop carries {replicated} amid
-    # manual-subgroup neighbors and the SPMD partitioner fatals — unroll
-    # the schedule statically there.  New jax handles whiles fine.
-    unroll = not hasattr(jax, "shard_map")
 
     def body_f32(x32, positions, layers, stage_ids):
         # The region boundary rides f32: XLA CPU hard-crashes on a bf16
@@ -210,13 +165,10 @@ def pipeline_scan_composed(
             # already a full-batch value, no pmean over data needed.
             batch_axis_names=(),
             stage_ids=stage_ids,
-            unroll=unroll,
         )
         return out.astype(jnp.float32), aux
 
-    from polyaxon_tpu.parallel.shmap import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body_f32,
         mesh=mesh,
         in_specs=(P(), P(), layer_spec, P(axis)),
@@ -252,8 +204,6 @@ def pipeline_scan(
     """
     from jax.sharding import PartitionSpec as P
 
-    from polyaxon_tpu.parallel.shmap import shard_map
-
     n_stages = mesh.shape[axis]
     n_layers = jax.tree.leaves(stacked_layers)[0].shape[0]
     if n_layers % n_stages:
@@ -282,7 +232,7 @@ def pipeline_scan(
         if isinstance(batch_axes, str)
         else tuple(a for a in (batch_axes or ()) if a in mesh.shape)
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _pp_body,
             block=block,

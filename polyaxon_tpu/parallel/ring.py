@@ -76,13 +76,12 @@ def ring_attention_sharded(
 
     ``impl`` selects the per-shard body: ``"flash"`` runs the pallas flash
     kernel per ring block (O(T_local) memory — scores never leave VMEM;
-    interpret mode off-TPU), ``"dense"`` the jnp blockwise body, ``"auto"``
-    flash on TPU and dense elsewhere.
+    interpreted on the cpu backend only), ``"dense"`` the jnp blockwise
+    body, ``"auto"`` flash on TPU and dense elsewhere.
     """
     from jax.sharding import PartitionSpec as P
 
-    from polyaxon_tpu.parallel.flash import _on_tpu
-    from polyaxon_tpu.parallel.shmap import shard_map
+    from polyaxon_tpu.parallel.flash import on_tpu, pallas_interpret
 
     if q.shape[2] % k.shape[2]:
         raise ValueError(
@@ -90,12 +89,12 @@ def ring_attention_sharded(
             f"({k.shape[2]}) for grouped-query attention"
         )
     if impl == "auto":
-        impl = "flash" if _on_tpu() else "dense"
+        impl = "flash" if on_tpu() else "dense"
     if impl == "flash":
         from polyaxon_tpu.parallel.flash import ring_flash_attention
 
         d = q.shape[-1]
-        cfg = (seq_axis, d**-0.5, block_q, block_k, not _on_tpu())
+        cfg = (seq_axis, d**-0.5, block_q, block_k, pallas_interpret())
         body = partial(ring_flash_attention, cfg)
     elif impl == "dense":
         # The dense blockwise body is plain MHA; broadcast GQA KV heads to
@@ -110,7 +109,7 @@ def ring_attention_sharded(
         raise ValueError(f"Unknown ring attention impl {impl!r}")
 
     spec = P(batch_axes, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -23,7 +23,7 @@ from typing import Tuple, Union
 import jax
 from jax import lax
 
-from polyaxon_tpu.parallel.flash import _on_tpu, flash_attention
+from polyaxon_tpu.parallel.flash import flash_attention, pallas_interpret
 
 
 def _ulysses_body(q, k, v, *, axis_name, cfg):
@@ -56,8 +56,6 @@ def ulysses_attention_sharded(
     and H divisible by the axis size."""
     from jax.sharding import PartitionSpec as P
 
-    from polyaxon_tpu.parallel.shmap import shard_map
-
     n = mesh.shape[seq_axis]
     H = q.shape[2]
     if H % n:
@@ -65,9 +63,9 @@ def ulysses_attention_sharded(
             f"Ulysses needs heads ({H}) divisible by the '{seq_axis}' axis ({n})"
         )
     d = q.shape[-1]
-    cfg = (d**-0.5, block_q, block_k, not _on_tpu())
+    cfg = (d**-0.5, block_q, block_k, pallas_interpret())
     spec = P(batch_axes, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_body, axis_name=seq_axis, cfg=cfg),
         mesh=mesh,
         in_specs=(spec, spec, spec),

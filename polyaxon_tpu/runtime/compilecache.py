@@ -1,33 +1,29 @@
-"""Persistent XLA compile cache: cold-start elimination for workers.
+"""Persistent XLA compile cache: one directory, placed from outside.
 
-The goodput ledger (PR 5) attributes ``xla_compile_s`` per run, and it
-shows every gang member, serving replica, and hpsearch trial paying the
-full XLA compile bill fresh — pure overhead, and for short trials the
-dominant cost.  This module wires JAX's persistent compilation cache
-into worker startup, rooted at a per-:class:`StoreLayout` shared
-directory (``<base_dir>/compile_cache``) so gang members and successive
-runs of the same store share compiled executables: a restarted run comes
-back warm.
+Every gang member, serving replica and hpsearch trial would otherwise
+pay the full XLA compile bill fresh (the goodput ledger attributes it as
+``xla_compile_s``).  JAX's persistent compilation cache removes the
+repeat cost, and its directory is part of the cache key — a directory
+that moves never hits.  So there is exactly one rule for where it lives:
 
-Knobs (all env, spawner-propagated like ``POLYAXON_TPU_DATA_DIR``):
+- ``JAX_COMPILATION_CACHE_DIR`` set from outside → the cache is there.
+  Nothing in this package writes another directory into the environment
+  or into ``jax.config``; child processes inherit the variable.
+- not set → :data:`DEFAULT_CACHE_DIR`, one fixed git-ignored path at the
+  root of the checkout.  Not a function of ``--base-dir``, a temporary
+  name, a pid or the time.
 
-- ``POLYAXON_TPU_COMPILE_CACHE`` — ``0``/``false``/``off`` disables
-  (default on).
-- ``POLYAXON_TPU_COMPILE_CACHE_DIR`` — cache directory; the spawner
-  resolves it from the store layout, hand-launched workers derive it
-  from the run dir.
-- ``POLYAXON_TPU_COMPILE_CACHE_MIN_COMPILE_S`` — only persist compiles
-  that took at least this long (default 0: persist everything; the CPU
-  smoke configs compile in milliseconds and cross-process reuse is the
-  point).
+JAX's own variables stay the interface for everything else
+(``JAX_ENABLE_COMPILATION_CACHE=0`` turns the cache off,
+``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` sets a persist
+threshold); this module only defaults the thresholds to "persist every
+compile" where the outside left them unset — the CPU smoke
+configurations compile in milliseconds and cross-process reuse is the
+point.
 
-Same graceful-degradation contract as the ledger's ``jax.monitoring``
-hooks: on JAX versions/backends without the persistent-cache API,
-:func:`enable_compile_cache` returns a no-op status carrying the reason
-(surfaced by ``checks/health.py:check_compile_cache``) and never raises.
-Never imports jax itself when it isn't already loaded — the worker
-defers the jax import deliberately, so the pre-import path arms the
-cache through env vars that jax's config reads at import time.
+Never imports jax when it isn't already loaded — the worker defers the
+jax import deliberately, so the boot path arms the cache through the
+environment jax reads at import time.
 """
 
 from __future__ import annotations
@@ -37,21 +33,31 @@ import sys
 import threading
 import time
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Any, Callable, Optional, Tuple
-
-from polyaxon_tpu.conf.knobs import knob_bool, knob_float, knob_str
 
 __all__ = [
     "CacheStatus",
+    "DEFAULT_CACHE_DIR",
+    "cache_dir",
     "enable_compile_cache",
     "cache_status",
     "aot_compile",
 ]
 
-# Knob names as module constants (tests and callers reference these).
-ENV_ENABLE = "POLYAXON_TPU_COMPILE_CACHE"
-ENV_DIR = "POLYAXON_TPU_COMPILE_CACHE_DIR"
-ENV_MIN_COMPILE_S = "POLYAXON_TPU_COMPILE_CACHE_MIN_COMPILE_S"
+#: JAX's own variable: the only way to place the cache.
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: Where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` is not set:
+#: ``<checkout>/.compile_cache`` (listed in ``.gitignore``).
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".compile_cache"
+
+#: Persist every compile unless the outside says otherwise (-1 = no
+#: entry-size floor).
+_THRESHOLD_DEFAULTS = {
+    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+    "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1",
+}
 
 
 @dataclass(frozen=True)
@@ -61,50 +67,33 @@ class CacheStatus:
     enabled: bool
     cache_dir: Optional[str]
     reason: str
-    min_compile_s: float = 0.0
 
 
 _lock = threading.Lock()
 _status: Optional[CacheStatus] = None
 
 
-def enable_compile_cache(
-    cache_dir: Optional[str] = None,
-    *,
-    min_compile_s: Optional[float] = None,
-) -> CacheStatus:
-    """Enable JAX's persistent compilation cache for this process.
+def cache_dir() -> str:
+    """The directory this process's compile cache lives in."""
+    return os.environ.get(ENV_DIR) or str(DEFAULT_CACHE_DIR)
 
-    ``POLYAXON_TPU_COMPILE_CACHE_DIR`` wins over the ``cache_dir``
-    argument (callers pass their layout-derived fallback).  Idempotent:
-    re-enabling with the same directory returns the cached status.
-    Never raises — failures come back as a disabled status with the
-    reason.
+
+def enable_compile_cache() -> CacheStatus:
+    """Arm JAX's persistent compilation cache for this process and the
+    children that inherit its environment.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` exported this only creates the
+    directory and defaults the persist thresholds; the variable and
+    ``jax_compilation_cache_dir`` are left exactly as the outside set
+    them.  Without it the fixed default is exported (before jax is
+    imported on the worker boot path, so jax reads it at import).
+    An unusable directory comes back as a disabled status with the
+    reason (surfaced by ``checks/health.py:check_compile_cache``).
     """
     global _status
     with _lock:
-        if not knob_bool(ENV_ENABLE):
-            _status = CacheStatus(
-                False, None, f"disabled by {ENV_ENABLE}"
-            )
-            return _status
-        resolved = knob_str(ENV_DIR) or cache_dir
-        if not resolved:
-            _status = CacheStatus(
-                False,
-                None,
-                f"no cache dir (set {ENV_DIR} or pass cache_dir)",
-            )
-            return _status
-        resolved = str(resolved)
-        if (
-            _status is not None
-            and _status.enabled
-            and _status.cache_dir == resolved
-        ):
-            return _status
-        if min_compile_s is None:
-            min_compile_s = knob_float(ENV_MIN_COMPILE_S)
+        placed = bool(os.environ.get(ENV_DIR))
+        resolved = cache_dir()
         try:
             os.makedirs(resolved, exist_ok=True)
             if not os.access(resolved, os.W_OK):
@@ -114,57 +103,38 @@ def enable_compile_cache(
                 False, resolved, f"cache dir {resolved} unusable: {e}"
             )
             return _status
-
-        # Arm through env first: jax reads these at import, so workers
-        # that haven't paid the jax import yet (the common boot path)
-        # get the cache for free on first use.  min_entry_size -1 means
-        # "persist regardless of size" — the compile-time threshold is
-        # the only gate we expose.
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = resolved
-        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = str(
-            min_compile_s
-        )
-        os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
-
+        for var, default in _THRESHOLD_DEFAULTS.items():
+            os.environ.setdefault(var, default)
+        if not placed:
+            os.environ[ENV_DIR] = resolved
         if "jax" in sys.modules:
-            # Already-imported jax ignores env: go through the config
-            # API, then reset the cache singleton — is_cache_used() and
-            # the backing LRUCache latch on first compile, so without
-            # the reset a process that compiled anything pre-enable
-            # would silently never read or write the cache.
-            try:
-                import jax
-                from jax._src import compilation_cache as _cc
+            # jax read its flags from the environment at import; bring an
+            # already-imported jax in line, then reset the cache singleton
+            # — it latches on first compile, so a process that compiled
+            # anything before this call would otherwise never read or
+            # write the cache.
+            import jax
+            from jax._src import compilation_cache as _cc
 
+            from polyaxon_tpu.tracking.ledger import install_compile_hooks
+
+            if not placed:
                 jax.config.update("jax_compilation_cache_dir", resolved)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs",
-                    float(min_compile_s),
-                )
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1
-                )
-                _cc.reset_cache()
-            except Exception as e:
-                _status = CacheStatus(
-                    False,
-                    resolved,
-                    f"jax persistent-cache API unavailable: {e!r}",
-                    float(min_compile_s),
-                )
-                return _status
-            # Hit/miss counters ride the same monitoring channel as the
-            # ledger's compile-seconds attribution.
-            try:
-                from polyaxon_tpu.tracking.ledger import install_compile_hooks
-
-                install_compile_hooks()
-            except Exception:
-                pass
-            reason = "enabled (config API)"
-        else:
-            reason = "armed via env (jax not imported yet)"
-        _status = CacheStatus(True, resolved, reason, float(min_compile_s))
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs",
+                float(os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"]),
+            )
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes",
+                int(os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"]),
+            )
+            _cc.reset_cache()
+            install_compile_hooks()
+        _status = CacheStatus(
+            True,
+            resolved,
+            f"placed by {ENV_DIR}" if placed else "default path in the checkout",
+        )
         return _status
 
 
@@ -184,19 +154,16 @@ def _reset_for_tests() -> None:
         _status = None
 
 
-def aot_compile(jitted: Callable, *args: Any) -> Tuple[Callable, float]:
+def aot_compile(jitted: Callable, *args: Any) -> Tuple[Any, float]:
     """AOT-compile a jitted fn: ``(executable, compile_seconds)``.
 
     The returned executable must be *called directly* — ``lower().
     compile()`` does not populate the jit dispatch cache, so calling the
-    original ``jitted`` afterwards would compile a second time.  Falls
-    back to ``(jitted, 0.0)`` wherever lowering is unavailable, so
-    callers can use the result unconditionally.  Donation declared on
-    the jit is preserved through the AOT path.
+    original ``jitted`` afterwards would compile a second time.  A
+    lowering or compile error (a Mosaic refusal, an HBM overflow)
+    propagates from here, where it happened.  Donation declared on the
+    jit is preserved through the AOT path.
     """
     t0 = time.perf_counter()
-    try:
-        compiled = jitted.lower(*args).compile()
-    except Exception:
-        return jitted, 0.0
+    compiled = jitted.lower(*args).compile()
     return compiled, time.perf_counter() - t0

@@ -33,9 +33,6 @@ class EnvVars:
     HEARTBEAT_INTERVAL = "POLYAXON_TPU_HEARTBEAT_INTERVAL"
     SEED = "POLYAXON_TPU_SEED"
     DATA_DIR = "POLYAXON_TPU_DATA_DIR"
-    #: doubles as the runtime/compilecache.py knob — the spawner writing
-    #: it IS the enablement channel, no separate plumbing.
-    COMPILE_CACHE_DIR = "POLYAXON_TPU_COMPILE_CACHE_DIR"
 
 
 @dataclass
@@ -61,9 +58,6 @@ class GangInfo:
     #: The store layout's shared data/ dir (registered datasets); the
     #: spawner resolves it so workers never re-derive layout structure.
     data_dir: Optional[str] = None
-    #: The store layout's shared compile_cache/ dir (persistent XLA
-    #: compile cache); same spawner-resolved contract as data_dir.
-    compile_cache_dir: Optional[str] = None
 
     @classmethod
     def from_env(cls, env: Optional[Dict[str, str]] = None) -> "GangInfo":
@@ -86,7 +80,6 @@ class GangInfo:
             heartbeat_interval=float(e.get(EnvVars.HEARTBEAT_INTERVAL, "5.0")),
             seed=int(seed) if seed not in (None, "") else None,
             data_dir=e.get(EnvVars.DATA_DIR) or None,
-            compile_cache_dir=e.get(EnvVars.COMPILE_CACHE_DIR) or None,
         )
 
 
@@ -108,7 +101,6 @@ def gang_env(
     heartbeat_interval: float = 5.0,
     seed: Optional[int] = None,
     data_dir: Optional[str] = None,
-    compile_cache_dir: Optional[str] = None,
 ) -> Dict[str, str]:
     """Spawner-side encoder (inverse of ``GangInfo.from_env``)."""
     env = {
@@ -132,6 +124,32 @@ def gang_env(
         env[EnvVars.SEED] = str(seed)
     if data_dir:
         env[EnvVars.DATA_DIR] = data_dir
-    if compile_cache_dir:
-        env[EnvVars.COMPILE_CACHE_DIR] = compile_cache_dir
+    return env
+
+
+#: libtpu's per-process chip grid for the chip counts one host can hold
+#: (a v5e host is a 2x2 or 2x4 tray).
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def visible_chips_env(n_chips: int, first_chip: int = 0) -> Dict[str, str]:
+    """The environment that limits one process to ``n_chips`` TPU chips
+    of its host, starting at ``first_chip``.
+
+    A chip belongs to one process at a time and libtpu claims every chip
+    it can see, so a process that should use fewer than the host holds
+    must be told which: this is what lets a ``v5e-1`` gang run on a
+    four-chip host and gives each replica of a local fleet its own chip.
+    (The platform itself is requested separately: ``JAX_PLATFORMS=tpu``,
+    by the worker from its plan, by the fleet for its replicas.)
+    """
+    env = {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(first_chip + i) for i in range(n_chips)
+        ),
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+    bounds = _CHIP_BOUNDS.get(n_chips)
+    if bounds is not None:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
     return env

@@ -33,9 +33,7 @@ def _validate_param_shapes(init_fn, param_specs, mesh_axes) -> None:
     )
     paths = [
         "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        # tree_flatten_with_path lives in tree_util on the older jax line;
-        # jax.tree.flatten_with_path only arrived later.
-        for path, _ in jax.tree_util.tree_flatten_with_path(abstract)[0]
+        for path, _ in jax.tree.flatten_with_path(abstract)[0]
     ]
     for name, leaf, spec in zip(paths, flat_shapes, flat_specs):
         for dim, entry in zip(leaf.shape, spec):
@@ -70,15 +68,6 @@ class TrainStep:
         return jax.tree.map(
             lambda x: jax.device_put(x, self.batch_sharding), batch
         )
-
-    def step_flops(self, *args: Any) -> Optional[float]:
-        """Total FLOPs of one step from XLA's cost analysis, or None where
-        the backend exposes none — the utilization ledger's measured path
-        (callers fall back to analytic estimates).  Costs one extra
-        compile: ``lower().compile()`` does not populate the jit cache."""
-        from polyaxon_tpu.tracking.ledger import compiled_flops
-
-        return compiled_flops(self.step, *args)
 
 
 def build_train_step(

@@ -51,6 +51,7 @@ import queue
 import random
 import threading
 import time
+import traceback
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -342,7 +343,8 @@ class ServingEngine:
         every prefill chunk bucket up to ``prefill_chunk``, the COW copy
         fn) at the top of the scheduler loop before serving traffic, so
         the first request never eats a compile.  ``wait_ready()`` blocks
-        on the gate; ``stats()['state']`` reports ``warming|ready``.
+        on the gate; ``stats()['state']`` reports ``warming|ready``, or
+        ``failed`` (with ``start_error``) when the warmup raised.
         With the persistent compile cache armed
         (``runtime/compilecache.py``) a restarted replica warms from
         disk instead of compiling cold.  ``False`` skips straight to
@@ -534,6 +536,10 @@ class ServingEngine:
             warmup = knob_bool("POLYAXON_TPU_SERVING_WARMUP")
         self._warmup = bool(warmup)
         self._ready = threading.Event()
+        #: Set once the warmup pass has ended either way; ``start_error``
+        #: then says whether the engine is serving or failed to start.
+        self._warm_settled = threading.Event()
+        self.start_error: Optional[str] = None
         self._warmup_total = 0
         self._warmup_done = 0
         self._warmup_s = 0.0
@@ -890,8 +896,7 @@ class ServingEngine:
         return self._verify_fns[width]
 
     def _compiled_count(self) -> int:
-        """Total compiled entries across the engine's jitted fns (0 when
-        the jax version exposes no ``_cache_size``)."""
+        """Total compiled entries across the engine's jitted fns."""
         fns = [
             self._step_fn,
             *self._chunk_fns.values(),
@@ -903,13 +908,7 @@ class ServingEngine:
             fns.append(self._export_fn)
         if self._import_fn is not None:
             fns.append(self._import_fn)
-        n = 0
-        for fn in fns:
-            try:
-                n += int(fn._cache_size())
-            except Exception:
-                pass
-        return n
+        return sum(int(fn._cache_size()) for fn in fns)
 
     def _warmup_buckets(self) -> List[int]:
         """The chunk-bucket family live traffic can mint: every
@@ -934,23 +933,17 @@ class ServingEngine:
         populate the jit dispatch cache — with arguments whose writes
         all land in the reserved trash block 0: the decode step with an
         all-inactive mask, each chunk bucket with ``length=0``, and the
-        COW copy as a trash self-copy.  Failures degrade to lazy
-        compiles (counted by the steady-state monitor) rather than
-        killing the engine; the readiness gate opens regardless.
+        COW copy as a trash self-copy.  A failure here (a compile the
+        backend refuses, an HBM overflow, a step that died after the
+        pool was donated) is a failed start: the error is recorded, the
+        readiness gate stays shut, ``stats()['state']`` reads
+        ``failed`` and the scheduler loop exits.
         """
         import jax
         import jax.numpy as jnp
 
         tracer = get_tracer()
         t0 = time.perf_counter()
-        # Warm replica boot: hydrate the prefix cache from the persisted
-        # store BEFORE the ready gate opens, so a scale-up replica's
-        # first request already walks a warm cache.  Best-effort —
-        # a missing/torn/mismatched store just boots cold.
-        try:
-            self._preload_prefixes()
-        except Exception:
-            pass
         spillers = self._host_tier is not None or bool(self.kv_persist_dir)
         buckets = self._warmup_buckets() if self._warmup else []
         widths = self._spec_widths() if self._warmup else []
@@ -970,6 +963,12 @@ class ServingEngine:
                 )
 
         try:
+            # Warm replica boot: hydrate the prefix cache from the
+            # persisted store BEFORE the ready gate opens, so a scale-up
+            # replica's first request already walks a warm cache.  (A
+            # missing/torn/mismatched store loads as nothing and boots
+            # cold; a device error importing a block is a failed start.)
+            self._preload_prefixes()
             if self._warmup:
                 with tracer.span("serving.warmup", buckets=len(buckets)):
                     self._key, sub = jax.random.split(self._key)
@@ -1039,10 +1038,10 @@ class ServingEngine:
                         )
                         jax.block_until_ready(self._pool)
                         _tick()
-        except Exception:
-            pass
-        finally:
-            self._warmup_s = time.perf_counter() - t0
+        except Exception as e:
+            traceback.print_exc()
+            self.start_error = f"{type(e).__name__}: {e}"
+        else:
             self._compiled_baseline = self._compiled_count()
             # Lazy HLO source for on-demand captures: lowering text is only
             # produced if a profile command actually fires (no extra
@@ -1054,6 +1053,9 @@ class ServingEngine:
             self._ready.set()
             if gauge is not None:
                 gauge("serving.warmup_progress", 1.0)
+        finally:
+            self._warmup_s = time.perf_counter() - t0
+            self._warm_settled.set()
 
     def _decode_hlo_text(self) -> str:
         """Lower the decode step against the engine's live shapes and
@@ -1100,9 +1102,11 @@ class ServingEngine:
     # -- public API ------------------------------------------------------------
 
     def wait_ready(self, timeout: Optional[float] = None) -> bool:
-        """Block until the warmup pass has run (or was skipped/failed);
-        True when the engine is ready to serve without compiling."""
-        return self._ready.wait(timeout)
+        """Block until the warmup pass has ended (or was skipped); True
+        when the engine is ready to serve, False on timeout or a failed
+        start (``start_error`` says which)."""
+        self._warm_settled.wait(timeout)
+        return self._ready.is_set()
 
     def start(self) -> "ServingEngine":
         if self._thread is None:
@@ -1169,11 +1173,7 @@ class ServingEngine:
                 drain.setdefault(req.id, req)
         for req in drain.values():
             if not req.done.is_set():
-                req.error = "engine stopped"
-                req.error_kind = "stopped"
-                self._finalize_trace(req, "stopped")
-                req.stream.put(None)
-                req.done.set()
+                self._fail_request(req, "engine stopped", "stopped", "stopped")
 
     def drain(self) -> None:
         """Stop admitting new requests; in-flight work runs to completion.
@@ -1225,6 +1225,10 @@ class ServingEngine:
         if trace is not None and self.trace_requests and trace.sampled:
             req.trace = _RequestTrace(trace, get_tracer().next_span_id())
         with self._cv:
+            if self.start_error is not None:
+                raise RuntimeError(
+                    f"engine failed to start: {self.start_error}"
+                )
             if self._stop.is_set():
                 raise RuntimeError("engine is stopped")
             if self._draining:
@@ -1252,11 +1256,9 @@ class ServingEngine:
                     self._queue.remove(req)
                     with self._stats_lock:
                         self._n_cancelled += 1
-                    req.error = "request cancelled"
-                    req.error_kind = "cancelled"
-                    self._finalize_trace(req, "cancelled")
-                    req.stream.put(None)
-                    req.done.set()
+                    self._fail_request(
+                        req, "request cancelled", "cancelled", "cancelled"
+                    )
                     return True
             for req in self._slot_req:
                 if (
@@ -1417,10 +1419,13 @@ class ServingEngine:
             tps = window_tokens / window_span if window_span > 0 else 0.0
             return {
                 "state": (
-                    "draining"
+                    "failed"
+                    if self.start_error is not None
+                    else "draining"
                     if self._draining
                     else "ready" if self._ready.is_set() else "warming"
                 ),
+                "start_error": self.start_error,
                 "warmup": {
                     "done": self._warmup_done,
                     "total": self._warmup_total,
@@ -1612,6 +1617,9 @@ class ServingEngine:
     def _loop(self) -> None:
         tracer = get_tracer()
         self._run_warmup()
+        if self.start_error is not None:
+            self._fail_start()
+            return
         while not self._stop.is_set():
             self._process_cancels()
             self._admit()
@@ -1680,6 +1688,31 @@ class ServingEngine:
             with self._cv:
                 if not self._queue and not self._stop.is_set():
                     self._cv.wait(timeout=0.2)
+
+    def _fail_start(self) -> None:
+        """A failed warmup serves nothing: whoever queued while warming
+        gets the start error instead of waiting on a dead scheduler."""
+        with self._cv:
+            pending = list(self._queue)
+            self._queue.clear()
+        for req in pending:
+            self._fail_request(
+                req,
+                f"engine failed to start: {self.start_error}",
+                "engine_error",
+                "failed",
+            )
+
+    def _fail_request(
+        self, req: GenerationRequest, msg: str, kind: str, outcome: str
+    ) -> None:
+        """Resolve a request that holds no slot (queued, or being drained
+        at stop): its error, its trace, and exactly ONE stream sentinel."""
+        req.error = msg
+        req.error_kind = kind
+        self._finalize_trace(req, outcome)
+        req.stream.put(None)
+        req.done.set()
 
     def _admit(self) -> None:
         """Move queued requests into free slots (queue order) and enqueue

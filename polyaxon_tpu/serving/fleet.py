@@ -121,8 +121,30 @@ class LocalServingFleet:
         self.transport = LocalExecTransport()
         self.router = router if router is not None else FleetRouter()
         self._procs: Dict[str, Any] = {}
+        #: TPU fleets only: replica name -> the one chip it was given.
+        self._chips: Dict[str, int] = {}
         self._counter = itertools.count()
         self.autoscaler: Optional[Any] = None
+
+    def _platform_env(self, name: str) -> Dict[str, str]:
+        """What pins a replica to its device.  The fleet runs on the CPU
+        only when ``JAX_PLATFORMS=cpu`` says so (``env`` or inherited);
+        otherwise every replica is its own process on its own TPU chip —
+        the lowest index no live replica holds — through the same
+        visible-chips environment the gang spawner uses.  A chip belongs
+        to one process: without this the second replica on a host would
+        sit in ``warming`` waiting for the chip the first one holds."""
+        from polyaxon_tpu.runtime.env import visible_chips_env
+
+        platform = self.env.get("JAX_PLATFORMS") or os.environ.get("JAX_PLATFORMS")
+        if platform == "cpu":
+            return {}
+        held = set(self._chips.values())
+        chip = next(i for i in itertools.count() if i not in held)
+        self._chips[name] = chip
+        # JAX_PLATFORMS=tpu: a replica without its chip fails at start-up
+        # instead of falling back to the CPU.
+        return {"JAX_PLATFORMS": "tpu", **visible_chips_env(1, first_chip=chip)}
 
     # -- lifecycle ------------------------------------------------------------
     def start(self) -> "LocalServingFleet":
@@ -163,7 +185,7 @@ class LocalServingFleet:
 
         pkg_root = str(Path(polyaxon_tpu.__file__).resolve().parent.parent)
         existing = os.environ.get("PYTHONPATH")
-        env = dict(self.env)
+        env = {**self.env, **self._platform_env(name)}
         env.setdefault(
             "PYTHONPATH",
             pkg_root + (os.pathsep + existing if existing else ""),
@@ -205,6 +227,7 @@ class LocalServingFleet:
         for ref in self._procs.values():
             ref.wait(timeout=10)
         self._procs.clear()
+        self._chips.clear()
 
     # -- fault injection -------------------------------------------------------
     def kill_replica(self, name: str) -> None:
@@ -246,6 +269,7 @@ class LocalServingFleet:
         if ref is not None:
             ref.signal(signal.SIGKILL)
             ref.wait(timeout=10)
+        self._chips.pop(name, None)
         self.router.remove_replica(name)
 
     def run_id_for(self, name: str) -> Optional[int]:

@@ -37,9 +37,16 @@ def serve(spec: dict) -> None:
     # spec parse errors don't pay for jax.
     import os
 
+    # Same persistent compile cache the gang workers arm (before the jax
+    # import, so jax reads it from the environment): a replacement or
+    # scale-up replica warms from disk instead of compiling cold.
+    from polyaxon_tpu.runtime.compilecache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
 
-    from polyaxon_tpu.builtins.services import _make_lm_handler
+    from polyaxon_tpu.builtins.services import _make_lm_handler, serve_engine
     from polyaxon_tpu.models import TransformerConfig, init_params
     from polyaxon_tpu.serving import ServingEngine
     from polyaxon_tpu.tracking.trace import get_tracer
@@ -98,11 +105,8 @@ def serve(spec: dict) -> None:
     host = str(spec.get("host", "127.0.0.1"))
     port = int(spec["port"])
     server = ThreadingHTTPServer((host, port), handler)
-    print(f"replica: serving on {host}:{port}", flush=True)
-    try:
-        server.serve_forever()
-    finally:
-        engine.stop()
+    print(f"replica: serving on {host}:{port} with {jax.devices()}", flush=True)
+    serve_engine(server, engine)
 
 
 def main(argv) -> int:

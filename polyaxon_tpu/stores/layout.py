@@ -93,19 +93,11 @@ class StoreLayout:
         return self.base_dir / "data"
 
     @property
-    def compile_cache_dir(self) -> Path:
-        """Shared persistent XLA compile cache: gang members and
-        successive runs of the same store reuse compiled executables
-        (see ``runtime/compilecache.py``)."""
-        return self.base_dir / "compile_cache"
-
-    @property
     def kv_cache_dir(self) -> Path:
         """Shared persistent prefix-KV store (``serving/kvstore.py``):
         serving replicas snapshot their hot prefix blocks here, and
-        replacement/scale-up replicas preload them during warmup — the
-        compile-cache pattern applied to KV state, so a new replica
-        boots prefix-warm as well as compile-warm."""
+        replacement/scale-up replicas preload them during warmup, so a
+        new replica boots prefix-warm as well as compile-warm."""
         return self.base_dir / "kv_cache"
 
     def run_paths(self, run_uuid: str) -> RunPaths:

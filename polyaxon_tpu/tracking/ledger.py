@@ -11,7 +11,7 @@ first-class: it decomposes a run's wall clock into named buckets
 (xla-compile, data-wait, step-compute, checkpoint-block, metric-drain,
 idle), tracks model FLOPs per step (XLA cost analysis when available,
 analytic estimates otherwise), HBM high-water marks, and XLA compile
-telemetry from ``jax.monitoring`` record hooks (no-op on older JAX).
+telemetry from ``jax.monitoring`` record hooks.
 Rows flow as typed ``ledger`` report lines through the Reporter → the
 GangWatcher ingests them into the registry's ``utilization`` table → the
 API aggregates them gang-wide as ``GET /api/v1/runs/<id>/goodput``.
@@ -37,12 +37,12 @@ __all__ = [
     "install_compile_hooks",
     "compile_telemetry",
     "compile_cache_telemetry",
-    "compiled_flops",
     "executable_flops",
     "transformer_flops_per_token",
     "conv_classifier_flops_per_image",
     "BUCKETS",
     "PEAK_FLOPS",
+    "peak_flops_per_chip",
 ]
 
 #: The wall-clock decomposition vocabulary.  Every ledger row's
@@ -57,19 +57,31 @@ BUCKETS = (
     "idle_s",
 )
 
-#: bf16 peak FLOP/s per chip by PJRT device kind (dense MXU).  Shared
-#: with ``bench.py`` so the platform's MFU and the benchmark's can never
-#: disagree about the denominator.  Absent kinds (CPU, unknown TPUs)
-#: resolve to no peak → MFU reports 0.0 rather than a made-up ratio.
+#: bf16 peak FLOP/s per chip, keyed by the PJRT ``device_kind`` the
+#: runtime reports.  Shared with ``bench.py`` and ``chip_smoke.py`` so the
+#: platform's MFU and the benchmark's can never disagree about the
+#: denominator.  Only kinds seen on hardware, each with its source:
+#:
+#: - ``"TPU v5 lite"`` — what a v5e chip reports; 197 TFLOP/s bf16
+#:   (Google Cloud documentation, "TPU v5e").
 PEAK_FLOPS = {
-    "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
 }
+
+
+def peak_flops_per_chip(platform: str, device_kind: str) -> float:
+    """The MFU denominator for one device.  The CPU has no peak (0.0: no
+    MFU is claimed there); a TPU kind missing from :data:`PEAK_FLOPS` is
+    an error, not a default."""
+    if platform == "cpu":
+        return 0.0
+    if device_kind not in PEAK_FLOPS:
+        raise KeyError(
+            f"no peak FLOP/s recorded for {platform} device kind "
+            f"{device_kind!r}: add it to tracking/ledger.py:PEAK_FLOPS "
+            f"with its source"
+        )
+    return PEAK_FLOPS[device_kind]
 
 _UNSET = object()
 
@@ -81,7 +93,7 @@ _compile_seconds = 0.0
 _compile_events = 0
 _cache_hits = 0
 _cache_misses = 0
-_hooks_installed: Optional[bool] = None  # None = not yet attempted
+_hooks_installed = False
 
 
 def install_compile_hooks() -> bool:
@@ -90,47 +102,42 @@ def install_compile_hooks() -> bool:
     Duration events under ``/jax/core/compile/`` (jaxpr trace, MLIR
     lowering, backend compile) accumulate into compile seconds; each
     ``compile_requests``/``cache_miss`` event counts one jit-cache miss.
-    Idempotent; returns False — and stays a no-op — on JAX versions
-    without the monitoring API.  Never imports jax itself: callers arm
-    the ledger from workloads that already did.
+    Idempotent.  Never imports jax itself (returns False until some
+    workload has): callers arm the ledger from workloads that already did.
     """
     global _hooks_installed
-    if _hooks_installed is not None:
-        return _hooks_installed
+    if _hooks_installed:
+        return True
     if "jax" not in sys.modules:
-        return False  # unattempted: a later start() after jax import retries
-    try:
-        from jax import monitoring
+        return False  # a later start() after the jax import retries
+    from jax import monitoring
 
-        def _on_duration(event: str, duration: float, **kw: Any) -> None:
-            if "compile" in event:
-                global _compile_seconds
-                with _compile_lock:
-                    _compile_seconds += float(duration)
+    def _on_duration(event: str, duration: float, **kw: Any) -> None:
+        if "compile" in event:
+            global _compile_seconds
+            with _compile_lock:
+                _compile_seconds += float(duration)
 
-        def _on_event(event: str, **kw: Any) -> None:
-            # With the persistent cache armed (runtime/compilecache.py)
-            # a cold compile fires BOTH compile_requests and cache_miss;
-            # counting either-or (the pre-cache behaviour) would double
-            # count, so requests carry compile_events and hit/miss feed
-            # their own counters.
-            global _compile_events, _cache_hits, _cache_misses
-            if "cache_hit" in event:
-                with _compile_lock:
-                    _cache_hits += 1
-            elif "cache_miss" in event:
-                with _compile_lock:
-                    _cache_misses += 1
-            elif "compile_requests" in event:
-                with _compile_lock:
-                    _compile_events += 1
+    def _on_event(event: str, **kw: Any) -> None:
+        # With the persistent cache armed (runtime/compilecache.py) a
+        # cold compile fires BOTH compile_requests and cache_miss, so
+        # requests carry compile_events and hit/miss feed their own
+        # counters.
+        global _compile_events, _cache_hits, _cache_misses
+        if "cache_hit" in event:
+            with _compile_lock:
+                _cache_hits += 1
+        elif "cache_miss" in event:
+            with _compile_lock:
+                _cache_misses += 1
+        elif "compile_requests" in event:
+            with _compile_lock:
+                _compile_events += 1
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        monitoring.register_event_listener(_on_event)
-        _hooks_installed = True
-    except Exception:
-        _hooks_installed = False
-    return _hooks_installed
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+    _hooks_installed = True
+    return True
 
 
 def compile_telemetry() -> Tuple[float, int]:
@@ -140,8 +147,8 @@ def compile_telemetry() -> Tuple[float, int]:
 
 
 def compile_cache_telemetry() -> Tuple[int, int]:
-    """(persistent-cache hits, misses) so far — both stay 0 when the
-    cache is disabled or the jax version emits no cache events."""
+    """(persistent-cache hits, misses) so far — both stay 0 while the
+    cache is disabled."""
     with _compile_lock:
         return _cache_hits, _cache_misses
 
@@ -153,33 +160,15 @@ def executable_flops(compiled: Any) -> Optional[float]:
 
     The free probe: callers that AOT-compiled their step anyway
     (``runtime/compilecache.aot_compile``) get the number without paying
-    a second compile.  Returns None when the object has no analysis
-    (e.g. it is still a plain jitted fn because AOT fell back)."""
-    try:
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        flops = analysis.get("flops") if hasattr(analysis, "get") else None
-        if flops is not None and float(flops) > 0:
-            return float(flops)
-    except Exception:
-        pass
+    a second compile.  Returns None where the backend's analysis holds
+    no FLOP count (callers fall back to the analytic estimates below)."""
+    analysis = compiled.cost_analysis()
+    if isinstance(analysis, (list, tuple)):
+        analysis = analysis[0] if analysis else {}
+    flops = (analysis or {}).get("flops")
+    if flops is not None and float(flops) > 0:
+        return float(flops)
     return None
-
-
-def compiled_flops(jitted: Callable, *args: Any) -> Optional[float]:
-    """Total FLOPs of one compiled call, from XLA's cost analysis.
-
-    ``jitted.lower(*args).compile()`` does NOT share the executable with
-    later ``jitted(...)`` calls — probing costs one extra compile, which
-    the compile hooks account honestly.  Returns None wherever the
-    backend exposes no analysis (callers fall back to the analytic
-    estimates below).
-    """
-    try:
-        return executable_flops(jitted.lower(*args).compile())
-    except Exception:
-        return None
 
 
 def transformer_flops_per_token(
@@ -305,17 +294,16 @@ class UtilizationLedger:
             self._compile0 = compile_telemetry()
             self._cache0 = compile_cache_telemetry()
         if "jax" in sys.modules:
-            try:
-                import jax
+            import jax
 
-                devices = jax.local_devices()
-                with self._lock:
-                    self.devices = len(devices)
-                    self.device_kind = devices[0].device_kind if devices else ""
-                    per_chip = PEAK_FLOPS.get(self.device_kind, 0.0)
-                    self.peak_flops_per_s = per_chip * len(devices)
-            except Exception:
-                pass
+            devices = jax.local_devices()
+            per_chip = peak_flops_per_chip(
+                devices[0].platform, devices[0].device_kind
+            )
+            with self._lock:
+                self.devices = len(devices)
+                self.device_kind = devices[0].device_kind
+                self.peak_flops_per_s = per_chip * len(devices)
         return self
 
     # -- feeding ---------------------------------------------------------------
@@ -374,25 +362,19 @@ class UtilizationLedger:
                 self.flops += self._flops_per_step
 
     def sample_hbm(self) -> float:
-        """Refresh the HBM high-water mark from ``memory_stats()`` (0 on
-        backends without memory telemetry — CPU, older PJRT)."""
+        """Refresh the HBM high-water mark from ``memory_stats()`` (the
+        CPU backend reports none: 0 there)."""
         total = 0.0
         if "jax" in sys.modules:
-            try:
-                import jax
+            import jax
 
-                for d in jax.local_devices():
-                    try:
-                        stats = d.memory_stats() or {}
-                    except Exception:
-                        stats = {}
-                    peak = stats.get("peak_bytes_in_use")
-                    if peak is None:
-                        peak = stats.get("bytes_in_use")
-                    if peak:
-                        total += float(peak)
-            except Exception:
-                pass
+            for d in jax.local_devices():
+                stats = d.memory_stats() or {}
+                peak = stats.get("peak_bytes_in_use")
+                if peak is None:
+                    peak = stats.get("bytes_in_use")
+                if peak:
+                    total += float(peak)
         with self._lock:
             if total > self._hbm_peak_bytes:
                 self._hbm_peak_bytes = total

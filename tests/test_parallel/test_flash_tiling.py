@@ -1,0 +1,112 @@
+"""The flash kernels against the TPU lowering, checked from the CPU.
+
+What a sandbox can check without a chip: that ``_pick_block`` only ever
+returns a tile the Pallas TPU lowering accepts (and raises a typed error
+otherwise, instead of letting the interpreter on the CPU accept what the
+chip refuses), that the kernels are interpreted on the ``cpu`` backend
+only, and — by cross-lowering with ``jax.export`` for ``platforms=["tpu"]``
+— that forward, dq and dkv each lower to a Mosaic custom call.  Mosaic's
+own compile and the execution are the chip's to say (``chip_smoke.py``).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from polyaxon_tpu.exceptions import RuntimeLayerError
+from polyaxon_tpu.parallel import flash
+
+
+class TestPickBlock:
+    @pytest.mark.parametrize(
+        "t,want,tile",
+        [
+            (1024, 1024, 1024),   # the whole axis
+            (8192, 1024, 1024),   # largest 128-multiple divisor <= want
+            (2048, 1024, 1024),
+            (1536, 1024, 768),
+            (1152, 1024, 384),
+            (128, 1024, 128),
+            (16, 1024, 16),       # toy shapes: the whole axis, multiple of 8
+            (8, 1024, 8),
+            (640, 512, 128),
+            (1000, 512, 200),     # no 128-multiple divides: sublane-aligned
+            (16, 8, 8),
+        ],
+    )
+    def test_lowerable_tiles(self, t, want, tile):
+        b = flash._pick_block(t, want)
+        assert b == tile
+        assert t % b == 0 and b <= want
+        # What the lowering accepts: sublane-aligned tile edges.
+        assert b % 8 == 0
+
+    @pytest.mark.parametrize("t", [1100, 1030, 4100, 12, 100])
+    def test_untileable_lengths_raise_typed(self, t):
+        # 1100 is the ISSUE's case: the old "any divisor" rule picked 550,
+        # which the interpreter runs and the TPU lowering rejects.
+        with pytest.raises(flash.FlashTilingError, match=str(t)) as e:
+            flash._pick_block(t, 1024)
+        assert isinstance(e.value, RuntimeLayerError)
+
+    def test_raises_at_trace_time_through_the_model_path(self):
+        from polyaxon_tpu.models import TransformerConfig, init_params, loss_fn
+
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=32, n_layers=1, n_heads=2, head_dim=16,
+            d_ff=64, max_seq=1100, attention_impl="flash", flash_block=1024,
+        )
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        tok = jnp.zeros((1, 1100), jnp.int32)
+        with pytest.raises(flash.FlashTilingError, match="1100"):
+            jax.eval_shape(
+                lambda p: loss_fn(p, {"tokens": tok, "targets": tok}, cfg),
+                params,
+            )
+
+
+class TestBackend:
+    def test_interpreted_on_cpu_only(self, monkeypatch):
+        assert flash.pallas_interpret() is True  # the suite runs on cpu
+        assert flash.on_tpu() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert flash.pallas_interpret() is False
+        assert flash.on_tpu() is True
+
+    def test_any_other_backend_is_an_error(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeLayerError, match="gpu"):
+            flash.pallas_interpret()
+
+    def test_unreadable_backend_is_an_error(self, monkeypatch):
+        def no_backend():
+            raise RuntimeError("Unable to initialize backend")
+
+        monkeypatch.setattr(jax, "default_backend", no_backend)
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            flash.pallas_interpret()
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            flash.on_tpu()
+
+
+def test_flash_fwd_bwd_cross_lower_to_three_mosaic_calls():
+    """(T, head_dim) = (1024, 64), bf16, compiled (interpret=False) and
+    exported for the TPU: forward, dq and dkv are three tpu_custom_calls
+    carrying their kernel names."""
+    from jax import export
+
+    cfg = (64**-0.5, 1024, 1024, False)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: flash.flash_attention(cfg, q, k, v)
+            .astype(jnp.float32)
+            .sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    x = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16)
+    text = export.export(jax.jit(grads), platforms=["tpu"])(x, x, x).mlir_module()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f'kernel_name = "{name}"' in text

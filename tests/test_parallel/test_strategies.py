@@ -190,7 +190,9 @@ class TestGQA:
         "strategy,mesh_axes,impl",
         [
             ("fsdp", {"data": 8}, "dense"),
-            ("sp_ring", {"data": 2, "sequence": 4}, "flash"),
+            # sequence=2: T_local = 8, the smallest shard the flash kernels
+            # tile (a TPU-lowerable tile edge is a multiple of 8).
+            ("sp_ring", {"data": 4, "sequence": 2}, "flash"),
             ("ulysses", {"data": 2, "sequence": 4}, "flash"),
         ],
     )
@@ -538,7 +540,7 @@ class TestRingFlash:
         for strategy in ("sp_ring", "ulysses"):
             cfg = CFG.scaled(attention_impl="flash", flash_block=8)
             loss, _ = strategy_loss(
-                strategy, {"data": 2, "sequence": 4}, batch, cfg=cfg
+                strategy, {"data": 4, "sequence": 2}, batch, cfg=cfg
             )
             assert loss == pytest.approx(ref_loss, abs=2e-4), strategy
 
@@ -548,6 +550,6 @@ class TestRingFlash:
         the optimizer all composed."""
         cfg = CFG.scaled(attention_impl="flash")
         loss, _ = strategy_loss(
-            "sp_ring", {"data": 2, "sequence": 4}, batch, cfg=cfg
+            "sp_ring", {"data": 4, "sequence": 2}, batch, cfg=cfg
         )
         assert loss == pytest.approx(ref_loss, abs=2e-4)

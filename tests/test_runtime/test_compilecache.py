@@ -1,46 +1,50 @@
-"""Persistent compile cache: arming, graceful no-op, and actual reuse.
+"""Persistent compile cache: where it lives, and actual reuse.
 
-The contract under test (runtime/compilecache.py): enabling is
-idempotent and never raises; a process that compiled before enabling
-still reads/writes the cache (the reset_cache() fix); identical
-programs hit — in the same process and, the point of the feature,
-across processes sharing a StoreLayout's ``compile_cache`` dir.
+The contract under test (runtime/compilecache.py): the cache directory is
+placed from outside — ``JAX_COMPILATION_CACHE_DIR`` exported means the
+program leaves the variable and ``jax_compilation_cache_dir`` exactly as
+set; not exported means one fixed path in the checkout.  A process that
+compiled before enabling still reads/writes the cache (the reset_cache()
+fix); identical programs hit — in the same process and, the point of the
+feature, across processes that share the directory.
 """
 
 import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
 from polyaxon_tpu.runtime import compilecache as cc
-from polyaxon_tpu.stores.layout import StoreLayout
 
 _JAX_ENV = (
     "JAX_COMPILATION_CACHE_DIR",
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
     "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
 )
+REPO = Path(__file__).resolve().parents[2]
 
 
 @pytest.fixture()
-def cache_env(monkeypatch):
+def cache_env(monkeypatch, tmp_path):
     """Snapshot/restore everything enable_compile_cache mutates: module
-    status, the knob env vars, jax's env mirror, jax config, and the
-    cache singleton — so the suite's other tests never see an armed
-    cache."""
+    status, jax's env variables, jax config, and the cache singleton — so
+    the suite's other tests never see an armed cache.  The fixed default
+    is pointed into tmp_path so no test writes into the checkout."""
     import jax
     from jax._src import compilation_cache as jcc
 
-    for var in (cc.ENV_ENABLE, cc.ENV_DIR, cc.ENV_MIN_COMPILE_S):
-        monkeypatch.delenv(var, raising=False)
     saved_env = {k: os.environ.get(k) for k in _JAX_ENV}
+    for k in _JAX_ENV:
+        os.environ.pop(k, None)
     saved_cfg = (
         jax.config.jax_compilation_cache_dir,
         jax.config.jax_persistent_cache_min_compile_time_secs,
         jax.config.jax_persistent_cache_min_entry_size_bytes,
     )
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", tmp_path / "default_cc")
     cc._reset_for_tests()
     yield cc
     cc._reset_for_tests()
@@ -59,75 +63,87 @@ def cache_env(monkeypatch):
     jcc.reset_cache()
 
 
-class TestEnable:
-    def test_knob_off_disables(self, cache_env, monkeypatch, tmp_path):
-        monkeypatch.setenv(cc.ENV_ENABLE, "0")
-        st = cc.enable_compile_cache(str(tmp_path / "cc"))
-        assert not st.enabled
-        assert cc.ENV_ENABLE in st.reason
-        assert os.environ.get("JAX_COMPILATION_CACHE_DIR") is None
+def test_default_is_one_fixed_git_ignored_path_in_the_checkout():
+    """Not a function of a base dir, a temp name, a pid or the time."""
+    assert cc.DEFAULT_CACHE_DIR == REPO / ".compile_cache"
+    ignored = (REPO / ".gitignore").read_text().split()
+    assert ".compile_cache/" in ignored
 
-    def test_no_dir_disables(self, cache_env):
+
+class TestPlacement:
+    def test_set_from_outside_is_left_untouched(self, cache_env, tmp_path):
+        import jax
+
+        outside = tmp_path / "outside"
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(outside)
+        before = jax.config.jax_compilation_cache_dir
         st = cc.enable_compile_cache()
-        assert not st.enabled
-        assert "no cache dir" in st.reason
+        assert st.enabled and st.cache_dir == str(outside)
+        assert "JAX_COMPILATION_CACHE_DIR" in st.reason
+        # Neither the variable nor the config is assigned by the program.
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(outside)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert outside.is_dir()
+        assert not cc.DEFAULT_CACHE_DIR.exists()
+        assert cc.cache_dir() == str(outside)
 
-    def test_env_dir_wins_over_argument(self, cache_env, monkeypatch, tmp_path):
-        env_dir = tmp_path / "from_env"
-        monkeypatch.setenv(cc.ENV_DIR, str(env_dir))
-        st = cc.enable_compile_cache(str(tmp_path / "from_arg"))
-        assert st.enabled
-        assert st.cache_dir == str(env_dir)
-        assert env_dir.is_dir()
+    def test_unset_means_the_fixed_path(self, cache_env):
+        import jax
 
-    def test_enabled_and_idempotent(self, cache_env, tmp_path):
-        d = str(tmp_path / "cc")
-        st = cc.enable_compile_cache(d)
-        assert st.enabled and st.cache_dir == d
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == d
+        st = cc.enable_compile_cache()
+        default = str(cc.DEFAULT_CACHE_DIR)
+        assert st.enabled and st.cache_dir == default
+        # Exported so children inherit it, and an already-imported jax is
+        # brought in line.
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == default
+        assert jax.config.jax_compilation_cache_dir == default
+        assert cc.cache_dir() == default
+        assert cc.cache_status() is st
+
+    def test_thresholds_default_to_persist_everything(self, cache_env):
+        cc.enable_compile_cache()
         # min_entry_size -1: persist regardless of executable size (the
         # CPU smoke configs compile tiny modules).
         assert os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] == "-1"
-        assert cc.enable_compile_cache(d) is st  # cached status
-        assert cc.cache_status() is st
+        assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
 
-    def test_unwritable_dir_is_noop_not_raise(self, cache_env, tmp_path):
-        blocked = tmp_path / "file_not_dir"
-        blocked.write_text("occupied")
-        st = cc.enable_compile_cache(str(blocked / "cc"))
-        assert not st.enabled
-        assert "unusable" in st.reason
-
-    def test_missing_jax_api_is_noop_not_raise(self, cache_env, tmp_path):
-        """Older-JAX degradation: config API failures come back as a
-        disabled status with the reason, never an exception."""
+    def test_outside_thresholds_win(self, cache_env):
         import jax
 
-        def boom(*a, **k):
-            raise AttributeError("no persistent cache here")
+        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "2.5"
+        cc.enable_compile_cache()
+        assert os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "2.5"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 2.5
 
-        # Patch scoped INSIDE the test: cache_env's teardown needs the
-        # real jax.config.update to restore state.
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(jax.config, "update", boom)
-            st = cc.enable_compile_cache(str(tmp_path / "cc"))
+    def test_unusable_dir_is_a_disabled_status(self, cache_env, tmp_path):
+        blocked = tmp_path / "file_not_dir"
+        blocked.write_text("occupied")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(blocked / "cc")
+        st = cc.enable_compile_cache()
         assert not st.enabled
-        assert "unavailable" in st.reason
+        assert "unusable" in st.reason
 
     def test_status_placeholder_when_never_enabled(self, cache_env):
         st = cc.cache_status()
         assert not st.enabled
         assert "not enabled" in st.reason
 
+    def test_spawner_injects_no_cache_dir(self):
+        """Children inherit the variable; the gang env contract carries
+        no cache directory of its own."""
+        from polyaxon_tpu.runtime.env import gang_env
 
-def test_layout_compile_cache_dir(tmp_path):
-    """One cache per StoreLayout, shared by every gang of that store."""
-    layout = StoreLayout(tmp_path / "stores")
-    assert layout.compile_cache_dir == tmp_path / "stores" / "compile_cache"
+        env = gang_env(
+            run_id=1, run_uuid="u", run_dir="/r", spec_path="/r/spec.json",
+            process_id=0, num_processes=1, coordinator=None,
+            devices_per_host=1, accelerator="cpu-1", mesh_axes={"data": 1},
+            strategy="ddp", strategy_options={},
+        )
+        assert not [k for k in env if "CACHE" in k]
 
 
 class TestReuse:
-    def test_in_process_hit_after_reset(self, cache_env, tmp_path):
+    def test_in_process_hit_after_reset(self, cache_env):
         """Arm AFTER this process already compiled plenty (the whole
         test session) — reset_cache() must still make writes and reads
         work: first compile of a novel program misses (entry written),
@@ -137,8 +153,8 @@ class TestReuse:
 
         from polyaxon_tpu.tracking.ledger import compile_cache_telemetry
 
-        d = tmp_path / "cc"
-        assert cc.enable_compile_cache(str(d)).enabled
+        assert cc.enable_compile_cache().enabled
+        d = cc.DEFAULT_CACHE_DIR
         h0, m0 = compile_cache_telemetry()
         jax.jit(lambda x: (x * 3.0 - 1.0).sum())(jnp.arange(11.0))
         h1, m1 = compile_cache_telemetry()
@@ -150,37 +166,42 @@ class TestReuse:
         h2, _ = compile_cache_telemetry()
         assert h2 > h1, "identical program should hit the cache"
 
-    def test_aot_compile_returns_executable(self, cache_env, tmp_path):
+    def test_aot_compile_returns_executable(self, cache_env):
         import jax
         import jax.numpy as jnp
         import numpy as np
 
-        cc.enable_compile_cache(str(tmp_path / "cc"))
+        cc.enable_compile_cache()
         jitted = jax.jit(lambda x: x * 2.0 + 0.5)
         x = jnp.arange(5.0)
         fn, secs = cc.aot_compile(jitted, x)
         assert fn is not jitted and secs > 0
         np.testing.assert_allclose(np.asarray(fn(x)), np.asarray(x) * 2.0 + 0.5)
 
-    def test_aot_compile_falls_back_on_plain_fn(self, cache_env):
-        def plain(x):
-            return x + 1
+    def test_aot_compile_lets_a_compile_error_propagate(self):
+        """A failed lower()/compile() is reported from where it happened,
+        not swallowed into a second, lazy compile."""
+        import jax
+        import jax.numpy as jnp
 
-        fn, secs = cc.aot_compile(plain, 1)
-        assert fn is plain and secs == 0.0
-        assert fn(1) == 2
+        def bad(x):
+            return x @ jnp.ones((3, 3))  # [5] @ [3,3]: shape error at trace
+
+        with pytest.raises(TypeError):
+            cc.aot_compile(jax.jit(bad), jnp.arange(5.0))
 
 
 _CHILD = textwrap.dedent(
     """
-    import sys
-    import jax, jax.numpy as jnp
+    import os, sys
     from polyaxon_tpu.runtime.compilecache import enable_compile_cache
+    st = enable_compile_cache()          # before the jax import, as workers do
+    assert st.enabled and st.cache_dir == sys.argv[1], st
+    import jax, jax.numpy as jnp
+    assert jax.config.jax_compilation_cache_dir == sys.argv[1]
     from polyaxon_tpu.tracking.ledger import (
         compile_cache_telemetry, install_compile_hooks,
     )
-    st = enable_compile_cache(sys.argv[1])
-    assert st.enabled, st
     install_compile_hooks()
     out = jax.jit(lambda x: (x @ x.T).sum() * 0.25)(
         jnp.arange(64.0).reshape(8, 8)
@@ -195,10 +216,10 @@ _CHILD = textwrap.dedent(
 @pytest.mark.slow
 def test_cross_process_reuse(tmp_path):
     """The feature's reason to exist: a SECOND process compiling the
-    same program loads it from the shared dir instead of compiling."""
+    same program loads it from the directory the outside placed, and
+    nothing lands anywhere else."""
     d = str(tmp_path / "cc")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=d)
 
     def run():
         p = subprocess.run(

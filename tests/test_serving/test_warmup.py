@@ -145,3 +145,52 @@ def test_no_warmup_counts_lazy_compiles(params):
         assert eng.stats()["steady_state_compiles"] > 0
     finally:
         eng.stop()
+
+
+def test_failed_warmup_is_a_failed_start(params):
+    """A warmup that raises (a compile the backend refuses, a step that
+    died after donating the pool) must NOT open the readiness gate: the
+    engine reports ``failed`` with the error, refuses new requests with
+    it, and fails whoever queued while it was warming."""
+    eng = ServingEngine(params, CFG, slots=2, max_len=48, warmup=True)
+
+    def boom(*a, **k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM (simulated)")
+
+    eng._step_fn = boom
+    early = eng.submit([1, 2, 3], max_new_tokens=4)  # queued while warming
+    eng.start()
+    try:
+        assert eng.wait_ready(timeout=60) is False
+        st = eng.stats()
+        assert st["state"] == "failed"
+        assert "RESOURCE_EXHAUSTED" in st["start_error"]
+        assert not eng._ready.is_set()
+        with pytest.raises(RuntimeError, match="failed to start"):
+            early.wait(timeout=5)
+        with pytest.raises(RuntimeError, match="failed to start"):
+            eng.submit([1, 2, 3], max_new_tokens=4)
+    finally:
+        eng.stop()
+
+
+def test_serve_engine_exits_nonzero_on_failed_start(params):
+    """``lm_server``/``replica`` serve through ``serve_engine``: a failed
+    start shuts the HTTP server down and raises, so the process exits
+    non-zero instead of sitting in ``warming``; /healthz said 503
+    ``failed`` meanwhile."""
+    from http.server import ThreadingHTTPServer
+
+    from polyaxon_tpu.builtins.services import _make_lm_handler, serve_engine
+
+    eng = ServingEngine(params, CFG, slots=2, max_len=48, warmup=True)
+    eng._step_fn = lambda *a, **k: (_ for _ in ()).throw(
+        RuntimeError("Mosaic refused the tile (simulated)")
+    )
+    server = ThreadingHTTPServer(
+        ("127.0.0.1", 0), _make_lm_handler(eng, CFG, {})
+    )
+    eng.start()
+    with pytest.raises(RuntimeError, match="failed to start.*Mosaic refused"):
+        serve_engine(server, eng)
+    server.server_close()

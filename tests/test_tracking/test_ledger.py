@@ -148,24 +148,14 @@ class TestCompileTelemetry:
         assert s1 > s0  # backend_compile duration observed
         assert e1 > e0  # compile request counted
 
-    def test_hook_install_fallback_is_graceful(self, monkeypatch):
-        # Simulate an older JAX without the monitoring API; restore the
-        # module state afterwards so later tests still have live hooks.
-        from jax import monitoring
-
-        saved = ledger_mod._hooks_installed
-        try:
-            ledger_mod._hooks_installed = None
-            monkeypatch.setattr(
-                monitoring,
-                "register_event_duration_secs_listener",
-                None,
-                raising=True,
-            )
-            assert ledger_mod.install_compile_hooks() is False
-            assert ledger_mod.install_compile_hooks() is False  # sticky
-        finally:
-            ledger_mod._hooks_installed = saved
+    def test_peak_table_unknown_tpu_kind_is_an_error(self):
+        # The CPU has no peak (no MFU is claimed there); the v5e chip
+        # reports "TPU v5 lite"; a TPU kind nobody recorded a sourced
+        # peak for is an error, never a default or a silent 0.
+        assert ledger_mod.peak_flops_per_chip("cpu", "cpu") == 0.0
+        assert ledger_mod.peak_flops_per_chip("tpu", "TPU v5 lite") == 197e12
+        with pytest.raises(KeyError, match="TPU v9"):
+            ledger_mod.peak_flops_per_chip("tpu", "TPU v9")
 
     def test_start_snapshots_compile_baseline(self):
         import jax
