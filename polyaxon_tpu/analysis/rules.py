@@ -848,12 +848,19 @@ _SPAN_NAMES = {
     "train.aot_compile", "train.loop", "train.step",
     "pipeline.drain", "pipeline.gather",
     # serving engine lifecycle + request phases
-    "engine.compile", "serving.warmup", "serving.step", "serving.prefill",
+    "engine.compile", "serving.warmup",
     "serving.request", "serving.generate", "serving.admit",
     "serving.queue_wait", "serving.prefill.chunk", "serving.first_token",
     "serving.prefix_cache.hit", "serving.decode.step",
     "serving.spec.draft", "serving.spec.verify",
     "serving.park", "serving.spill", "serving.restore", "serving.finish",
+    # serving engine loop: the exclusive phases of its clock
+    # (serving/engine.py:LOOP_PHASES; /v1/stats loop_* keys, xplane rows)
+    "serving.paging.match", "serving.paging.offer", "serving.paging.alloc",
+    "serving.loop.admit", "serving.loop.prefill_host",
+    "serving.loop.decode_host", "serving.loop.device_wait",
+    "serving.loop.emit", "serving.loop.bookkeeping", "serving.loop.other",
+    "serving.loop.idle",
     # fleet router
     "router.request", "router.attempt",
 }

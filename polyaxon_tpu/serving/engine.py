@@ -46,6 +46,7 @@ step — the block pool lives on the gang mesh.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import queue
 import random
@@ -68,6 +69,46 @@ from polyaxon_tpu.stats import MemoryStats
 from polyaxon_tpu.stats.tsdb import RatioWindow
 from polyaxon_tpu.tracking.flightrec import get_progress
 from polyaxon_tpu.tracking.trace import TraceContext, get_tracer
+
+
+#: The engine loop's phases (``tracking/trace.py:PhaseClock``): every instant
+#: of the scheduler thread belongs to exactly one.  ``/v1/stats`` reports each
+#: as ``loop_<phase>_s`` / ``loop_<phase>_n`` (``serving.`` and ``loop.``
+#: dropped, dots to underscores) beside ``loop_wall_s``.
+PH_MATCH = "serving.paging.match"  # PrefixCache.match at admission, its increfs
+PH_OFFER = "serving.paging.offer"  # PrefixCache.offer when a prompt is in
+PH_ALLOC = "serving.paging.alloc"  # allocator, PrefixCache.evict, every decref
+PH_ADMIT = "serving.loop.admit"  # queue pop, slot, drafter seeding, job creation
+PH_PREFILL_HOST = "serving.loop.prefill_host"  # a chunk's inputs, uploads, dispatch
+PH_DECODE_HOST = "serving.loop.decode_host"  # a step's inputs, uploads, dispatch
+PH_DEVICE_WAIT = "serving.loop.device_wait"  # the blocking device-to-host reads
+PH_EMIT = "serving.loop.emit"  # tokens out, stream puts, retire, trace finalize
+PH_BOOKKEEPING = "serving.loop.bookkeeping"  # gauges, ledger, histograms, beacon, spans
+PH_OTHER = "serving.loop.other"  # the loop itself, cancels, park/restore, drafting
+PH_IDLE = "serving.loop.idle"  # the cv.wait with nothing to do
+LOOP_PHASES = (
+    PH_MATCH, PH_OFFER, PH_ALLOC, PH_ADMIT, PH_PREFILL_HOST, PH_DECODE_HOST,
+    PH_DEVICE_WAIT, PH_EMIT, PH_BOOKKEEPING, PH_OTHER, PH_IDLE,
+)
+
+
+def _in_phase(phase: str):
+    """Run a ``ServingEngine`` method as ``phase`` of the engine's clock."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(self, *args, **kwargs):
+            with self._clock.phase(phase):
+                return fn(self, *args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
+def _stats_key(phase: str) -> str:
+    """``serving.paging.match`` -> ``loop_paging_match``."""
+    return "loop_" + phase[len("serving."):].replace("loop.", "").replace(".", "_")
 
 
 class EngineDrainingError(RuntimeError):
@@ -685,12 +726,14 @@ class ServingEngine:
             knob_int("POLYAXON_TPU_TRACE_EXEMPLARS"),
             knob_float("POLYAXON_TPU_TRACE_EXEMPLAR_WINDOW_S"),
         )
-        # Decode-side utilization ledger (armed in start()): device-busy
-        # seconds (prefill + decode dispatch/sync) and occupancy-weighted
-        # busy time — the serving analogue of train-side goodput/MFU.
+        # The scheduler thread's exclusive phase clock: where the loop's
+        # wall time goes, as whole-run counters in stats() and, during an
+        # xplane capture, as annotations in the device trace.
+        self._clock = get_tracer().phase_clock(LOOP_PHASES, PH_OTHER)
+        # Decode-side utilization ledger (armed in start()): the seconds
+        # of prefill and decode ticks weighted by slot occupancy — the
+        # serving analogue of train-side goodput/MFU.
         self._ledger: Optional[Any] = None
-        self._started_at: Optional[float] = None
-        self._busy_s = 0.0
         self._occ_weighted_s = 0.0
 
     # -- compiled functions ----------------------------------------------------
@@ -1113,7 +1156,6 @@ class ServingEngine:
             from polyaxon_tpu.tracking.ledger import get_ledger
 
             self._ledger = get_ledger().start(source="serving")
-            self._started_at = time.time()
             self._thread = threading.Thread(
                 target=self._loop, name="serving-engine", daemon=True
             )
@@ -1281,23 +1323,38 @@ class ServingEngine:
         """Blocking convenience: submit + wait."""
         return self.submit(prompt, max_new_tokens, temperature).wait(timeout)
 
-    def _utilization_snapshot(self) -> Dict[str, float]:
-        """Decode-side utilization: busy fraction of wall clock since
-        start(), mean slot occupancy while busy, and their product — the
-        serving equivalent of the train ledger's goodput × MFU."""
+    def _utilization_snapshot(self, clock: Optional[tuple] = None) -> Dict[str, float]:
+        """Host-loop utilization from the phase clock: the share of the
+        scheduler loop's wall time it was not idle (HOST-loop busy, which
+        is not device busy: it holds paging and bookkeeping, and the device
+        also works while the host builds the next call), the mean slot
+        occupancy of its prefill and decode ticks over that busy time, and
+        their product — the serving equivalent of the train ledger's
+        goodput × MFU."""
+        wall, seconds, _ = clock or self._clock.snapshot()
         with self._stats_lock:
-            busy = self._busy_s
             occw = self._occ_weighted_s
-        elapsed = (
-            time.time() - self._started_at if self._started_at else 0.0
-        )
-        busy_frac = busy / elapsed if elapsed > 0 else 0.0
+        busy = wall - seconds[PH_IDLE]
+        busy_frac = busy / wall if wall > 0 else 0.0
         occ = occw / busy if busy > 0 else 0.0
         return {
             "decode_busy_frac": round(busy_frac, 6),
             "slot_occupancy": round(occ, 6),
             "decode_utilization": round(busy_frac * occ, 6),
         }
+
+    @staticmethod
+    def _loop_snapshot(clock: tuple) -> Dict[str, Any]:
+        """The phase clock as flat monotone counters: ``loop_wall_s`` and,
+        per phase, its seconds and the times it was entered.  Differences
+        of two ``/v1/stats`` reads split the window between them."""
+        wall, seconds, counts = clock
+        out: Dict[str, Any] = {"loop_wall_s": round(wall, 6)}
+        for phase in LOOP_PHASES:
+            key = _stats_key(phase)
+            out[key + "_s"] = round(seconds[phase], 6)
+            out[key + "_n"] = counts[phase]
+        return out
 
     def _paging_snapshot(self) -> Dict[str, Any]:
         """Block-pool / prefix-cache / prefill-backlog state, shared by
@@ -1391,9 +1448,8 @@ class ServingEngine:
         }
 
     def _ledger_account(self, dt: float, occ_frac: float, tokens: int) -> None:
-        """Fold one device-busy interval into the utilization ledger."""
+        """Fold one prefill or decode tick into the utilization ledger."""
         with self._stats_lock:
-            self._busy_s += dt
             self._occ_weighted_s += dt * occ_frac
         led = self._ledger
         if led is None:
@@ -1405,7 +1461,9 @@ class ServingEngine:
         led.maybe_flush()
 
     def stats(self) -> Dict[str, Any]:
-        util = self._utilization_snapshot()
+        clock = self._clock.snapshot()
+        util = self._utilization_snapshot(clock)
+        loop = self._loop_snapshot(clock)
         paging = self._paging_snapshot()
         spec = self._spec_snapshot()
         with self._stats_lock:
@@ -1445,6 +1503,7 @@ class ServingEngine:
                 **paging,
                 **spec,
                 **util,
+                **loop,
             }
 
     def latency_summaries(self) -> Dict[str, Dict[str, float]]:
@@ -1615,11 +1674,20 @@ class ServingEngine:
     # -- scheduler loop --------------------------------------------------------
 
     def _loop(self) -> None:
-        tracer = get_tracer()
         self._run_warmup()
         if self.start_error is not None:
             self._fail_start()
             return
+        self._clock.start()
+        try:
+            self._serve()
+        finally:
+            self._clock.stop()
+
+    def _serve(self) -> None:
+        """The scheduler loop proper.  What it does itself is phase
+        ``serving.loop.other``; every callee enters its own."""
+        idle = self._clock.phase(PH_IDLE)
         while not self._stop.is_set():
             self._process_cancels()
             self._admit()
@@ -1646,14 +1714,7 @@ class ServingEngine:
                 remaining = len(job.req.prompt) - job.next_pos
                 spent += min(remaining, budget) if budget else remaining
                 try:
-                    # Per-iteration span at the hot sample rate, like the
-                    # decode step below: prefill runs per CHUNK.
-                    with tracer.span(
-                        "serving.prefill",
-                        sample=tracer.hot_sample,
-                        request_id=job.req.id,
-                    ):
-                        did = self._prefill_tick()
+                    did = self._prefill_tick()
                 except Exception as e:
                     if self._prefill and self._prefill[0] is job:
                         self._prefill.popleft()
@@ -1667,8 +1728,7 @@ class ServingEngine:
                     break
             if self._active.any():
                 try:
-                    with tracer.span("serving.step", sample=tracer.hot_sample):
-                        self._step_once()
+                    self._step_once()
                 except Exception as e:  # fail in-flight, keep serving
                     for slot in np.nonzero(self._active)[0]:
                         self._fail_slot(int(slot), f"decode step failed: {e!r}")
@@ -1685,9 +1745,11 @@ class ServingEngine:
             # (throttled; scale-up replicas preload whatever incumbents
             # last published).
             self._maybe_persist()
+            self._clock.anchor()
             with self._cv:
                 if not self._queue and not self._stop.is_set():
-                    self._cv.wait(timeout=0.2)
+                    with idle:
+                        self._cv.wait(timeout=0.2)
 
     def _fail_start(self) -> None:
         """A failed warmup serves nothing: whoever queued while warming
@@ -1714,10 +1776,12 @@ class ServingEngine:
         req.stream.put(None)
         req.done.set()
 
+    @_in_phase(PH_ADMIT)
     def _admit(self) -> None:
         """Move queued requests into free slots (queue order) and enqueue
         their prefill jobs; the prefix cache shortens a job to its first
         uncached block."""
+        clock = self._clock
         while True:
             with self._cv:
                 if not self._queue:
@@ -1727,16 +1791,19 @@ class ServingEngine:
                     return
                 req = self._queue.popleft()
             req.started_at = time.time()
-            self.stats_registry.timing(
-                "serving.queue_wait_s", req.started_at - req.submitted_at
-            )
-            self._trace_span(
-                req,
-                "serving.queue_wait",
-                req.submitted_at,
-                req.started_at - req.submitted_at,
-            )
-            self._trace_span(req, "serving.admit", req.started_at, 0.0, slot=slot)
+            with clock.phase(PH_BOOKKEEPING):
+                self.stats_registry.timing(
+                    "serving.queue_wait_s", req.started_at - req.submitted_at
+                )
+                self._trace_span(
+                    req,
+                    "serving.queue_wait",
+                    req.submitted_at,
+                    req.started_at - req.submitted_at,
+                )
+                self._trace_span(
+                    req, "serving.admit", req.started_at, 0.0, slot=slot
+                )
             self._slot_req[slot] = req
             # Speculative path selection is typed per request at
             # admission: greedy requests get a drafter (its suffix index
@@ -1760,7 +1827,8 @@ class ServingEngine:
                     self._drafters[slot] = drafter
             job = _PrefillJob(req, slot)
             if self.prefix_cache is not None:
-                matched = self.prefix_cache.match(req.prompt)
+                with clock.phase(PH_MATCH):
+                    matched = self.prefix_cache.match(req.prompt)
                 for i, block in enumerate(matched):
                     self._tables[slot, i] = block
                 m = len(matched) * self.block_size
@@ -1785,6 +1853,7 @@ class ServingEngine:
             self._prefill.append(job)
             self._record_gauges()
 
+    @_in_phase(PH_ALLOC)
     def _alloc_block(self) -> Optional[int]:
         """Allocate one pool block, evicting a cold cached prefix if the
         free list is empty."""
@@ -1794,17 +1863,20 @@ class ServingEngine:
                 block = self.block_allocator.alloc()
         return block
 
+    @_in_phase(PH_PREFILL_HOST)
     def _prefill_tick(self) -> bool:
         """Run ONE chunk of the oldest pending prefill.  Returns True if
         the device did work; False means the job is blocked on the block
         pool (it stays at the head and retries next iteration)."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        t0 = clock.t  # the transition into this phase
+        bookkeeping = clock.phase(PH_BOOKKEEPING)
         job = self._prefill[0]
         req, slot = job.req, job.slot
         bs = self.block_size
         t = len(req.prompt)
-        t0 = time.perf_counter()
         if job.cow_pending:
             fresh = self._alloc_block()
             if fresh is None:
@@ -1814,7 +1886,8 @@ class ServingEngine:
             self._pool = self._get_copy()(
                 self._pool, jnp.int32(shared), jnp.int32(fresh)
             )
-            self.block_allocator.decref(shared)
+            with clock.phase(PH_ALLOC):
+                self.block_allocator.decref(shared)
             self._tables[slot, bi] = fresh
             job.cow_pending = False
             with self._stats_lock:
@@ -1846,27 +1919,30 @@ class ServingEngine:
         )
         job.next_pos += n
         done = job.next_pos >= t
-        if req.trace is not None:
-            t1 = time.perf_counter()
+        with bookkeeping as t1:
             self._trace_span(
                 req,
                 "serving.prefill.chunk",
-                time.time() - (t1 - t0),
+                clock.epoch + t0,
                 t1 - t0,
                 tokens=n,
                 pos=job.next_pos,
             )
-        # Chunk compute is device-busy time serving one request; only the
-        # final chunk emits a token.
-        self._ledger_account(
-            time.perf_counter() - t0, 1.0 / self.slots,
-            tokens=1 if done else 0,
-        )
+            # The tick serves one request; only the final chunk
+            # emits a token.
+            self._ledger_account(
+                t1 - t0, 1.0 / self.slots, tokens=1 if done else 0
+            )
         if done:
             self._prefill.popleft()
-            self._finalize_prefill(job, np.asarray(logits))
-        self._record_gauges()
-        self._progress.beat(step=self._n_steps)
+            # Every chunk before this one was only dispatched: here
+            # the host waits for the device to finish the prompt.
+            with clock.phase(PH_DEVICE_WAIT):
+                logits = np.asarray(logits)
+            self._finalize_prefill(job, logits)
+        with bookkeeping:
+            self._record_gauges()
+            self._progress.beat(step=self._n_steps)
         return True
 
     def _finalize_prefill(self, job: _PrefillJob, logits: np.ndarray) -> None:
@@ -1874,27 +1950,32 @@ class ServingEngine:
         token from the last chunk's logits, activate the slot."""
         req, slot = job.req, job.slot
         t = len(req.prompt)
+        clock = self._clock
         if self.prefix_cache is not None:
-            full = t // self.block_size
-            self.prefix_cache.offer(
-                req.prompt,
-                [int(self._tables[slot, i]) for i in range(full)],
-            )
-        first = self._pick_first(logits, req.temperature)
-        # Time-to-first-token: prefill produced it, the client can read it.
-        ttft = time.time() - req.submitted_at
-        self.stats_registry.timing("serving.ttft_s", ttft)
-        if req.trace is not None:
-            req.trace.ttft_s = ttft
-            self._trace_span(
-                req, "serving.first_token", time.time(), 0.0, ttft_s=round(ttft, 6)
-            )
-        self._emit(slot, req, first)
-        if not req.done.is_set():
-            self._tok[slot] = first
-            self._pos[slot] = t
-            self._temps[slot] = req.temperature
-            self._active[slot] = True
+            with clock.phase(PH_OFFER):
+                full = t // self.block_size
+                self.prefix_cache.offer(
+                    req.prompt,
+                    [int(self._tables[slot, i]) for i in range(full)],
+                )
+        with clock.phase(PH_EMIT):
+            first = self._pick_first(logits, req.temperature)
+            # Time-to-first-token: prefill produced it, the client can read it.
+            ttft = time.time() - req.submitted_at
+            with clock.phase(PH_BOOKKEEPING):
+                self.stats_registry.timing("serving.ttft_s", ttft)
+                if req.trace is not None:
+                    req.trace.ttft_s = ttft
+                    self._trace_span(
+                        req, "serving.first_token", time.time(), 0.0,
+                        ttft_s=round(ttft, 6),
+                    )
+            self._emit(slot, req, first)
+            if not req.done.is_set():
+                self._tok[slot] = first
+                self._pos[slot] = t
+                self._temps[slot] = req.temperature
+                self._active[slot] = True
 
     def _pick_first(self, logits: np.ndarray, temperature: float) -> int:
         """First generated token comes from the prefill logits (exactly
@@ -1907,6 +1988,7 @@ class ServingEngine:
         p /= p.sum()
         return int(self._rng.choice(len(p), p=p))
 
+    @_in_phase(PH_OTHER)
     def _park(self, slot: int) -> None:
         """Pool exhausted at a block boundary: deactivate the slot with
         its state intact.  The active mask is data, so parking and
@@ -1942,7 +2024,8 @@ class ServingEngine:
         handles = self._spilled.setdefault(slot, {})
         for bi, data in zip(spill_bi, payloads):
             handles[bi] = self._host_tier.put(data, pinned=True)
-            alloc.decref(int(self._tables[slot, bi]))
+            with self._clock.phase(PH_ALLOC):
+                alloc.decref(int(self._tables[slot, bi]))
             self._tables[slot, bi] = -1
         req = self._slot_req[slot]
         if req is not None:
@@ -1971,25 +2054,26 @@ class ServingEngine:
         if self._tables[slot, bi_pos] < 0 and bi_pos not in handles:
             need += 1  # the faulted pos block resumes alongside
         alloc = self.block_allocator
+        clock = self._clock
         if alloc.n_free < need and self.prefix_cache is not None:
-            self.prefix_cache.evict(need - alloc.n_free)
+            with clock.phase(PH_ALLOC):
+                self.prefix_cache.evict(need - alloc.n_free)
         if alloc.n_free < need:
             return False, False
         n_restore = len(handles)
-        t0 = time.perf_counter()
-        for bi in sorted(handles):
-            fresh = self._alloc_block()
-            self._import_block(fresh, self._host_tier.pop(handles.pop(bi)))
-            self._tables[slot, bi] = fresh
-        self._spilled.pop(slot, None)
+        with clock.phase(PH_OTHER) as t0:
+            for bi in sorted(handles):
+                fresh = self._alloc_block()
+                self._import_block(fresh, self._host_tier.pop(handles.pop(bi)))
+                self._tables[slot, bi] = fresh
+            self._spilled.pop(slot, None)
         req = self._slot_req[slot]
         if req is not None:
-            dt = time.perf_counter() - t0
             self._trace_span(
                 req,
                 "serving.restore",
-                time.time() - dt,
-                dt,
+                clock.epoch + t0,
+                clock.t - t0,
                 blocks=n_restore,
             )
         return True, True
@@ -2103,10 +2187,13 @@ class ServingEngine:
                         self._n_cancelled += 1
         self._record_gauges()
 
+    @_in_phase(PH_DECODE_HOST)
     def _step_once(self) -> None:
         import jax
         import jax.numpy as jnp
 
+        clock = self._clock
+        t0 = clock.t  # the transition into this phase
         bs = self.block_size
         # Block-boundary faults: a slot whose next write crosses into an
         # unallocated block needs one now — or parks until the pool can
@@ -2129,7 +2216,6 @@ class ServingEngine:
             if self._slot_req[int(s)] is not None
             and self._slot_req[int(s)].trace is not None
         ]
-        t0 = time.perf_counter()
         self._key, sub = jax.random.split(self._key)
         tables = np.where(self._tables >= 0, self._tables, 0).astype(np.int32)
         n_live = int(self._active.sum())
@@ -2148,41 +2234,46 @@ class ServingEngine:
                 sub,
                 self._qweights,
             )
-            toks = np.asarray(toks)  # host sync — the loop's one device read
-            for slot in np.nonzero(self._active)[0]:
-                slot = int(slot)
-                req = self._slot_req[slot]
-                tok = int(toks[slot])
-                self._pos[slot] += 1
-                self._tok[slot] = tok
-                self._emit(slot, req, tok)
-                emitted += 1
-        with self._stats_lock:
-            self._n_steps += 1
-            self._window.append((time.time(), emitted))
-        # The step advances every live slot ≥1 token, so its wall time IS
-        # the per-token decode latency each of those requests observed
-        # (amortized over the accept run on speculative steps).
-        step_dt = time.perf_counter() - t0
-        self.stats_registry.timing("serving.decode_step_s", step_dt)
-        self.stats_registry.observe("serving.batch_occupancy", float(n_live))
-        # Per-request decode-step spans ride at the hot-sample rate; the
-        # waterfall's decode phase is interval-based, so these are pure
-        # detail and sampling them away loses nothing but zoom.
-        for req in participants:
-            self._trace_hot(
-                req,
-                "serving.decode.step",
-                time.time() - step_dt,
-                step_dt,
-                batch=n_live,
-            )
-        self._ledger_account(step_dt, n_live / self.slots, tokens=emitted)
-        self._record_gauges()
-        if self._ready.is_set():
-            self._capture.on_step(self._n_steps)
-        self._progress.beat(step=self._n_steps)
+            with clock.phase(PH_DEVICE_WAIT):
+                toks = np.asarray(toks)  # host sync — the loop's one device read
+            with clock.phase(PH_EMIT):
+                for slot in np.nonzero(self._active)[0]:
+                    slot = int(slot)
+                    req = self._slot_req[slot]
+                    tok = int(toks[slot])
+                    self._pos[slot] += 1
+                    self._tok[slot] = tok
+                    self._emit(slot, req, tok)
+                    emitted += 1
+        with clock.phase(PH_BOOKKEEPING) as t1:
+            with self._stats_lock:
+                self._n_steps += 1
+                self._window.append((time.time(), emitted))
+            # The step advances every live slot ≥1 token, so its wall time
+            # (block faults and drafting included) IS the per-token decode
+            # latency each of those requests observed (amortized over the
+            # accept run on speculative steps).
+            step_dt = t1 - t0
+            self.stats_registry.timing("serving.decode_step_s", step_dt)
+            self.stats_registry.observe("serving.batch_occupancy", float(n_live))
+            # Per-request decode-step spans ride at the hot-sample rate; the
+            # waterfall's decode phase is interval-based, so these are pure
+            # detail and sampling them away loses nothing but zoom.
+            for req in participants:
+                self._trace_hot(
+                    req,
+                    "serving.decode.step",
+                    clock.epoch + t0,
+                    step_dt,
+                    batch=n_live,
+                )
+            self._ledger_account(step_dt, n_live / self.slots, tokens=emitted)
+            self._record_gauges()
+            if self._ready.is_set():
+                self._capture.on_step(self._n_steps)
+            self._progress.beat(step=self._n_steps)
 
+    @_in_phase(PH_OTHER)
     def _collect_drafts(self) -> Dict[int, List[int]]:
         """Ask each active greedy lane's drafter for a proposal, clipped
         to the request's remaining budget (emits = accepts + 1 can never
@@ -2251,56 +2342,63 @@ class ServingEngine:
             sub,
             self._qweights,
         )
-        out = np.asarray(out)  # host sync — the loop's one device read
-        n_emit = np.asarray(n_emit)
+        clock = self._clock
+        with clock.phase(PH_DEVICE_WAIT):
+            out = np.asarray(out)  # host sync — the loop's one device read
+            n_emit = np.asarray(n_emit)
         emitted = 0
         n_proposed = n_accepted = 0
         observe = getattr(self.stats_registry, "observe", None)
-        for slot in np.nonzero(self._active)[0]:
-            slot = int(slot)
-            req = self._slot_req[slot]
-            e = int(n_emit[slot])
-            prop = drafts.get(slot)
-            if prop is not None:
-                n_proposed += len(prop)
-                n_accepted += e - 1
-                if observe is not None:
-                    observe("serving.spec_accept_len", float(e - 1))
-                self._trace_hot(
-                    req,
-                    "serving.spec.verify",
-                    time.time(),
-                    0.0,
-                    proposed=len(prop),
-                    accepted=e - 1,
-                )
-            self._pos[slot] += e
-            self._tok[slot] = int(out[slot, e - 1])
-            # Rollback: rows past the accept run are garbage; blocks
-            # wholly beyond the next write position go back to the pool.
-            truncate_table(
-                self._tables[slot],
-                self.block_allocator,
-                int(self._pos[slot]),
-                self.block_size,
-            )
-            for j in range(e):
-                self._emit(slot, req, int(out[slot, j]))
-                emitted += 1
-                if req.done.is_set():
-                    break  # eos/budget retired the slot mid-run
-        with self._stats_lock:
-            self._spec_steps += 1
-            self._spec_proposed += n_proposed
-            self._spec_accepted += n_accepted
-        incr = getattr(self.stats_registry, "incr", None)
-        if incr is not None:
-            if n_proposed:
-                incr("serving.spec_proposed_total", n_proposed)
-            if n_accepted:
-                incr("serving.spec_accepted_total", n_accepted)
+        with clock.phase(PH_EMIT):
+            for slot in np.nonzero(self._active)[0]:
+                slot = int(slot)
+                req = self._slot_req[slot]
+                e = int(n_emit[slot])
+                prop = drafts.get(slot)
+                if prop is not None:
+                    n_proposed += len(prop)
+                    n_accepted += e - 1
+                    with clock.phase(PH_BOOKKEEPING):
+                        if observe is not None:
+                            observe("serving.spec_accept_len", float(e - 1))
+                        self._trace_hot(
+                            req,
+                            "serving.spec.verify",
+                            time.time(),
+                            0.0,
+                            proposed=len(prop),
+                            accepted=e - 1,
+                        )
+                self._pos[slot] += e
+                self._tok[slot] = int(out[slot, e - 1])
+                # Rollback: rows past the accept run are garbage; blocks
+                # wholly beyond the next write position go back to the pool.
+                with clock.phase(PH_ALLOC):
+                    truncate_table(
+                        self._tables[slot],
+                        self.block_allocator,
+                        int(self._pos[slot]),
+                        self.block_size,
+                    )
+                for j in range(e):
+                    self._emit(slot, req, int(out[slot, j]))
+                    emitted += 1
+                    if req.done.is_set():
+                        break  # eos/budget retired the slot mid-run
+        with clock.phase(PH_BOOKKEEPING):
+            with self._stats_lock:
+                self._spec_steps += 1
+                self._spec_proposed += n_proposed
+                self._spec_accepted += n_accepted
+            incr = getattr(self.stats_registry, "incr", None)
+            if incr is not None:
+                if n_proposed:
+                    incr("serving.spec_proposed_total", n_proposed)
+                if n_accepted:
+                    incr("serving.spec_accepted_total", n_accepted)
         return emitted
 
+    @_in_phase(PH_BOOKKEEPING)
     def _record_gauges(self) -> None:
         """Refresh paging gauges + backlog counters (scheduler thread)."""
         self._check_steady_compiles()
@@ -2467,6 +2565,7 @@ class ServingEngine:
         if len(req.tokens) >= req.max_new_tokens or hit_eos:
             self._retire(slot, req)
 
+    @_in_phase(PH_ALLOC)
     def _release_slot_blocks(self, slot: int) -> None:
         """Drop the slot's reference on every block in its table.  Blocks
         a neighbor or the prefix cache still references stay allocated —

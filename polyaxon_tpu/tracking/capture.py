@@ -10,7 +10,12 @@ same hook trainers already give :class:`~polyaxon_tpu.tracking.profiling.
 StepProfiler`, and the serving engine gives its decode iterations:
 
 - an xplane trace (``jax.profiler.start_trace``/``stop_trace``) over the
-  requested step window, viewable with xprof / tensorboard-profile;
+  requested step window, viewable with xprof / tensorboard-profile.  The
+  profiler's Python tracer is off: it taxes the very thread whose gaps are
+  being read and names them by file and line.  Instead, while the trace is
+  on, the process tracer's ``profiler_hook`` is ``jax.profiler.
+  TraceAnnotation``, so the program's spans and the serving loop's phases
+  lie in the trace under names that stay put from commit to commit;
 - a device-memory snapshot (``jax.profiler.device_memory_profile``);
 - the HLO text of any AOT-compiled executables the workload registered
   (PR 7's ``aot_compile`` products).
@@ -19,6 +24,13 @@ Everything lands under ``profiles/<capture_id>/proc<N>/`` in the run dir
 (artifact-API visible, store-synced), and the lifecycle is reported as
 typed ``capture``/``command`` lines the watcher folds into the registry's
 ``captures``/``commands`` tables.
+
+Threads: ``on_step`` runs on the workload's thread and only starts the
+trace, counts steps and, when the window is full, hands the job over.
+Stopping the profiler and writing trace, memory profile, HLO and the
+manifest (last: readers wait on it) run on a writer thread of the
+agent's own, so neither the workload nor the heartbeat stands still
+while seconds of trace are serialized.
 
 Failure policy mirrors StepProfiler: profiling is diagnostics — any jax
 profiler failure degrades the capture (xplane skipped, noted in attrs)
@@ -40,6 +52,8 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
 
+from polyaxon_tpu.tracking.trace import get_tracer
+
 logger = logging.getLogger(__name__)
 
 _UNSET = object()
@@ -50,6 +64,8 @@ DEFAULT_NUM_STEPS = 5
 #: serving engine, a cmd-path worker with no step loop): at the deadline
 #: the poll thread finalizes with whatever was collected.
 DEFAULT_DURATION_S = 30.0
+#: How long ``close()`` waits for a write-out already in flight.
+WRITE_OUT_JOIN_S = 30.0
 
 
 class CaptureAgent:
@@ -61,6 +77,9 @@ class CaptureAgent:
         self.profiles_root: Optional[Path] = None
         self.process_id = 0
         self._lock = threading.RLock()
+        #: Wakes the writer thread when a job's state changes.
+        self._wake = threading.Condition(self._lock)
+        self._writer: Optional[threading.Thread] = None
         self._executables: Dict[str, Any] = {}
         self._job: Optional[Dict[str, Any]] = None
         self._handlers: Dict[str, Callable[[Dict[str, Any]], None]] = {
@@ -177,13 +196,21 @@ class CaptureAgent:
                 "num_steps": max(1, num_steps),
                 "deadline": time.time() + max(1.0, duration_s),
                 "out_dir": out_dir,
-                "state": "armed",  # armed → tracing → (finalized)
+                # armed → tracing → stopping (the writer's) | aborted
+                "state": "armed",
                 "start_step": None,
                 "steps_seen": 0,
                 "started_at": None,
                 "xplane": False,
                 "notes": {},
             }
+            self._writer = threading.Thread(
+                target=self._write_out,
+                args=(self._job,),
+                name="capture-writer",
+                daemon=True,
+            )
+            self._writer.start()
         self._emit_capture(
             capture_id,
             status="started",
@@ -197,19 +224,24 @@ class CaptureAgent:
         stopped (or never started) stepping."""
         with self._lock:
             job = self._job
-            if job is None or time.time() < job["deadline"]:
+            if (
+                job is None
+                or job["state"] == "stopping"
+                or time.time() < job["deadline"]
+            ):
                 return
             if job["state"] == "tracing":
-                self._stop_trace(job)
                 job["notes"]["window_truncated"] = True
             else:
                 job["notes"]["no_step_window"] = True
-            self._finalize(job)
+            self._hand_over(job)
 
     # -- workload-thread side -------------------------------------------------
     def on_step(self, step: int) -> None:
         """Call once per step/decode iteration; near-free while no capture
-        is armed (one attribute read)."""
+        is armed (one attribute read).  The first call of a window starts
+        the trace; the one that fills it hands the job to the writer
+        thread and returns before anything is written."""
         if self._job is None:
             return
         with self._lock:
@@ -220,28 +252,69 @@ class CaptureAgent:
                 job["state"] = "tracing"
                 job["start_step"] = step
                 job["started_at"] = time.time()
-                try:
-                    import jax
-
-                    jax.profiler.start_trace(str(job["out_dir"] / "xplane"))
-                    job["xplane"] = True
-                except Exception as e:
-                    # A launch-time StepProfiler window (or no profiler at
-                    # all) owns the singleton trace — degrade, don't die.
-                    logger.warning(
-                        "Capture %s: start_trace failed (%s); continuing "
-                        "without an xplane trace",
-                        job["capture_id"],
-                        e,
-                    )
-                    job["notes"]["xplane_error"] = f"{type(e).__name__}: {e}"
+                self._start_trace(job)
+            elif job["state"] != "tracing":
+                return
             job["steps_seen"] += 1
             if job["steps_seen"] >= job["num_steps"]:
-                self._stop_trace(job)
-                self._finalize(job)
+                self._hand_over(job)
+
+    def _start_trace(self, job: Dict[str, Any]) -> None:
+        """Start the xplane trace, Python tracer off, and put the program's
+        spans and phases on its clock (lock held, workload's thread)."""
+        t0 = time.perf_counter()
+        try:
+            import jax
+
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(
+                str(job["out_dir"] / "xplane"), profiler_options=options
+            )
+            job["xplane"] = True
+            get_tracer().profiler_hook = jax.profiler.TraceAnnotation
+        except Exception as e:
+            # A launch-time StepProfiler window (or no profiler at
+            # all) owns the singleton trace — degrade, don't die.
+            logger.warning(
+                "Capture %s: start_trace failed (%s); continuing "
+                "without an xplane trace",
+                job["capture_id"],
+                e,
+            )
+            job["notes"]["xplane_error"] = f"{type(e).__name__}: {e}"
+        job["notes"]["start_trace_s"] = round(time.perf_counter() - t0, 6)
+
+    def _hand_over(self, job: Dict[str, Any]) -> None:
+        """The window is over (lock held): the rest is the writer's.  The
+        annotations end here, not whenever the writer gets to run."""
+        job["state"] = "stopping"
+        get_tracer().profiler_hook = None
+        self._wake.notify_all()
+
+    # -- writer-thread side ---------------------------------------------------
+    def _write_out(self, job: Dict[str, Any]) -> None:
+        with self._wake:
+            while job["state"] in ("armed", "tracing"):
+                self._wake.wait()
+            if job["state"] != "stopping":  # aborted: close() resolves it
+                return
+        t0 = time.perf_counter()
+        self._stop_trace(job)
+        job["notes"]["stop_trace_s"] = round(time.perf_counter() - t0, 6)
+        self._finalize(job)
+
+    def wait_written(self, timeout: Optional[float] = None) -> bool:
+        """Block until the capture in flight, if any, is written out and
+        reported; False if it still is not after ``timeout`` seconds."""
+        writer = self._writer
+        if writer is not None:
+            writer.join(timeout)
+        return writer is None or not writer.is_alive()
 
     # -- finalization ---------------------------------------------------------
     def _stop_trace(self, job: Dict[str, Any]) -> None:
+        get_tracer().profiler_hook = None
         if not job.get("xplane"):
             return
         try:
@@ -321,17 +394,28 @@ class CaptureAgent:
             artifacts.append(_rel(manifest))
         except OSError as e:
             job["notes"]["manifest_error"] = f"{type(e).__name__}: {e}"
-        self._job = None
+        with self._lock:
+            self._job = None
         self._emit_capture_record(record)
         self._command_event(job["command_uuid"], "complete")
 
     def _abort(self, message: str) -> None:
         with self._lock:
-            job = self._job
+            job, writer = self._job, self._writer
             if job is None:
                 return
-            self._stop_trace(job)
-            self._job = None
+            writing = job["state"] == "stopping"
+            if not writing:
+                job["state"] = "aborted"
+                self._job = None
+                self._wake.notify_all()
+        if writing:
+            # The window was over: let the write-out report its own outcome.
+            writer.join(WRITE_OUT_JOIN_S)
+            if not writer.is_alive():
+                return
+            message = f"{message}; the write-out had not finished"
+        self._stop_trace(job)
         self._emit_capture(
             job["capture_id"],
             status="failed",

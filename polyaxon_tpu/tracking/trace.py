@@ -20,6 +20,15 @@ is the worker-side half of that answer:
   hot-path call sites (per step / per decode tick, gated on
   ``tracer.hot_sample``) cost about as much as a ``perf_counter`` call.
 
+- :class:`PhaseClock` (``tracer.phase_clock(...)``) is the accounting of ONE
+  thread's loop: an exclusive clock over a small fixed catalog of phases,
+  one ``perf_counter`` read per transition, plain seconds and counts that
+  sum to the loop's wall time.  The serving engine's loop runs on one.
+- ``tracer.profiler_hook`` puts both on the device profiler's clock: the
+  capture agent sets it to ``jax.profiler.TraceAnnotation`` while an xplane
+  trace is on (this module never imports jax), and every span and phase is
+  then also an annotation of its name in the ``.xplane.pb``.
+
 Process-wide singleton: library code calls :func:`get_tracer` and never
 configures it; the worker entrypoint calls :func:`configure` once with the
 report sink, its process id, and the run uuid.  Control-plane spans stay
@@ -44,12 +53,23 @@ import random
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    Iterable,
+    List,
+    Mapping,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 from polyaxon_tpu.conf.knobs import knob_float
 
 __all__ = [
     "Tracer",
+    "PhaseClock",
     "get_tracer",
     "configure",
     "chrome_trace",
@@ -182,6 +202,7 @@ class _Span:
         "parent_id",
         "_trace_id",
         "_explicit_parent",
+        "_annotation",
         "_t0",
         "_p0",
     )
@@ -193,6 +214,7 @@ class _Span:
         attrs: Dict[str, Any],
         trace_id: Optional[str] = None,
         parent_id: Optional[str] = None,
+        annotation: Any = None,
     ) -> None:
         self._tracer = tracer
         self.name = name
@@ -201,6 +223,7 @@ class _Span:
         self.parent_id: Optional[str] = parent_id
         self._trace_id = trace_id
         self._explicit_parent = parent_id is not None
+        self._annotation = annotation
         self._t0 = 0.0
         self._p0 = 0.0
 
@@ -215,12 +238,16 @@ class _Span:
             self.parent_id = stack[-1] if stack else None
         self.span_id = tracer.next_span_id()
         stack.append(self.span_id)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.time()
         self._p0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         duration = time.perf_counter() - self._p0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -240,6 +267,154 @@ class _Span:
             **self.attrs,
         )
         return False
+
+
+class _Phase:
+    """The reusable ``with`` target of one phase of a :class:`PhaseClock`;
+    ``as`` gives the ``perf_counter`` reading of the transition into it."""
+
+    __slots__ = ("_clock", "name")
+
+    def __init__(self, clock: "PhaseClock", name: str) -> None:
+        self._clock = clock
+        self.name = name
+
+    def __enter__(self) -> float:
+        clock = self._clock
+        stack = clock._stack
+        if not stack:  # clock off
+            return clock.t
+        clock._seq += 1
+        now = time.perf_counter()
+        clock._seconds[stack[-1]] += now - clock.t
+        clock.t = now
+        stack.append(self.name)
+        clock._counts[self.name] += 1
+        clock._seq += 1
+        hook = clock._tracer.profiler_hook
+        if hook is not None or clock._live is not None:
+            clock._mark(hook, self.name)
+        return now
+
+    def __exit__(self, *exc: Any) -> bool:
+        clock = self._clock
+        stack = clock._stack
+        if len(stack) < 2:  # clock off: the base phase is stop()'s to close
+            return False
+        clock._seq += 1
+        now = time.perf_counter()
+        clock._seconds[stack.pop()] += now - clock.t
+        clock.t = now
+        clock._seq += 1
+        hook = clock._tracer.profiler_hook
+        if hook is not None or clock._live is not None:
+            clock._mark(hook, stack[-1])
+        return False
+
+
+class PhaseClock:
+    """Exclusive self-time accounting of ONE thread over a fixed catalog.
+
+    ``start()`` opens the ``base`` phase; ``with clock.phase(name) as t:``
+    pauses whichever phase is open, runs ``name`` and resumes the outer one
+    at the exit, so every instant between ``start()`` and ``stop()`` belongs
+    to exactly one phase and the phases' seconds sum to the wall time.  A
+    transition reads ``perf_counter`` once and calls nothing else: ``t`` is
+    that reading (the phase's start), ``clock.t`` the latest one, and
+    ``clock.epoch + t`` the same instant on ``time.time()``'s clock.  While
+    the tracer's ``profiler_hook`` is set, each interval is also an
+    annotation of the phase's name, one after the other on the thread's row
+    of the device trace.  Off (before ``start()``, after ``stop()``) a phase
+    does nothing, so code that also runs without the loop (a test calling a
+    tick by hand, the drain after the thread is joined) needs no guard.
+
+    Only the owning thread enters phases.  Any thread may :meth:`snapshot`:
+    the owner brackets each transition, its clock read included, with two
+    increments of ``_seq`` (odd while one is in flight) and the reader retries
+    until it has read, its own clock read included, between two.
+    """
+
+    def __init__(self, tracer: "Tracer", names: Sequence[str], base: str) -> None:
+        if base not in names:
+            raise ValueError(f"base phase {base!r} is not in the catalog")
+        self._tracer = tracer
+        self.base = base
+        self._phases = {name: _Phase(self, name) for name in names}
+        self._seconds = {name: 0.0 for name in names}
+        self._counts = {name: 0 for name in names}
+        self._stack: List[str] = []
+        self._live: Any = None  # the open profiler annotation, if any
+        self._seq = 0
+        self._wall = 0.0  # of the start()..stop() spans already closed
+        self._started = 0.0
+        self.t = 0.0
+        self.epoch = 0.0
+
+    def phase(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def anchor(self) -> None:
+        """Re-read the offset between ``perf_counter`` and ``time.time()``
+        (the wall clock is slewed; call where the loop has time to spare)."""
+        self.epoch = time.time() - time.perf_counter()
+
+    def start(self) -> None:
+        if self._stack:
+            return
+        self.anchor()
+        self._seq += 1
+        self._started = self.t = time.perf_counter()
+        self._stack.append(self.base)
+        self._counts[self.base] += 1
+        self._seq += 1
+
+    def stop(self) -> None:
+        """Close every open phase (an exception may have left some)."""
+        if not self._stack:
+            return
+        self._seq += 1
+        now = time.perf_counter()
+        self._seconds[self._stack[-1]] += now - self.t
+        self._wall += now - self._started
+        self.t = now
+        del self._stack[:]
+        self._seq += 1
+        self._mark(None, "")
+
+    def _mark(self, hook: Optional[Callable[[str], Any]], name: str) -> None:
+        """Close the open annotation and, while a capture is on, open
+        ``name``'s.  A profiler that fails must never take the loop down."""
+        live, self._live = self._live, None
+        try:
+            if live is not None:
+                live.__exit__(None, None, None)
+            if hook is not None:
+                live = hook(name)
+                live.__enter__()
+                self._live = live
+        except Exception:
+            pass
+
+    def snapshot(self) -> Tuple[float, Dict[str, float], Dict[str, int]]:
+        """``(wall_s, seconds, counts)`` as of now: the interval in flight is
+        charged to the open phase, so the seconds sum to ``wall_s``."""
+        while True:
+            seq = self._seq
+            seconds = dict(self._seconds)
+            counts = dict(self._counts)
+            top = self._stack[-1:]
+            t, wall, started = self.t, self._wall, self._started
+            # Read inside the bracket: were "now" taken after it, a phase
+            # the owner closed meanwhile would be charged past its end and
+            # read lower in the next snapshot.
+            now = time.perf_counter()
+            if not seq & 1 and seq == self._seq:
+                break
+            time.sleep(0)  # let the owner finish its transition
+        if top:
+            seconds[top[0]] += now - t
+            wall += now - started
+        return wall, seconds, counts
 
 
 class Tracer:
@@ -273,6 +448,11 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._ids = itertools.count(1)
+        #: ``name -> context manager`` that writes a host event into the
+        #: device profiler's trace.  The capture agent sets it to
+        #: ``jax.profiler.TraceAnnotation`` while an xplane trace is on;
+        #: spans then ignore sampling and wrap it, as a PhaseClock's phases do.
+        self.profiler_hook: Optional[Callable[[str], Any]] = None
 
     # -- configuration ------------------------------------------------------
 
@@ -321,10 +501,21 @@ class Tracer:
         via the shared Random's C implementation) — a per-instance RNG
         here would be raced by concurrent HTTP handler threads.
         """
+        hook = self.profiler_hook
+        if hook is not None:
+            return _Span(
+                self, name, attrs, trace_id=trace_id, parent_id=parent_id,
+                annotation=hook(name),
+            )
         rate = self.sample if sample is None else sample
         if rate < 1.0 and (rate <= 0.0 or random.random() >= rate):
             return _NOOP
         return _Span(self, name, attrs, trace_id=trace_id, parent_id=parent_id)
+
+    def phase_clock(self, names: Sequence[str], base: str) -> PhaseClock:
+        """A :class:`PhaseClock` over ``names`` for the calling loop's
+        thread, annotated through this tracer's ``profiler_hook``."""
+        return PhaseClock(self, names, base)
 
     def next_span_id(self) -> str:
         """Allocate a span id unique within (and, when a process label is

@@ -136,5 +136,5 @@ class EngineLikeForwarders:
         self._trace_span(req, name, start, duration, **attrs)
 
     def prefill(self, req, t0, dt):
-        self._trace_span(req, "serving.prefill", t0, dt)
+        self._trace_span(req, "serving.prefill.chunk", t0, dt)
         self._trace_hot(req, "serving.decode.step", t0, dt)

@@ -5,7 +5,11 @@ Every test builds its own :class:`Tracer` — the process-global one (from
 reconfigured by tests.
 """
 
+import sys
 import threading
+import time
+
+import pytest
 
 from polyaxon_tpu.tracking.trace import (
     TRACEPARENT_HEADER,
@@ -369,3 +373,225 @@ class TestRecordSpan:
         assert rec["parent_id"] == "client.0.1"
         assert rec["span_id"] == sp.span_id
         assert rec["span_id"].startswith("router.")
+
+
+A, B, C, BASE = "loop.a", "loop.b", "loop.c", "loop.base"
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: the log holds what
+    was opened and closed, in order."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _Annotation.log.append(("open", self.name))
+
+    def __exit__(self, *exc):
+        _Annotation.log.append(("close", self.name))
+        return False
+
+
+def _nested(clock):
+    with clock.phase(A):
+        with clock.phase(B):
+            with clock.phase(B):
+                pass
+        with clock.phase(C):
+            pass
+
+
+def _raises(clock):
+    with pytest.raises(ZeroDivisionError):
+        with clock.phase(A):
+            with clock.phase(B):
+                1 / 0
+    with clock.phase(C):
+        pass
+
+
+def _flat(clock):
+    for name in (A, B, C, A):
+        with clock.phase(name):
+            pass
+
+
+class TestPhaseClock:
+    """The exclusive clock alone: no timing is asserted, only that the
+    accounting adds up."""
+
+    def _clock(self, tracer=None):
+        clock = (tracer or Tracer()).phase_clock([A, B, C, BASE], BASE)
+        clock.start()
+        return clock
+
+    @pytest.mark.parametrize(
+        "body, counts",
+        [
+            (_nested, {A: 1, B: 2, C: 1, BASE: 1}),
+            (_raises, {A: 1, B: 1, C: 1, BASE: 1}),
+            (_flat, {A: 2, B: 1, C: 1, BASE: 1}),
+        ],
+        ids=["nested", "exception", "flat"],
+    )
+    def test_phases_sum_to_the_wall_time(self, body, counts):
+        clock = self._clock()
+        body(clock)
+        # Mid-run: the open phase is charged up to now.
+        wall, seconds, seen = clock.snapshot()
+        assert seen == counts
+        assert clock._stack == [BASE]  # whatever happened inside
+        assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+        clock.stop()
+        wall, seconds, _ = clock.snapshot()
+        assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+        assert all(v >= 0.0 for v in seconds.values()) and wall > 0.0
+        assert clock.snapshot()[0] == wall  # stopped: the wall stands still
+
+    def test_entering_an_inner_phase_pauses_the_outer_one(self, monkeypatch):
+        from types import SimpleNamespace
+
+        from polyaxon_tpu.tracking import trace as trace_mod
+
+        now = [100.0]
+        monkeypatch.setattr(trace_mod, "time", SimpleNamespace(
+            perf_counter=lambda: now[0], time=lambda: 1e9 + now[0], sleep=time.sleep))
+        clock = self._clock()
+        now[0] = 101.0  # one second of the base phase
+        with clock.phase(A) as t_a:
+            now[0] = 103.0  # two of A
+            with clock.phase(B) as t_b:
+                now[0] = 108.0  # five of B, while A stands still
+            now[0] = 109.0  # one more of A
+        now[0] = 110.0  # one more of the base phase
+        assert (t_a, t_b, clock.t) == (101.0, 103.0, 109.0)
+        assert clock.snapshot() == (10.0, {A: 3.0, B: 5.0, C: 0.0, BASE: 2.0},
+                                    {A: 1, B: 1, C: 0, BASE: 1})
+        clock.stop()
+        now[0] = 200.0
+        assert clock.snapshot()[0] == 10.0
+        assert clock.epoch + t_a == 1e9 + 101.0
+
+    @pytest.mark.parametrize("when", ["before_start", "after_stop"])
+    def test_off_the_clock_a_phase_does_nothing(self, when):
+        clock = Tracer().phase_clock([A, BASE], BASE)
+        if when == "after_stop":
+            clock.start()
+            clock.stop()
+        frozen = clock.snapshot()
+        with clock.phase(A):
+            with clock.phase(A):
+                pass
+        assert clock.snapshot() == frozen and clock._stack == []
+
+    def test_unknown_base_is_refused(self):
+        with pytest.raises(ValueError, match="base phase"):
+            Tracer().phase_clock([A], BASE)
+
+    def test_snapshot_from_another_thread_is_consistent(self):
+        """The reader retries around transitions in flight: whatever it
+        sees, the seconds sum to the wall time and never shrink."""
+        clock_box, stop = [], threading.Event()
+
+        def owner():
+            clock = self._clock()
+            clock_box.append(clock)
+            while not stop.is_set():
+                _nested(clock)
+            clock.stop()
+
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        thread = threading.Thread(target=owner, daemon=True)
+        try:
+            thread.start()
+            while not clock_box:
+                time.sleep(0.001)
+            clock, last = clock_box[0], None
+            for _ in range(2000):
+                wall, seconds, counts = clock.snapshot()
+                assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+                if last is not None:
+                    assert wall >= last[0]
+                    assert all(seconds[k] >= last[1][k] for k in seconds)
+                    assert all(counts[k] >= last[2][k] for k in counts)
+                last = (wall, seconds, counts)
+        finally:
+            stop.set()
+            thread.join(10)
+            sys.setswitchinterval(switch)
+        assert not thread.is_alive()
+
+
+class TestProfilerHook:
+    """While the hook is set, spans ignore sampling and wrap an annotation,
+    and a clock's phases are annotations one after the other."""
+
+    def setup_method(self):
+        _Annotation.log = []
+
+    @pytest.mark.parametrize("sample", [1.0, 0.0])
+    def test_span_wraps_the_annotation_whatever_the_sampling(self, sample):
+        t = Tracer(sample=sample)
+        with t.span("x.y"):
+            pass
+        assert _Annotation.log == []
+        t.profiler_hook = _Annotation
+        with t.span("x.y", sample=sample):
+            pass
+        t.profiler_hook = None
+        with t.span("x.y"):
+            pass
+        assert _Annotation.log == [("open", "x.y"), ("close", "x.y")]
+        assert len(t.spans()) == (3 if sample else 1)
+
+    def test_phases_are_annotated_one_after_the_other(self):
+        t = Tracer()
+        clock = t.phase_clock([A, B, BASE], BASE)
+        clock.start()
+        with clock.phase(A):
+            pass
+        assert _Annotation.log == []
+        t.profiler_hook = _Annotation
+        with clock.phase(A):
+            with clock.phase(B):
+                pass
+        t.profiler_hook = None
+        with clock.phase(A):  # closes what was open, opens nothing
+            pass
+        clock.stop()
+        names = [n for kind, n in _Annotation.log if kind == "open"]
+        assert names == [A, B, A, BASE]
+        # Exclusive on the profiler's clock too: never two open at once.
+        depth = 0
+        for kind, _ in _Annotation.log:
+            depth += 1 if kind == "open" else -1
+            assert depth in (0, 1)
+        assert depth == 0
+
+    def test_a_profiler_that_raises_does_not_reach_the_loop(self):
+        def broken(name):
+            raise RuntimeError("no profiler")
+
+        t = Tracer()
+        clock = t.phase_clock([A, BASE], BASE)
+        clock.start()
+        t.profiler_hook = broken
+        with clock.phase(A):
+            pass
+        clock.stop()
+        wall, seconds, counts = clock.snapshot()
+        assert counts[A] == 1
+        assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+
+    def test_trace_module_stays_free_of_jax(self):
+        import subprocess
+
+        code = (
+            "import sys; import polyaxon_tpu.tracking.trace; "
+            "sys.exit(1 if 'jax' in sys.modules else 0)"
+        )
+        assert subprocess.run([sys.executable, "-c", code], timeout=60).returncode == 0
