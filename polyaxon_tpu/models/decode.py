@@ -442,40 +442,50 @@ def _kv_dequant(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 
 def _pool_append(
-    pool_l: Dict[str, jax.Array],
+    pool: Dict[str, jax.Array],
     name: str,
+    layer_idx: jax.Array,
     rows: jax.Array,
     write_blk: jax.Array,
     write_off: jax.Array,
 ) -> Dict[str, jax.Array]:
-    """Scatter freshly-computed KV rows for one layer into per-layer pool
-    leaves at (write_blk, write_off), quantizing on append for the int8
-    layout.  ``rows``: [..., Hkv, d] aligned with write_blk/write_off
-    [...] — one index pair per row, any leading shape (a decode step's
-    [S], a verify step's [S, T])."""
-    if name + "_q" in pool_l:
+    """Scatter freshly-computed KV rows for layer ``layer_idx`` into the
+    STACKED pool leaves at (layer_idx, write_blk, write_off), quantizing
+    on append for the int8 layout.  ``rows``: [..., Hkv, d] aligned with
+    write_blk/write_off [...] — one index pair per row, any leading shape
+    (a decode step's [S], a verify step's [S, T]).  The pool is the layer
+    loop's carry, so the scatter updates the donated buffer in place: no
+    per-layer slice is ever cut out or stacked back."""
+    at = (layer_idx, write_blk, write_off)
+    if name + "_q" in pool:
         q, scale = _kv_quant(rows)
         return {
-            **pool_l,
-            name + "_q": pool_l[name + "_q"].at[write_blk, write_off].set(q),
-            name + "_scale": pool_l[name + "_scale"]
-            .at[write_blk, write_off]
-            .set(scale),
+            **pool,
+            name + "_q": pool[name + "_q"].at[at].set(q),
+            name + "_scale": pool[name + "_scale"].at[at].set(scale),
         }
-    leaf = pool_l[name]
-    return {**pool_l, name: leaf.at[write_blk, write_off].set(rows.astype(leaf.dtype))}
+    leaf = pool[name]
+    return {**pool, name: leaf.at[at].set(rows.astype(leaf.dtype))}
 
 
 def _pool_gather(
-    pool_l: Dict[str, jax.Array], name: str, table: jax.Array, dtype
+    pool: Dict[str, jax.Array],
+    name: str,
+    layer_idx: jax.Array,
+    table: jax.Array,
+    dtype,
 ) -> jax.Array:
-    """Gather a layer's KV rows for a block table, dequantizing int8
-    leaves fused into the read.  table [..., W] → [..., W, bs, Hkv, d]."""
-    if name + "_q" in pool_l:
+    """Gather layer ``layer_idx``'s KV rows for a block table straight out
+    of the stacked pool leaves — ONE gather addressed by (layer, block) —
+    dequantizing int8 leaves fused into the read.
+    table [..., W] → [..., W, bs, Hkv, d]."""
+    if name + "_q" in pool:
         return _kv_dequant(
-            pool_l[name + "_q"][table], pool_l[name + "_scale"][table], dtype
+            pool[name + "_q"][layer_idx, table],
+            pool[name + "_scale"][layer_idx, table],
+            dtype,
         )
-    return pool_l[name][table]
+    return pool[name][layer_idx, table]
 
 
 def copy_block(
@@ -562,6 +572,12 @@ def paged_prefill_chunk(
     Numerics mirror the training ``forward`` block exactly (broadcast GQA
     heads, ``_dense_attention``'s masked f32 softmax), so greedy outputs
     stay token-identical to the sequential :func:`generate` path.
+
+    The layer loop CARRIES ``(x, pool)`` and scans over the weights and
+    the layer index only: each layer writes and reads the stacked leaves
+    at ``[layer, block, offset]`` in place, so with the pool donated the
+    buffer that enters is the one that leaves — no per-layer slice, no
+    second stacked pool.
     """
     from polyaxon_tpu.models.transformer import _dense_attention
 
@@ -582,8 +598,9 @@ def paged_prefill_chunk(
     x = params["embed"].astype(c.dtype)[tokens][None]  # [1, C, D]
     positions = qpos[None]  # [1, C]
 
-    def layer_body(x, inputs):
-        layer, pool_l = inputs  # pool_l leaves: [NB, bs, Hkv, ...]
+    def layer_body(carry, inputs):
+        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
+        layer, li = inputs
         h = _rmsnorm(x, layer["attn_norm"])
         q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
         k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
@@ -592,10 +609,10 @@ def paged_prefill_chunk(
         k = _rope(k, positions, c.rope_theta)
         # Write the chunk's KV rows, then attend against the whole table —
         # the rows just written ARE the chunk's causal self-attention keys.
-        pool_l = _pool_append(pool_l, "k", k[0], write_blk, write_off)
-        pool_l = _pool_append(pool_l, "v", v[0], write_blk, write_off)
-        ck = _pool_gather(pool_l, "k", table, h.dtype).reshape(1, W * bs, Hkv, d)
-        cv = _pool_gather(pool_l, "v", table, h.dtype).reshape(1, W * bs, Hkv, d)
+        pool = _pool_append(pool, "k", li, k[0], write_blk, write_off)
+        pool = _pool_append(pool, "v", li, v[0], write_blk, write_off)
+        ck = _pool_gather(pool, "k", li, table, h.dtype).reshape(1, W * bs, Hkv, d)
+        cv = _pool_gather(pool, "v", li, table, h.dtype).reshape(1, W * bs, Hkv, d)
         if group > 1:
             ck = jnp.repeat(ck, group, axis=2)
             cv = jnp.repeat(cv, group, axis=2)
@@ -607,9 +624,11 @@ def paged_prefill_chunk(
         gate = jnp.einsum("btd,df->btf", h, layer["wg"].astype(h.dtype))
         y = jax.nn.silu(gate) * up
         x = x + jnp.einsum("btf,fd->btd", y, layer["wd"].astype(h.dtype))
-        return x, pool_l
+        return (x, pool), None
 
-    x, new_pool = lax.scan(layer_body, x, (params["block"], pool))
+    (x, new_pool), _ = lax.scan(
+        layer_body, (x, pool), (params["block"], jnp.arange(c.n_layers))
+    )
     x = _rmsnorm(x, params["final_norm"])
     logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(x.dtype))
     last = jnp.take(logits[0], length - 1, axis=0)
@@ -656,6 +675,10 @@ def paged_decode_step(
     (slots, pool size, table width): steady-state serving never
     recompiles, whichever requests come and go or how their blocks are
     scattered across the pool.
+
+    The layer loop carries ``(x, pool)`` — the whole pool, updated in
+    place at ``[layer, block, offset]`` — and scans over the weights and
+    the layer index; see :func:`paged_prefill_chunk`.
     """
     c = cfg
     S, W = tables.shape
@@ -679,8 +702,8 @@ def paged_decode_step(
         unembed = qweights["unembed"]
 
     def layer_body(carry, inputs):
-        x = carry
-        layer, pool_l = inputs  # pool_l leaves: [NB, bs, Hkv, ...]
+        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
+        layer, li = inputs
         h = _rmsnorm(x, layer["attn_norm"])
         q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
         k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
@@ -688,10 +711,10 @@ def paged_decode_step(
         positions = pos[:, None]  # [S, 1]
         q = _rope(q, positions, c.rope_theta)
         k = _rope(k, positions, c.rope_theta)
-        pool_l = _pool_append(pool_l, "k", k[:, 0], write_blk, write_off)
-        pool_l = _pool_append(pool_l, "v", v[:, 0], write_blk, write_off)
-        ck = _pool_gather(pool_l, "k", tables, h.dtype).reshape(S, W * bs, Hkv, d)
-        cv = _pool_gather(pool_l, "v", tables, h.dtype).reshape(S, W * bs, Hkv, d)
+        pool = _pool_append(pool, "k", li, k[:, 0], write_blk, write_off)
+        pool = _pool_append(pool, "v", li, v[:, 0], write_blk, write_off)
+        ck = _pool_gather(pool, "k", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
+        cv = _pool_gather(pool, "v", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
         attn = _attend_paged(q, ck, cv, pos, c.n_heads // c.kv_heads)
         x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
 
@@ -700,9 +723,11 @@ def paged_decode_step(
         gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
         y = jax.nn.silu(gate) * up
         x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-        return x, pool_l
+        return (x, pool), None
 
-    x, new_pool = lax.scan(layer_body, x, (layers, pool))
+    (x, new_pool), _ = lax.scan(
+        layer_body, (x, pool), (layers, jnp.arange(c.n_layers))
+    )
     x = _rmsnorm(x, params["final_norm"])
     logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
     return logits[:, 0].astype(jnp.float32), new_pool
@@ -771,6 +796,8 @@ def paged_verify_step(
     weight streaming (int8 qweights compose), same ``_pool_append`` /
     ``_pool_gather`` (int8 KV pools compose), same masked f32 softmax —
     so greedy outputs stay token-identical to the non-speculative path.
+    The layer loop carries ``(x, pool)`` the same way: the whole pool,
+    written and read in place by (layer, block, offset).
     """
     c = cfg
     S, W = tables.shape
@@ -801,8 +828,8 @@ def paged_verify_step(
         unembed = qweights["unembed"]
 
     def layer_body(carry, inputs):
-        x = carry
-        layer, pool_l = inputs  # pool_l leaves: [NB, bs, Hkv, ...]
+        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
+        layer, li = inputs
         h = _rmsnorm(x, layer["attn_norm"])
         q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
         k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
@@ -811,10 +838,10 @@ def paged_verify_step(
         k = _rope(k, qpos, c.rope_theta)
         # Write every row, then gather: rows written earlier in the run
         # ARE later rows' causal keys, exactly like a prefill chunk.
-        pool_l = _pool_append(pool_l, "k", k, write_blk, write_off)
-        pool_l = _pool_append(pool_l, "v", v, write_blk, write_off)
-        ck = _pool_gather(pool_l, "k", tables, h.dtype).reshape(S, W * bs, Hkv, d)
-        cv = _pool_gather(pool_l, "v", tables, h.dtype).reshape(S, W * bs, Hkv, d)
+        pool = _pool_append(pool, "k", li, k, write_blk, write_off)
+        pool = _pool_append(pool, "v", li, v, write_blk, write_off)
+        ck = _pool_gather(pool, "k", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
+        cv = _pool_gather(pool, "v", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
         attn = _attend_spec(q, ck, cv, qpos, c.n_heads // c.kv_heads)
         x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
 
@@ -823,9 +850,11 @@ def paged_verify_step(
         gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
         y = jax.nn.silu(gate) * up
         x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-        return x, pool_l
+        return (x, pool), None
 
-    x, new_pool = lax.scan(layer_body, x, (layers, pool))
+    (x, new_pool), _ = lax.scan(
+        layer_body, (x, pool), (layers, jnp.arange(c.n_layers))
+    )
     x = _rmsnorm(x, params["final_norm"])
     logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
     return logits.astype(jnp.float32), new_pool
