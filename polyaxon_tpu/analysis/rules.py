@@ -861,6 +861,9 @@ _SPAN_NAMES = {
     "serving.loop.decode_host", "serving.loop.device_wait",
     "serving.loop.emit", "serving.loop.bookkeeping", "serving.loop.other",
     "serving.loop.idle",
+    # ... and the two more of an engine whose model has recurrent layers
+    # (serving/engine.py:STATE_PHASES)
+    "serving.state.snapshot", "serving.state.restore",
     # fleet router
     "router.request", "router.attempt",
 }

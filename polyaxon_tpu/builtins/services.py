@@ -19,7 +19,6 @@ import subprocess
 import sys
 import time
 from pathlib import Path
-
 from polyaxon_tpu.tracking import Context
 
 
@@ -408,6 +407,10 @@ def serve_engine(server, engine) -> None:
         )
 
 
+def _truthy(value) -> bool:
+    return str(value).lower() not in ("0", "false", "no", "off", "")
+
+
 def lm_server(ctx: Context) -> None:
     """LM inference endpoint: the default ``kind: service`` entrypoint.
 
@@ -466,6 +469,21 @@ def lm_server(ctx: Context) -> None:
     prefix-warm; ``kv_persist: true`` defaults the dir from the store
     layout).  The decode step's shapes depend only on (slots, pool
     size) — steady-state serving never recompiles.
+
+    A model with linear-attention layers (``models/hybrid.py``) is
+    declared by ``layer_types`` (a list or comma-separated string, one
+    ``linear_attention`` / ``full_attention`` a layer), the sizes
+    ``linear_num_key_heads`` / ``linear_num_value_heads`` /
+    ``linear_key_head_dim`` / ``linear_value_head_dim`` /
+    ``linear_conv_kernel_dim``, ``linear_allow_neg_eigval`` and ``rope``
+    (``0``: the full layers apply no rotary embedding).  Such a model
+    keeps a float32 recurrent state per slot beside the KV pool;
+    ``state_snapshot_every`` (tokens, a multiple of ``block_size``) and
+    ``state_snapshots`` (places in the device store) size the snapshots
+    a prefix hit resumes from — one costs the state of every linear
+    layer, and a hit is cut back to the newest one (docs/serving.md).
+    It refuses ``spec_decode``, ``kv_offload``, ``kv_persist`` and a
+    multi-chip mesh with a ``RecurrentStateError`` naming the option.
     """
     import jax
 
@@ -482,6 +500,27 @@ def lm_server(ctx: Context) -> None:
         if ctx.get_param(f) is not None
     }
     seq = int(ctx.get_param("seq", 512))
+    # A layer pattern selects the hybrid stack (models/hybrid.py): which
+    # layers are gated-delta-rule ("linear_attention") and which full
+    # attention, the linear layers' sizes under the published configs'
+    # names, and ``rope: 0`` for full layers without rotary embedding.
+    layer_types = ctx.get_param("layer_types")
+    if layer_types is not None:
+        if isinstance(layer_types, str):
+            layer_types = [t.strip() for t in layer_types.split(",") if t.strip()]
+        cfg_fields["layer_types"] = tuple(str(t) for t in layer_types)
+        for f in (
+            "linear_num_key_heads", "linear_num_value_heads",
+            "linear_key_head_dim", "linear_value_head_dim",
+            "linear_conv_kernel_dim",
+        ):
+            if ctx.get_param(f) is not None:
+                cfg_fields[f] = int(ctx.get_param(f))
+        cfg_fields["linear_allow_neg_eigval"] = _truthy(
+            ctx.get_param("linear_allow_neg_eigval", False)
+        )
+        if not _truthy(ctx.get_param("rope", True)):
+            cfg_fields["rope_theta"] = None
     cfg = TransformerConfig(max_seq=seq, **cfg_fields)
     params = init_params(jax.random.PRNGKey(ctx.seed or 0), cfg)
 
@@ -503,6 +542,10 @@ def lm_server(ctx: Context) -> None:
     template = None
     param_shardings = None
     if mesh is not None and mesh.size > 1:
+        if cfg.layer_types is not None:
+            from polyaxon_tpu.models.hybrid import RecurrentStateError
+
+            raise RecurrentStateError("mesh")
         from polyaxon_tpu.models.decode import decode_param_shardings
         from polyaxon_tpu.parallel import template_for
 
@@ -636,6 +679,9 @@ def lm_server(ctx: Context) -> None:
         ),
         kv_persist_dir=str(kv_persist_dir) if kv_persist_dir else None,
         kv_persist_sig=kv_persist_sig,
+        # 0 / unset = the engine's defaults (docs/serving.md)
+        state_snapshot_every=int(ctx.get_param("state_snapshot_every", 0) or 0),
+        state_snapshots=int(ctx.get_param("state_snapshots", 0) or 0),
         # The process-wide registry: /metrics then also exports anything
         # else this worker records (pipeline waits, task timings).
         stats=stats_backends.get_stats(),

@@ -87,6 +87,10 @@ def quantize_weights(params: Dict[str, Any]) -> Dict[str, Any]:
         return (jnp.asarray(qi), jnp.asarray(scale))
 
     blk = params["block"]
+    if "linear" in blk:  # the hybrid stack: its own tree, both kinds of layer
+        from polyaxon_tpu.models import hybrid
+
+        return hybrid.quantize_weights(params, q)
     out = {
         name: q(blk[name], axes)
         for name, axes in QUANTIZED_BLOCK_WEIGHTS.items()
@@ -380,7 +384,7 @@ def init_block_pool(
     memory budget.
     """
     c = cfg
-    shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.head_dim)
+    shape = (c.n_kv_layers, num_blocks, block_size, c.pool_kv_heads, c.head_dim)
     if kv_dtype is None:
         return {
             "k": jnp.zeros(shape, c.dtype),
@@ -416,7 +420,7 @@ def kv_block_bytes(
     budget B the pool holds ``B // kv_block_bytes(...)`` blocks.
     """
     c = cfg
-    rows = c.n_layers * block_size * c.kv_heads  # head-rows per block
+    rows = c.n_kv_layers * block_size * c.pool_kv_heads  # head-rows per block
     if kv_dtype is None:
         return 2 * rows * c.head_dim * jnp.dtype(c.dtype).itemsize
     if str(kv_dtype) != "int8":
@@ -488,6 +492,15 @@ def _pool_gather(
     return pool[name][layer_idx, table]
 
 
+def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The leaves addressed by block: all but a hybrid model's per-slot
+    recurrent rows (``models/hybrid.py:REC_LEAVES``), which ride the same
+    dict."""
+    from polyaxon_tpu.models.hybrid import REC_LEAVES
+
+    return {n: leaf for n, leaf in pool.items() if n not in REC_LEAVES}
+
+
 def copy_block(
     pool: Dict[str, jax.Array], src: jax.Array, dst: jax.Array
 ) -> Dict[str, jax.Array]:
@@ -497,8 +510,8 @@ def copy_block(
     every COW reuses one compilation.  Generic over the pool layout: an
     int8 pool's quantized rows and scales copy bit-exact, so a COW'd
     block dequantizes identically to the shared original."""
-    out = {}
-    for name, leaf in pool.items():
+    out = dict(pool)
+    for name, leaf in _kv_leaves(pool).items():
         sl = lax.dynamic_slice_in_dim(leaf, src, 1, axis=1)
         idx = (0, dst) + (0,) * (leaf.ndim - 2)
         out[name] = lax.dynamic_update_slice(leaf, sl, idx)
@@ -523,7 +536,7 @@ def export_block(
     payload is actually needed)."""
     return {
         name: lax.dynamic_slice_in_dim(leaf, src, 1, axis=1)[:, 0]
-        for name, leaf in pool.items()
+        for name, leaf in _kv_leaves(pool).items()
     }
 
 
@@ -538,8 +551,8 @@ def import_block(
     round trip is bit-exact for both pool layouts (values never
     requantize; only the block's address changes).  Jit with the pool
     donated, like every other pool-mutating fn."""
-    out = {}
-    for name, leaf in pool.items():
+    out = dict(pool)
+    for name, leaf in _kv_leaves(pool).items():
         blk = jnp.expand_dims(data[name].astype(leaf.dtype), 1)
         idx = (0, dst) + (0,) * (leaf.ndim - 2)
         out[name] = lax.dynamic_update_slice(leaf, blk, idx)
@@ -554,6 +567,7 @@ def paged_prefill_chunk(
     start: jax.Array,
     length: jax.Array,
     cfg: TransformerConfig,
+    slot: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Insert one prompt chunk into a paged cache and return the logits of
     its last REAL token.
@@ -578,7 +592,18 @@ def paged_prefill_chunk(
     at ``[layer, block, offset]`` in place, so with the pool donated the
     buffer that enters is the one that leaves — no per-layer slice, no
     second stacked pool.
+
+    A model with a layer pattern (``cfg.layer_types``) goes through
+    ``models/hybrid.py``'s form of this program instead: it also needs the
+    ``slot`` whose recurrent rows (leaves of the same pool) the chunk
+    advances.
     """
+    if cfg.layer_types is not None:
+        from polyaxon_tpu.models import hybrid
+
+        return hybrid.paged_prefill_chunk(
+            params, pool, table, tokens, start, length, slot, cfg
+        )
     from polyaxon_tpu.models.transformer import _dense_attention
 
     c = cfg
@@ -680,6 +705,12 @@ def paged_decode_step(
     place at ``[layer, block, offset]`` — and scans over the weights and
     the layer index; see :func:`paged_prefill_chunk`.
     """
+    if cfg.layer_types is not None:
+        from polyaxon_tpu.models import hybrid
+
+        return hybrid.paged_decode_step(
+            params, pool, tables, tokens, pos, active, cfg, qweights=qweights
+        )
     c = cfg
     S, W = tables.shape
     bs, Hkv, d = pool_geometry(pool)
@@ -799,6 +830,10 @@ def paged_verify_step(
     The layer loop carries ``(x, pool)`` the same way: the whole pool,
     written and read in place by (layer, block, offset).
     """
+    if cfg.layer_types is not None:
+        from polyaxon_tpu.models.hybrid import RecurrentStateError
+
+        raise RecurrentStateError("spec_decode")
     c = cfg
     S, W = tables.shape
     T = tokens.shape[1]
@@ -1014,6 +1049,11 @@ def generate(
     """
     if cfg.n_experts:
         raise NotImplementedError("MoE decoding is not supported yet")
+    if cfg.layer_types is not None:
+        raise NotImplementedError(
+            "a model with a layer pattern is served through the paged "
+            "programs (ServingEngine), not through generate()"
+        )
     B, T = prompt.shape
     max_len = T + max_new_tokens
     if max_len > cfg.max_seq:

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +43,9 @@ class TransformerConfig:
     head_dim: int = 64
     d_ff: int = 2048
     max_seq: int = 1024
-    rope_theta: float = 10000.0
+    #: ``None`` = no rotary embedding (the hybrid stack only: there the
+    #: recurrent layers carry the positions).
+    rope_theta: Optional[float] = 10000.0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     #: 0 = dense MLP; >0 = MoE with this many experts (top-1 switch routing)
@@ -92,6 +94,16 @@ class TransformerConfig:
                 f"n_heads ({self.n_heads}) must be divisible by n_kv_heads "
                 f"({self.kv_heads})"
             )
+        if self.layer_types is not None:
+            from polyaxon_tpu.models.hybrid import check_config
+
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            check_config(self)
+        elif self.rope_theta is None:
+            raise ValueError(
+                "rope_theta=None (no rotary embedding) needs a layer pattern: "
+                "the dense block has no other source of positions"
+            )
 
     @property
     def kv_heads(self) -> int:
@@ -111,14 +123,51 @@ class TransformerConfig:
     #: [B,chunk] slice at a time under jax.checkpoint, so the backward
     #: recomputes each chunk's logits instead of keeping them resident.
     ce_chunk: int = 0
+    #: A layer pattern makes this the HYBRID stack of ``models/hybrid.py``
+    #: (served through the paged programs only): one entry per layer,
+    #: ``"linear_attention"`` (gated delta rule, sized by the ``linear_*``
+    #: fields below, named as the published configs name them) or
+    #: ``"full_attention"``, a whole number of periods.  ``None`` = every
+    #: layer the dense block of this module.
+    layer_types: Optional[Tuple[str, ...]] = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    #: beta in (0, 2) instead of (0, 1): the state transition may reflect.
+    linear_allow_neg_eigval: bool = False
 
     def scaled(self, **overrides) -> "TransformerConfig":
         return replace(self, **overrides)
 
     @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep KV in the paged pool: the full-attention ones."""
+        if self.layer_types is None:
+            return self.n_layers
+        return sum(1 for t in self.layer_types if t == "full_attention")
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """KV heads a paged-pool row holds.  The hybrid stack pads them to a
+        multiple of 8: with 30 heads the TPU compiler keeps the pool in a
+        layout of its own choosing and copies the whole pool in and out of
+        every program to get there (chipless v5e compile: 6 pool-sized copies
+        in a decode step, 8 in a chunk; none at 32).  The dense model's pool
+        is as it was."""
+        if self.layer_types is None:
+            return self.kv_heads
+        return -(-self.kv_heads // 8) * 8
+
+    @property
     def n_params(self) -> int:
         """Parameter count (for MFU math)."""
         c = self
+        if c.layer_types is not None:
+            from polyaxon_tpu.models.hybrid import n_params
+
+            return n_params(c)
         attn = c.d_model * c.head_dim * (2 * c.n_heads + 2 * c.kv_heads)
         if c.n_experts:
             mlp = c.d_model * c.n_experts + c.n_experts * c.d_model * c.d_ff * 3
@@ -161,6 +210,10 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     c = cfg
+    if c.layer_types is not None:
+        from polyaxon_tpu.models import hybrid
+
+        return hybrid.init_params(key, c)
     k = iter(jax.random.split(key, 16))
     dt = c.param_dtype
 
@@ -342,6 +395,11 @@ def forward(
     each shard's global token positions.
     """
     c = cfg
+    if c.layer_types is not None:
+        raise NotImplementedError(
+            "a model with a layer pattern has no training forward: it is "
+            "served through the paged programs (models/hybrid.py)"
+        )
     rules: AxisRules = template.rules if template is not None else {}
     ring_axis = template.ring_axis if template is not None else None
     pipeline_axis = template.pipeline_axis if template is not None else None

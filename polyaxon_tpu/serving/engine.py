@@ -63,6 +63,7 @@ from polyaxon_tpu.serving.paging import (
     BlockAllocator,
     HostKVTier,
     PrefixCache,
+    StateSnapshots,
     truncate_table,
 )
 from polyaxon_tpu.stats import MemoryStats
@@ -90,6 +91,11 @@ LOOP_PHASES = (
     PH_MATCH, PH_OFFER, PH_ALLOC, PH_ADMIT, PH_PREFILL_HOST, PH_DECODE_HOST,
     PH_DEVICE_WAIT, PH_EMIT, PH_BOOKKEEPING, PH_OTHER, PH_IDLE,
 )
+#: Two more on the clock of an engine whose model has recurrent layers (a
+#: dense model's clock, and its ``/v1/stats``, have neither).
+PH_SNAPSHOT = "serving.state.snapshot"  # copying a slot's recurrent rows into the store
+PH_RESTORE = "serving.state.restore"  # copying a snapshot into an admitted slot's rows
+STATE_PHASES = (PH_SNAPSHOT, PH_RESTORE)
 
 
 def _in_phase(phase: str):
@@ -432,6 +438,18 @@ class ServingEngine:
         themselves (geometry can't tell checkpoints apart, so an
         unsigned store is never written).
         Defaults read the ``POLYAXON_TPU_KV_PERSIST_*`` knobs (off).
+    state_snapshot_every / state_snapshots : a model with linear-attention
+        layers (``cfg.layer_types``) keeps, beside the KV pool, a float32
+        recurrent state per slot, and can resume a cached prefix only
+        where that state was kept.  The engine copies a slot's state into
+        a preallocated device store wherever a prefill chunk ends on a
+        multiple of ``state_snapshot_every`` tokens (a multiple of the
+        block size; chunks are cut to end there; default: the prefill
+        chunk, else 1024) and the store holds ``state_snapshots`` of them
+        (default 4 per slot; least recently used goes first).  A prefix
+        hit is cut back to the newest snapshot on its chain.  Such a model
+        refuses ``spec_decode``, ``kv_offload``, ``kv_persist_dir`` and a
+        ``mesh`` with :class:`~polyaxon_tpu.models.hybrid.RecurrentStateError`.
     stats : a stats backend receiving latency histograms
         (``serving.queue_wait_s`` / ``serving.ttft_s`` /
         ``serving.decode_step_s`` / ``serving.batch_occupancy``) and
@@ -479,6 +497,8 @@ class ServingEngine:
         kv_persist_dir: Optional[str] = None,
         kv_persist_blocks: Optional[int] = None,
         kv_persist_sig: str = "",
+        state_snapshot_every: Optional[int] = None,
+        state_snapshots: Optional[int] = None,
     ) -> None:
         import jax
 
@@ -518,8 +538,25 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = 1 + self.slots * self._table_width
         self.block_allocator = BlockAllocator(num_blocks)
+        # Recurrent state (a model with a layer pattern): per-slot rows in
+        # the pool, and the snapshot store that prefix reuse resumes from.
+        self._recurrent = cfg.layer_types is not None
+        self._snaps: Optional[StateSnapshots] = None
+        self._snap_store: Optional[Any] = None
+        self._snap_every = 0
+        if self._recurrent and prefix_cache:
+            every = int(state_snapshot_every or prefill_chunk or 1024)
+            if every < 1 or every % self.block_size:
+                raise ValueError(
+                    f"state_snapshot_every ({every}) must be a positive "
+                    f"multiple of block_size ({self.block_size})"
+                )
+            self._snap_every = every
+            self._snaps = StateSnapshots(
+                int(state_snapshots) if state_snapshots else 4 * self.slots
+            )
         self.prefix_cache = (
-            PrefixCache(self.block_allocator, self.block_size)
+            PrefixCache(self.block_allocator, self.block_size, self._snaps)
             if prefix_cache
             else None
         )
@@ -543,6 +580,20 @@ class ServingEngine:
         self.kv_pool_bytes = int(
             sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._pool))
         )
+        self._state_row_bytes = 0
+        if self._recurrent:
+            from polyaxon_tpu.models import hybrid
+
+            # The recurrent rows ride the pool dict: the paged programs
+            # carry and donate one tree, KV blocks and per-slot state alike.
+            self._pool.update(hybrid.init_rec_state(cfg, self.slots))
+            self._state_row_bytes = hybrid.rec_row_bytes(cfg)
+            if self._snaps is not None:
+                self._snap_store = hybrid.init_rec_state(cfg, self._snaps.capacity)
+        #: slot -> {position: place}: snapshots of the slot's prefill in
+        #: flight, attached to their chain entries when the prompt is in.
+        self._pending_snaps: Dict[int, Dict[int, int]] = {}
+        self._n_state_restores = 0
         # Per-slot block tables (host truth): -1 = unset, mapped to the
         # trash block when shipped to the device.
         self._tables = np.full(
@@ -653,6 +704,8 @@ class ServingEngine:
                     stacklevel=2,
                 )
                 self.kv_persist_dir = None
+        if self._recurrent:
+            self._refuse_what_recurrent_state_cannot_follow()
         self._kv_persist_interval_s = knob_float(
             "POLYAXON_TPU_KV_PERSIST_INTERVAL_S"
         )
@@ -681,6 +734,8 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(seed)
         self._rng = np.random.default_rng(seed)
         self._chunk_fns: Dict[int, Any] = {}
+        self._snapshot_fn: Optional[Any] = None
+        self._restore_fn: Optional[Any] = None
         self._copy_fn: Optional[Any] = None
         self._verify_fns: Dict[int, Any] = {}
         self._step_fn = self._build_step()
@@ -729,12 +784,28 @@ class ServingEngine:
         # The scheduler thread's exclusive phase clock: where the loop's
         # wall time goes, as whole-run counters in stats() and, during an
         # xplane capture, as annotations in the device trace.
-        self._clock = get_tracer().phase_clock(LOOP_PHASES, PH_OTHER)
+        self._clock = get_tracer().phase_clock(
+            LOOP_PHASES + (STATE_PHASES if self._recurrent else ()), PH_OTHER
+        )
         # Decode-side utilization ledger (armed in start()): the seconds
         # of prefill and decode ticks weighted by slot occupancy — the
         # serving analogue of train-side goodput/MFU.
         self._ledger: Optional[Any] = None
         self._occ_weighted_s = 0.0
+
+    def _refuse_what_recurrent_state_cannot_follow(self) -> None:
+        """``models/hybrid.py:REFUSED``: each option asked for raises by name
+        rather than run on the KV alone."""
+        from polyaxon_tpu.models.hybrid import RecurrentStateError
+
+        for option, asked in (
+            ("spec_decode", self.spec_decode),
+            ("kv_offload", self.kv_offload),
+            ("kv_persist_dir", self.kv_persist_dir),
+            ("mesh", self._mesh is not None),
+        ):
+            if asked:
+                raise RecurrentStateError(option)
 
     # -- compiled functions ----------------------------------------------------
 
@@ -780,10 +851,20 @@ class ServingEngine:
         if c_pad not in self._chunk_fns:
             cfg = self.cfg
 
-            def chunk_fn(params, pool, table, tokens, start, length):
-                return paged_prefill_chunk(
-                    params, pool, table, tokens, start, length, cfg
-                )
+            if self._recurrent:
+                # The same program, told whose recurrent rows it advances.
+                def chunk_fn(params, pool, table, tokens, start, length, slot):
+                    return paged_prefill_chunk(
+                        params, pool, table, tokens, start, length, cfg,
+                        slot=slot,
+                    )
+
+            else:
+
+                def chunk_fn(params, pool, table, tokens, start, length):
+                    return paged_prefill_chunk(
+                        params, pool, table, tokens, start, length, cfg
+                    )
 
             self._chunk_fns[c_pad] = jax.jit(
                 chunk_fn, donate_argnums=(1,) if self._donate() else ()
@@ -800,6 +881,26 @@ class ServingEngine:
                 copy_block, donate_argnums=(0,) if self._donate() else ()
             )
         return self._copy_fn
+
+    def _get_snapshot(self):
+        import jax
+
+        from polyaxon_tpu.models.hybrid import take_snapshot
+
+        if self._snapshot_fn is None:
+            # The STORE is donated; the pool is only read (and is donated
+            # to the next chunk or step, which the runtime orders after).
+            self._snapshot_fn = jax.jit(take_snapshot, donate_argnums=(0,))
+        return self._snapshot_fn
+
+    def _get_restore(self):
+        import jax
+
+        from polyaxon_tpu.models.hybrid import restore_snapshot
+
+        if self._restore_fn is None:
+            self._restore_fn = jax.jit(restore_snapshot, donate_argnums=(0,))
+        return self._restore_fn
 
     def _get_export(self):
         import jax
@@ -951,6 +1052,10 @@ class ServingEngine:
             fns.append(self._export_fn)
         if self._import_fn is not None:
             fns.append(self._import_fn)
+        if self._snapshot_fn is not None:
+            fns.append(self._snapshot_fn)
+        if self._restore_fn is not None:
+            fns.append(self._restore_fn)
         return sum(int(fn._cache_size()) for fn in fns)
 
     def _warmup_buckets(self) -> List[int]:
@@ -992,6 +1097,7 @@ class ServingEngine:
         widths = self._spec_widths() if self._warmup else []
         self._warmup_total = (
             len(buckets) + len(widths) + 2 + (1 if spillers else 0)
+            + (1 if self._snaps is not None else 0)
             if self._warmup
             else 0
         )
@@ -1032,6 +1138,9 @@ class ServingEngine:
                     jax.block_until_ready(toks)
                     _tick()
                     table0 = jnp.zeros(self._table_width, jnp.int32)
+                    # A recurrent model's chunk also names a slot: slot 0,
+                    # whose rows a chunk of length 0 leaves zero.
+                    slot0 = (jnp.int32(0),) if self._recurrent else ()
                     for c_pad in buckets:
                         if self._stop.is_set():
                             break
@@ -1042,8 +1151,20 @@ class ServingEngine:
                             jnp.zeros(c_pad, jnp.int32),
                             jnp.int32(0),
                             jnp.int32(0),
+                            *slot0,
                         )
                         jax.block_until_ready(logits)
+                        _tick()
+                    if self._snaps is not None:
+                        # Snapshot and restore through place 0 and slot 0
+                        # (all zeros either way): both programs compiled.
+                        self._snap_store = self._get_snapshot()(
+                            self._snap_store, self._pool, jnp.int32(0), jnp.int32(0)
+                        )
+                        self._pool = self._get_restore()(
+                            self._pool, self._snap_store, jnp.int32(0), jnp.int32(0)
+                        )
+                        jax.block_until_ready(self._pool)
                         _tick()
                     # The verify family: every width bucket speculative
                     # traffic can request, warmed all-inactive so writes
@@ -1350,7 +1471,7 @@ class ServingEngine:
         of two ``/v1/stats`` reads split the window between them."""
         wall, seconds, counts = clock
         out: Dict[str, Any] = {"loop_wall_s": round(wall, 6)}
-        for phase in LOOP_PHASES:
+        for phase in seconds:
             key = _stats_key(phase)
             out[key + "_s"] = round(seconds[phase], 6)
             out[key + "_n"] = counts[phase]
@@ -1363,6 +1484,7 @@ class ServingEngine:
         total = alloc.num_blocks - 1
         pc = self.prefix_cache
         tier = self._host_tier
+        snaps = self._snaps
         with self._stats_lock:
             backlog = self._backlog_chunks
             jobs = self._prefill_jobs
@@ -1374,6 +1496,7 @@ class ServingEngine:
             restored = self._n_restored_blocks
             preloaded = self._kv_preloaded_blocks
             persisted = self._kv_persisted_blocks
+            state_restores = self._n_state_restores
             now = time.time()
             pc_rate_window = 0.0
             if pc is not None:
@@ -1420,6 +1543,19 @@ class ServingEngine:
             "block_parks": parks,
             "cow_copies": cow,
             "requests_cancelled": cancelled,
+            # Recurrent state (all 0 for a dense model): snapshots taken,
+            # snapshots restored into a slot, snapshots lost (the store's
+            # LRU, or the chain entry they stood on evicted), what the
+            # store holds now, and the tokens a KV match had to give back
+            # for want of a snapshot.
+            "state_snapshots": snaps.taken if snaps is not None else 0,
+            "state_restores": state_restores,
+            "state_snapshot_evictions": snaps.evictions if snaps is not None else 0,
+            "state_store_used": snaps.used if snaps is not None else 0,
+            "state_snapshot_bytes": (
+                snaps.used * self._state_row_bytes if snaps is not None else 0
+            ),
+            "prefix_floor_tokens": pc.floor_tokens if pc is not None else 0,
         }
 
     def _spec_snapshot(self) -> Dict[str, Any]:
@@ -1827,8 +1963,18 @@ class ServingEngine:
                     self._drafters[slot] = drafter
             job = _PrefillJob(req, slot)
             if self.prefix_cache is not None:
+                place = None
                 with clock.phase(PH_MATCH):
-                    matched = self.prefix_cache.match(req.prompt)
+                    if self._snaps is not None:
+                        # KV can resume at any block, the recurrent layers
+                        # only at a snapshot: the hit ends at the newest.
+                        matched, place = self.prefix_cache.match_with_state(
+                            req.prompt
+                        )
+                    else:
+                        matched = self.prefix_cache.match(req.prompt)
+                if place is not None:
+                    self._restore_state(slot, place)
                 for i, block in enumerate(matched):
                     self._tables[slot, i] = block
                 m = len(matched) * self.block_size
@@ -1852,6 +1998,38 @@ class ServingEngine:
                     job.next_pos = m
             self._prefill.append(job)
             self._record_gauges()
+
+    @_in_phase(PH_RESTORE)
+    def _restore_state(self, slot: int, place: int) -> None:
+        """Copy snapshot ``place`` into ``slot``'s recurrent rows: the
+        request's prefill goes on from there."""
+        import jax.numpy as jnp
+
+        self._pool = self._get_restore()(
+            self._pool, self._snap_store, jnp.int32(place), jnp.int32(slot)
+        )
+        with self._stats_lock:
+            self._n_state_restores += 1
+
+    @_in_phase(PH_SNAPSHOT)
+    def _snapshot_state(self, slot: int, pos: int) -> None:
+        """Keep ``slot``'s recurrent rows as they stand after ``pos`` prompt
+        tokens (the chunk that got there is dispatched; the copy is ordered
+        after it).  Pending until the prompt is in and its blocks are
+        offered; skipped when every place of the store is pending."""
+        import jax.numpy as jnp
+
+        place = self._snaps.alloc()
+        if place is None:
+            return
+        self._snap_store = self._get_snapshot()(
+            self._snap_store, self._pool, jnp.int32(slot), jnp.int32(place)
+        )
+        self._pending_snaps.setdefault(slot, {})[pos] = place
+
+    def _release_pending_snapshots(self, slot: int) -> None:
+        for place in self._pending_snaps.pop(slot, {}).values():
+            self._snaps.release(place)
 
     @_in_phase(PH_ALLOC)
     def _alloc_block(self) -> Optional[int]:
@@ -1895,6 +2073,9 @@ class ServingEngine:
         n = t - job.next_pos
         if self.prefill_chunk:
             n = min(n, self.prefill_chunk)
+        if self._snaps is not None:
+            # A chunk ends where a snapshot is due, whatever its start.
+            n = min(n, self._snap_every - job.next_pos % self._snap_every)
         # Lazy block faults for the chunk's span; partial allocations are
         # kept on exhaustion (the retry only fills what's still unset).
         first_bi = job.next_pos // bs
@@ -1916,8 +2097,11 @@ class ServingEngine:
             jnp.asarray(chunk),
             jnp.int32(job.next_pos),
             jnp.int32(n),
+            *((jnp.int32(slot),) if self._recurrent else ()),
         )
         job.next_pos += n
+        if self._snaps is not None and job.next_pos % self._snap_every == 0:
+            self._snapshot_state(slot, job.next_pos)
         done = job.next_pos >= t
         with bookkeeping as t1:
             self._trace_span(
@@ -1957,6 +2141,7 @@ class ServingEngine:
                 self.prefix_cache.offer(
                     req.prompt,
                     [int(self._tables[slot, i]) for i in range(full)],
+                    self._pending_snaps.pop(slot, None),
                 )
         with clock.phase(PH_EMIT):
             first = self._pick_first(logits, req.temperature)
@@ -2584,6 +2769,7 @@ class ServingEngine:
         req.stream.put(None)
         req.done.set()
         self._release_slot_blocks(slot)
+        self._release_pending_snapshots(slot)
         self._slot_req[slot] = None
         self._drafters[slot] = None
         self.allocator.free(slot)
@@ -2599,6 +2785,7 @@ class ServingEngine:
         self._active[slot] = False
         self._unpark(slot)
         self._release_slot_blocks(slot)
+        self._release_pending_snapshots(slot)
         self._slot_req[slot] = None
         self._drafters[slot] = None
         self.allocator.free(slot)

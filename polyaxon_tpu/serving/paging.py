@@ -26,6 +26,12 @@ layout bit-exact) for two populations: a parked sequence's spilled
 private blocks (pinned — correctness state) and demoted prefix-cache
 blocks (a bounded LRU — pure cache).  The device copies themselves live
 in the engine; this class is pure bookkeeping.
+
+:class:`StateSnapshots` — the places of the device store that keeps a
+hybrid model's RECURRENT state at chosen prefix positions.  KV can be
+resumed at every block; a linear-attention layer only where its state
+was kept.  A snapshot belongs to the :class:`PrefixCache` entry of the
+block it ends on: offered with it, found through it, dropped with it.
 """
 
 from __future__ import annotations
@@ -238,6 +244,72 @@ class HostKVTier:
             self.on_drop(handle)
 
 
+class StateSnapshots:
+    """Which place of a fixed store of ``capacity`` recurrent-state
+    snapshots holds what (the bytes live on the device, in the engine).
+
+    A place is FREE, PENDING (allocated to a prefill in flight, not yet
+    attached to a chain entry: never evicted) or ATTACHED to a
+    prefix-cache chain key.  ``alloc()`` takes a free place or, with
+    none left, the least recently used attached one.  Keys are the
+    prefix cache's chain keys, so a snapshot is only ever found under
+    the token prefix that produced it.
+    """
+
+    def __init__(self, capacity: int) -> None:
+        if capacity < 1:
+            raise ValueError(f"need at least one snapshot place, got {capacity}")
+        self.capacity = int(capacity)
+        self._free: deque = deque(range(self.capacity))
+        self._by_key: "OrderedDict[int, int]" = OrderedDict()  # LRU first
+        self.taken = 0
+        self.evictions = 0
+
+    @property
+    def used(self) -> int:
+        """Places not free (attached or pending)."""
+        return self.capacity - len(self._free)
+
+    def alloc(self) -> Optional[int]:
+        """A place for a new snapshot, evicting the least recently used
+        attached one if none is free; ``None`` when every place is pending."""
+        if self._free:
+            idx = self._free.popleft()
+        elif self._by_key:
+            _, idx = self._by_key.popitem(last=False)
+            self.evictions += 1
+        else:
+            return None
+        self.taken += 1
+        return idx
+
+    def release(self, idx: int) -> None:
+        """Give back a pending place (its prefill failed, or another
+        request's snapshot already stands at the same prefix)."""
+        self._free.append(idx)
+
+    def attach(self, key: int, idx: int) -> None:
+        """Pending place ``idx`` now stands at chain key ``key``; first
+        writer wins (the state is a function of the prefix alone)."""
+        if key in self._by_key:
+            self.release(idx)
+        else:
+            self._by_key[key] = idx
+
+    def lookup(self, key: int) -> Optional[int]:
+        return self._by_key.get(key)
+
+    def touch(self, key: int) -> None:
+        self._by_key.move_to_end(key)
+
+    def drop(self, key: int) -> None:
+        """The chain entry is gone: so is its snapshot."""
+        idx = self._by_key.pop(key, None)
+        if idx is not None:
+            self._free.append(idx)
+            self.evictions += 1
+
+
 class PrefixCache:
     """Block-granular shared-prefix cache over a :class:`BlockAllocator`.
 
@@ -256,13 +328,29 @@ class PrefixCache:
     their FULL prefix token chain, which is what makes them persistable:
     chain keys are built with Python's process-randomized string hash,
     so a store must carry tokens, not keys, and rebuild keys on load.
+
+    With ``snapshots`` (a hybrid model: :class:`StateSnapshots`) a hit is
+    only as long as the recurrent state can follow:
+    :meth:`match_with_state` cuts the KV match back to the newest position
+    where a snapshot stands, :meth:`offer` attaches a prefill's snapshots
+    to the entries of the blocks they end on, and every path that forgets
+    an entry drops its snapshot.
     """
 
-    def __init__(self, allocator: BlockAllocator, block_size: int) -> None:
+    def __init__(
+        self,
+        allocator: BlockAllocator,
+        block_size: int,
+        snapshots: Optional[StateSnapshots] = None,
+    ) -> None:
         if block_size < 1:
             raise ValueError(f"block_size must be positive, got {block_size}")
         self._alloc = allocator
         self.block_size = int(block_size)
+        self._snaps = snapshots
+        #: Tokens the KV chain matched that were prefilled again because no
+        #: snapshot stood that far (``match_with_state``).
+        self.floor_tokens = 0
         # chain key -> (physical block | DEMOTED, the block's token tuple)
         self._entries: "OrderedDict[int, Tuple[int, Tuple[int, ...]]]" = (
             OrderedDict()
@@ -335,10 +423,16 @@ class PrefixCache:
         if key is None:
             return
         self._demoted.pop(key, None)
-        self._entries.pop(key, None)
-        self._chains.pop(key, None)
+        self._forget(key)
         self.evictions += 1
         self.mutations += 1
+
+    def _forget(self, key: int) -> None:
+        """Remove an entry from the maps, with the snapshot that stood on it."""
+        self._entries.pop(key, None)
+        self._chains.pop(key, None)
+        if self._snaps is not None:
+            self._snaps.drop(key)
 
     def _keys_for(self, prompt: Sequence[int]) -> List[Tuple[int, Tuple[int, ...]]]:
         """Chained (key, tokens) per FULL block of the prompt."""
@@ -357,7 +451,12 @@ class PrefixCache:
         (host→device copy); if the pool can't provide one even after
         demoting colder entries, the walk stops there — a miss, never an
         error."""
+        return self._walk(prompt)[0]
+
+    def _walk(self, prompt: Sequence[int]) -> Tuple[List[int], List[int]]:
+        """The matched blocks and, beside each, its chain key."""
         blocks: List[int] = []
+        keys: List[int] = []
         for key, toks in self._keys_for(prompt):
             self.lookups += 1
             entry = self._entries.get(key)
@@ -372,7 +471,35 @@ class PrefixCache:
             self._entries.move_to_end(key)
             self._alloc.incref(block)
             blocks.append(block)
-        return blocks
+            keys.append(key)
+        return blocks, keys
+
+    def match_with_state(
+        self, prompt: Sequence[int]
+    ) -> Tuple[List[int], Optional[int]]:
+        """:meth:`match` for a model with recurrent layers: the KV match cut
+        back to the newest position, at or below it and below the prompt's
+        last token (whose logits have to be computed from the state BEFORE
+        it), where a snapshot stands.  Returns the kept blocks and the
+        snapshot's place in the store (``(.., None)``: no snapshot on the
+        chain, start from position 0).  The blocks beyond are released,
+        ``hits`` counts only what is left, and the difference goes to
+        ``floor_tokens``."""
+        blocks, keys = self._walk(prompt)
+        keep, place = 0, None
+        for i in range(len(blocks) - 1, -1, -1):
+            if (i + 1) * self.block_size >= len(prompt):
+                continue
+            place = self._snaps.lookup(keys[i])
+            if place is not None:
+                keep = i + 1
+                self._snaps.touch(keys[i])
+                break
+        for block in blocks[keep:]:
+            self._alloc.decref(block)
+        self.hits -= len(blocks) - keep
+        self.floor_tokens += (len(blocks) - keep) * self.block_size
+        return blocks[:keep], place
 
     def _restore_entry(self, key: int, toks: Tuple[int, ...]) -> Optional[int]:
         """Bring one demoted entry back on-device; returns its fresh
@@ -407,12 +534,21 @@ class PrefixCache:
         self.mutations += 1
         return block
 
-    def offer(self, prompt: Sequence[int], blocks: Sequence[int]) -> None:
+    def offer(
+        self,
+        prompt: Sequence[int],
+        blocks: Sequence[int],
+        snapshots: Optional[Dict[int, int]] = None,
+    ) -> None:
         """Publish a prompt's full blocks.  ``blocks[i]`` must hold block
         ``i``'s KV; already published prefixes keep their existing block
-        (first writer wins — later identical blocks stay private)."""
+        (first writer wins — later identical blocks stay private).
+        ``snapshots`` maps a position (a multiple of the block size) to the
+        pending place that holds the recurrent state after that many
+        tokens: each is attached to the entry of the block it ends on."""
         chain: List[int] = []
-        for (key, toks), block in zip(self._keys_for(prompt), blocks):
+        pending = dict(snapshots or {})
+        for i, ((key, toks), block) in enumerate(zip(self._keys_for(prompt), blocks)):
             chain.extend(toks)
             entry = self._entries.get(key)
             if entry is None:
@@ -421,6 +557,11 @@ class PrefixCache:
                 self._chains[key] = tuple(chain)
                 self.mutations += 1
             self._entries.move_to_end(key)
+            place = pending.pop((i + 1) * self.block_size, None)
+            if place is not None:
+                self._snaps.attach(key, place)
+        for place in pending.values():  # stood on no published block
+            self._snaps.release(place)
 
     def install(self, chain_tokens: Sequence[int], block: int) -> bool:
         """Register a persisted prefix block (warm boot): ``chain_tokens``
@@ -510,8 +651,7 @@ class PrefixCache:
                     self.mutations += 1
                     freed += 1
                     continue
-            self._entries.pop(key, None)
-            self._chains.pop(key, None)
+            self._forget(key)
             self._alloc.decref(block)
             self.evictions += 1
             self.mutations += 1

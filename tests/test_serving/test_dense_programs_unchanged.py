@@ -1,0 +1,85 @@
+"""The dense model's three paged programs are what they were before the hybrid
+stack came in beside them.
+
+``models/decode.py``'s ``paged_prefill_chunk``, ``paged_decode_step`` and
+``paged_verify_step`` hand a model WITH a layer pattern over to
+``models/hybrid.py`` and run every other model through the code they had.  The
+guard: at ``mistral-7b-v0.3-serve``'s toy sizes, for both pool layouts, each
+program lowers to HLO text with the digest it had at PR 28 (the commit before
+the hybrid stack; the digests were taken there with this file's own code, and
+the engine's decode step in its ``_decode_hlo_text`` form beside them).  The
+text holds no source locations, so it moves only when the program does: a
+digest that moves means the chip's compile cache misses for the Mistral cells
+and their device programs may differ, which is what the benchmark's
+parent-against-change runs then have to answer for.  A new jax may move all of
+them at once; then take them again at the same commit.
+"""
+
+import hashlib
+import json
+from functools import partial
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from polyaxon_tpu.models import TransformerConfig, decode, init_params
+
+ROOT = Path(__file__).resolve().parents[2]
+TOY = json.loads((ROOT / "benchmark/configs/mistral-7b-v0.3-serve.json").read_text())["toy"]
+ENGINE = TOY["engine"]
+CFG = TransformerConfig(
+    vocab_size=TOY["vocab_size"], d_model=TOY["hidden_size"], n_layers=TOY["num_hidden_layers"],
+    n_heads=TOY["num_attention_heads"], head_dim=TOY["head_dim"], d_ff=TOY["intermediate_size"],
+    n_kv_heads=TOY["num_key_value_heads"], max_seq=ENGINE["seq"])
+S, W, T, C = ENGINE["slots"], ENGINE["seq"] // ENGINE["block_size"], 5, ENGINE["prefill_chunk"]
+
+AT_PR_28 = {
+    "prefill-kv": "8d6c3d807ac758f5308f1aa7fda3e10c8601bfd49b7e11256e75111a43917b1b",
+    "decode-kv": "e470b812870f85b1c395f5c6105195e840b8b11bdb17511c71de9e446a18d21b",
+    "verify-kv": "765cf1829bc346595072cc19f9eb9976c9e4a15a164a89940b0300e2c98069d5",
+    "prefill-int8": "e31f6c878ae26266b070d421f292c27cc427fbf91cf194f6ea73b0ebdf38bbd8",
+    "decode-int8": "9064dff56c128f91c404b6354f18f4a7197f2a17525c2805079e164e41a8c07e",
+    "verify-int8": "29619d31e1a8de1decf241e91d76f0bbbb44288ab829e42678ce8861306f3a26",
+    "engine-step": "c37880507a0da402deb400149d5621a399c136525c8d67f2b371fa48bc414804",
+}
+
+
+def _lowered_text(kind, kvq):
+    i32, sds = jnp.int32, jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))
+    pool = jax.eval_shape(lambda: decode.init_block_pool(
+        CFG, ENGINE["kv_blocks"], ENGINE["block_size"], kv_dtype=kvq))
+    lanes = (sds((S,), i32), sds((S,), i32))
+    fn, args = {
+        "prefill": (decode.paged_prefill_chunk,
+                    (sds((W,), i32), sds((C,), i32), sds((), i32), sds((), i32))),
+        "decode": (decode.paged_decode_step,
+                   (sds((S, W), i32), *lanes, sds((S,), jnp.bool_))),
+        "verify": (decode.paged_verify_step,
+                   (sds((S, W), i32), sds((S, T), i32), *lanes, sds((S,), jnp.bool_))),
+    }[kind]
+    return jax.jit(partial(fn, cfg=CFG)).lower(params, pool, *args).as_text()
+
+
+@pytest.mark.parametrize("kvq", [None, "int8"], ids=["kv", "int8"])
+@pytest.mark.parametrize("kind", ["prefill", "decode", "verify"])
+def test_dense_program_lowers_to_the_text_it_had(kind, kvq):
+    digest = hashlib.sha256(_lowered_text(kind, kvq).encode()).hexdigest()
+    assert digest == AT_PR_28[f"{kind}-{kvq or 'kv'}"]
+
+
+def test_engines_decode_step_lowers_to_the_text_it_had():
+    from polyaxon_tpu.serving import ServingEngine
+
+    engine = ServingEngine(
+        init_params(jax.random.PRNGKey(0), CFG), CFG, slots=S, block_size=ENGINE["block_size"],
+        num_blocks=ENGINE["kv_blocks"], prefill_chunk=C, warmup=False)
+    digest = hashlib.sha256(engine._decode_hlo_text().encode()).hexdigest()
+    assert digest == AT_PR_28["engine-step"]
+    # and a dense engine has no recurrent state, no store and no state phases
+    stats = engine.stats()
+    assert "loop_state_snapshot_s" not in stats and "loop_state_restore_s" not in stats
+    assert stats["state_snapshots"] == stats["state_store_used"] == stats["prefix_floor_tokens"] == 0
+    assert not any(name.startswith("rec_") for name in engine._pool)
