@@ -574,6 +574,7 @@ def lm_server(ctx: Context) -> None:
         if restored is None:
             raise RuntimeError(f"No checkpoint under {ckpt_dir}")
         params, step = restored["params"], restored["step"]
+        del restored  # ``params`` is now the float32 tree's only name
         ctx.log_text(f"lm_server: restored run {target} step {step}")
 
     # int8 weight-only decode (param ``quantize: int8``): the per-token
@@ -591,6 +592,24 @@ def lm_server(ctx: Context) -> None:
             )
             qweights = jax.device_put(qweights, qweights_shardings)
         ctx.log_text("lm_server: int8 weight-only decode enabled")
+
+    # The chip holds what the programs read: ``decode.serving_params``'s
+    # tree, rounded HERE one leaf at a time, each float32 leaf released as
+    # soon as it is cast.  The high-water mark at load is then the float32
+    # tree plus one leaf; cast by the engine, both trees would stand under
+    # its pool for a moment (13.3 GB against 10.3 for the 4-layer
+    # Olmo-Hybrid cell, PERF.md PR 30).  The engine's own call finds nothing
+    # left to do.  ``qweights`` were made from the float32 weights above.
+    want = jax.tree.leaves(
+        jax.eval_shape(lambda p: decode.serving_params(p, cfg), params)
+    )
+    leaves, treedef = jax.tree.flatten(params)
+    del params
+    for i, w in enumerate(want):
+        if leaves[i].dtype != w.dtype:
+            # waited for, so the float32 leaf is gone before the next cast
+            leaves[i] = jax.block_until_ready(leaves[i].astype(w.dtype))
+    params = jax.tree.unflatten(treedef, leaves)
 
     port = _service_port(ctx)
     host = str(ctx.get_param("host", "0.0.0.0"))

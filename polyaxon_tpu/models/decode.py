@@ -99,6 +99,39 @@ def quantize_weights(params: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def serving_params(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, Any]:
+    """The tree the paged programs read, rounded to the compute dtype once.
+
+    The programs read the embeddings and every matmul weight ONLY through
+    ``.astype(cfg.dtype)`` / :func:`_wdq`; handed ``param_dtype`` float32
+    leaves, XLA materialises those casts in HBM in every program (a decode
+    step, a prefill chunk, a verify step: 9-12 ms each at 1.1-1.6 B
+    parameters on a v5e).  Here the same ``astype`` runs once, one leaf at
+    a time, so each program sees the operand bits it saw and its own
+    ``astype`` lowers to nothing.  The norm scales stay as they are (a few
+    KB, not read at ``cfg.dtype`` everywhere).  A leaf that is
+    ``cfg.dtype`` already comes back itself: a second call, or a model at
+    float32 compute, costs nothing.  The float32 tree is still what is made,
+    restored and quantized (:func:`quantize_weights` reads it, not this).
+    """
+    dtype = jnp.dtype(cfg.dtype)
+
+    def cast(w):
+        return w if w.dtype == dtype else w.astype(dtype)
+
+    blk = params["block"]
+    if "linear" in blk:  # the hybrid stack: its own leaf list
+        from polyaxon_tpu.models import hybrid
+
+        return hybrid.serving_params(params, cast)
+    return {
+        **params,
+        "embed": cast(params["embed"]),
+        "unembed": cast(params["unembed"]),
+        "block": {**blk, **{n: cast(blk[n]) for n in QUANTIZED_BLOCK_WEIGHTS}},
+    }
+
+
 def _wdq(w, dtype):
     """Weight as compute dtype: dequantize ``(int8, scale)`` pairs (XLA
     fuses the convert+scale into the consuming matmul's operand read —

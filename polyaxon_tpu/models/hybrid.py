@@ -218,6 +218,26 @@ def quantize_weights(params: Dict[str, Any], q) -> Dict[str, Any]:
     return {"block": out, "unembed": q(params["unembed"], (0,))}
 
 
+def serving_params(params: Dict[str, Any], cast) -> Dict[str, Any]:
+    """The hybrid stack's form of ``decode.serving_params``: ``cast`` (the one
+    it uses) over the embeddings, the MLP and every projection of both kinds
+    of layer, the decays' ``wa`` / ``wb`` too.  What the programs read in
+    float32 keeps its dtype: ``A_log``, ``dt_bias``, the convolutions
+    (``_conv_weights``) and ``o_norm`` (applied to the float32 rule output);
+    so do the other norms."""
+    blk = params["block"]
+    out = {**blk, **{n: cast(blk[n]) for n in _QUANTIZED[""]}}
+    for kind, names in (("full", _QUANTIZED["full"]),
+                        ("linear", (*_QUANTIZED["linear"], "wa", "wb"))):
+        out[kind] = {**blk[kind], **{n: cast(blk[kind][n]) for n in names}}
+    return {
+        **params,
+        "embed": cast(params["embed"]),
+        "unembed": cast(params["unembed"]),
+        "block": out,
+    }
+
+
 def _with_qweights(params, qweights):
     """The block tree with the int8 pairs in the quantized weights' places."""
     blk = params["block"]

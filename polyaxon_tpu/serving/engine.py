@@ -350,7 +350,11 @@ class ServingEngine:
 
     Parameters
     ----------
-    params, cfg : the model (a ``TransformerConfig`` tree).
+    params, cfg : the model (a ``TransformerConfig`` tree).  The engine
+        holds ``decode.serving_params(params, cfg)``: the embeddings and
+        matmul weights rounded to ``cfg.dtype`` once, and no reference to
+        ``params`` itself; a caller that wants the ``param_dtype`` bytes
+        off the device drops its own before ``start()`` warms up.
     slots : concurrent sequences the batch holds (the static batch dim).
     max_len : per-request sequence capacity (default ``cfg.max_seq``).
     block_size : tokens per KV block — the paging granularity.  Smaller
@@ -528,8 +532,19 @@ class ServingEngine:
             params = jax.device_put(params, param_shardings)
         if qweights is not None and qweights_shardings is not None:
             qweights = jax.device_put(qweights, qweights_shardings)
-        self._params = params
+        # The programs read the embeddings and matmul weights only through
+        # a cast to ``cfg.dtype``: make it once, here (a cast under a
+        # leaf's sharding keeps it), and keep no reference to the tree that
+        # was handed in, so a caller that drops its own frees the float32
+        # bytes.  ``qweights`` were made from that tree before this.
+        self._params = decode.serving_params(params, cfg)
         self._qweights = qweights
+        #: Device bytes of the tree the programs read and the dtype of its
+        #: matmul leaves, beside ``kv_pool_bytes`` / ``kv_dtype``.
+        self.weight_bytes = int(
+            sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._params))
+        )
+        self.weight_dtype = self._params["unembed"].dtype.name
 
         # Table width: logical blocks a max_len sequence spans.  The
         # default pool matches the old slot-granular footprint (every
@@ -1313,6 +1328,8 @@ class ServingEngine:
                 prefill_backlog_chunks=paging["prefill_backlog_chunks"],
                 kv_pool_bytes=paging["kv_pool_bytes"],
                 kv_dtype=paging["kv_dtype"],
+                weight_bytes=paging["weight_bytes"],
+                weight_dtype=paging["weight_dtype"],
                 spec_proposed_total=spec["spec_proposed_total"],
                 spec_accepted_total=spec["spec_accepted_total"],
                 spec_accept_rate=spec["spec_accept_rate"],
@@ -1512,6 +1529,8 @@ class ServingEngine:
             "block_size": self.block_size,
             "kv_dtype": self.kv_dtype,
             "kv_pool_bytes": self.kv_pool_bytes,
+            "weight_dtype": self.weight_dtype,
+            "weight_bytes": self.weight_bytes,
             "blocks_total": total,
             "blocks_free": alloc.n_free,
             "block_occupancy": (

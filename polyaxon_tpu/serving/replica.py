@@ -90,7 +90,9 @@ def serve(spec: dict) -> None:
         ),
         kv_persist_dir=str(kv_persist_dir) if kv_persist_dir else None,
         kv_persist_sig=str(spec.get("kv_persist_sig", "")),
-    ).start()
+    )
+    del params  # the engine reads its own cfg.dtype tree; free the float32 one
+    engine.start()
 
     meta = {
         "checkpoint_step": None,

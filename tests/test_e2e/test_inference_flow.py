@@ -147,6 +147,9 @@ class TestInferenceService:
             stats = json.load(r)
         assert stats["requests_finished"] >= 7
         assert stats["slots"] >= 1 and "tokens_per_s" in stats
+        # The float32 checkpoint was restored, then rounded to the compute
+        # dtype before the engine was built: what the chip holds.
+        assert stats["weight_dtype"] == "bfloat16" and stats["weight_bytes"] > 0
 
         # Bad requests are 400s, not server crashes.
         bad = urllib.request.Request(

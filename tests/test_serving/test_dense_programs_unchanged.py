@@ -13,10 +13,18 @@ digest that moves means the chip's compile cache misses for the Mistral cells
 and their device programs may differ, which is what the benchmark's
 parent-against-change runs then have to answer for.  A new jax may move all of
 them at once; then take them again at the same commit.
+
+PR 30 retook ``engine-step`` and only that one: the engine now hands its
+programs the embeddings and matmul weights in ``cfg.dtype`` already
+(``decode.serving_params``), so the engine's decode step takes bfloat16 weight
+arguments and its text has lost their ``convert`` lines.  The six program
+digests above it are taken on the float32 tree as before and did not move:
+``models/decode.py``'s programs are the ones PR 28 left.
 """
 
 import hashlib
 import json
+import re
 from functools import partial
 from pathlib import Path
 
@@ -35,14 +43,14 @@ CFG = TransformerConfig(
     n_kv_heads=TOY["num_key_value_heads"], max_seq=ENGINE["seq"])
 S, W, T, C = ENGINE["slots"], ENGINE["seq"] // ENGINE["block_size"], 5, ENGINE["prefill_chunk"]
 
-AT_PR_28 = {
+AT_PR_28 = {  # "engine-step" at PR 30, see above
     "prefill-kv": "8d6c3d807ac758f5308f1aa7fda3e10c8601bfd49b7e11256e75111a43917b1b",
     "decode-kv": "e470b812870f85b1c395f5c6105195e840b8b11bdb17511c71de9e446a18d21b",
     "verify-kv": "765cf1829bc346595072cc19f9eb9976c9e4a15a164a89940b0300e2c98069d5",
     "prefill-int8": "e31f6c878ae26266b070d421f292c27cc427fbf91cf194f6ea73b0ebdf38bbd8",
     "decode-int8": "9064dff56c128f91c404b6354f18f4a7197f2a17525c2805079e164e41a8c07e",
     "verify-int8": "29619d31e1a8de1decf241e91d76f0bbbb44288ab829e42678ce8861306f3a26",
-    "engine-step": "c37880507a0da402deb400149d5621a399c136525c8d67f2b371fa48bc414804",
+    "engine-step": "38573862abd6a308fde14272f7b8179050eb8ed97dd0eae1664e87bef6cc7c33",
 }
 
 
@@ -70,12 +78,35 @@ def test_dense_program_lowers_to_the_text_it_had(kind, kvq):
     assert digest == AT_PR_28[f"{kind}-{kvq or 'kv'}"]
 
 
-def test_engines_decode_step_lowers_to_the_text_it_had():
+def _engine():
     from polyaxon_tpu.serving import ServingEngine
 
-    engine = ServingEngine(
+    return ServingEngine(
         init_params(jax.random.PRNGKey(0), CFG), CFG, slots=S, block_size=ENGINE["block_size"],
         num_blocks=ENGINE["kv_blocks"], prefill_chunk=C, warmup=False)
+
+
+def _weight_casts(text, params):
+    """The ``f32 -> bf16`` converts of ``text`` whose operand has the shape of
+    an embedding or a matmul weight (a block leaf whole, or one layer of it)."""
+    shapes = {params["embed"].shape, params["unembed"].shape}
+    for name in decode.QUANTIZED_BLOCK_WEIGHTS:
+        shapes |= {params["block"][name].shape, params["block"][name].shape[1:]}
+    found = re.findall(r"convert %\S+ : \(tensor<([0-9x]+)xf32>\) -> tensor<[0-9x]+xbf16>", text)
+    return [dims for dims in found if tuple(map(int, dims.split("x"))) in shapes]
+
+
+def test_engines_decode_step_casts_no_weight():
+    engine = _engine()
+    params = init_params(jax.random.PRNGKey(0), CFG)
+    assert _weight_casts(engine._decode_hlo_text(), params) == []
+    # the same step handed the float32 tree, as before PR 30, casts all nine
+    engine._params = params
+    assert len(_weight_casts(engine._decode_hlo_text(), params)) == 9
+
+
+def test_engines_decode_step_lowers_to_the_text_it_had():
+    engine = _engine()
     digest = hashlib.sha256(engine._decode_hlo_text().encode()).hexdigest()
     assert digest == AT_PR_28["engine-step"]
     # and a dense engine has no recurrent state, no store and no state phases

@@ -290,6 +290,9 @@ class TestEngineUtilization:
         # the true pool bytes (and sees them shrink under kv_quantize).
         assert row["extra"]["kv_pool_bytes"] > 0
         assert row["extra"]["kv_dtype"] == "float32"
+        # and the weights the programs read, beside the pool
+        assert row["extra"]["weight_bytes"] == eng.weight_bytes > 0
+        assert row["extra"]["weight_dtype"] == "float32"
 
     def test_final_ledger_row_reports_quantized_pool(self, params):
         from polyaxon_tpu.serving import ServingEngine

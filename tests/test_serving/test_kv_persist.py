@@ -372,6 +372,28 @@ class TestAutoSignature:
         finally:
             b.stop()
 
+    def test_fingerprint_is_of_the_tree_handed_in_not_of_the_cast_one(
+        self, params, tmp_path
+    ):
+        """At bfloat16 compute the engine holds a rounded tree, but the
+        fingerprint stays the float32 weights': a store written by an
+        engine that still read the float32 tree opens."""
+        cfg = CFG.scaled(dtype=jnp.bfloat16)
+        eng = ServingEngine(
+            params, cfg, slots=2, max_len=48, block_size=4,
+            prefix_cache=True, kv_persist_dir=tmp_path / "kv", seed=3,
+        )
+        try:
+            assert eng.weight_dtype == "bfloat16"
+            assert eng.kv_persist_sig == ServingEngine._auto_persist_sig(
+                params, None, 3
+            )
+            assert eng.kv_persist_sig != ServingEngine._auto_persist_sig(
+                eng._params, None, 3
+            )
+        finally:
+            eng.stop()
+
     def test_different_weights_never_share_an_unsigned_store(
         self, params, tmp_path
     ):

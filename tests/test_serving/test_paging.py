@@ -683,3 +683,24 @@ class TestQuantizedPool:
         finally:
             f32.stop()
             q.stop()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+    def test_stats_report_weight_dtype_and_bytes(self, params, dtype):
+        """The tree the programs READ: at bfloat16 compute the embeddings
+        and matmul weights are held rounded, about half the float32 bytes
+        (the norm scales stay), whatever dtype the engine was handed."""
+        cfg = CFG.scaled(dtype=dtype)
+        handed = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
+        eng = ServingEngine(params, cfg, slots=2, max_len=48, block_size=4)
+        try:
+            stats = eng.stats()
+            assert stats["weight_dtype"] == jnp.dtype(dtype).name
+            assert stats["weight_bytes"] == sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(eng._params)
+            )
+            if dtype == jnp.float32:
+                assert stats["weight_bytes"] == handed
+            else:
+                assert 0.5 * handed < stats["weight_bytes"] < 0.51 * handed
+        finally:
+            eng.stop()
