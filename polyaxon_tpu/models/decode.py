@@ -30,8 +30,10 @@ from jax import lax
 
 from polyaxon_tpu.models.transformer import (
     TransformerConfig,
+    _dense_attention,
     _rmsnorm,
     _rope,
+    forward,
 )
 
 
@@ -142,51 +144,116 @@ def _wdq(w, dtype):
     return w.astype(dtype)
 
 
+# -- the served layer, piece by piece ---------------------------------------
+# Every program below (and ``models/hybrid.py``'s two) is index arithmetic, a
+# mixer of a few lines over these pieces, a runner and the unembedding.  A
+# program's lowered text follows the ORDER in which the pieces emit operations
+# (``tests/test_serving/test_dense_programs_unchanged.py`` holds its digests).
+
+
+def _qk_norm(x, w):
+    """RMSNorm over the whole projection (all heads), then split again."""
+    shape = x.shape
+    return _rmsnorm(x.reshape(shape[:-2] + (-1,)), w).reshape(shape)
+
+
+def _qkv(h, layer):
+    """One layer's projections of ``h [B, T, D]``: q ``[B, T, H, d]``, k and v
+    ``[B, T, Hkv, d]``.  A layer that carries ``q_norm`` / ``k_norm`` (the
+    hybrid stack's full-attention layers) normalises q and k over the whole
+    projection before the heads are split."""
+    dt = h.dtype
+    q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], dt))
+    if "q_norm" in layer:
+        q = _qk_norm(q, layer["q_norm"])
+    k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], dt))
+    if "k_norm" in layer:
+        k = _qk_norm(k, layer["k_norm"])
+    v = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wv"], dt))
+    return q, k, v
+
+
+def _rotary(q, k, positions, theta):
+    """q and k rotated to ``positions [B, T]``; ``theta`` None (a hybrid
+    model's ``rope_theta``) applies no rotary embedding."""
+    if theta is None:
+        return q, k
+    return _rope(q, positions, theta), _rope(k, positions, theta)
+
+
+def _attn_out(attn, layer):
+    """``W_o`` over the heads: attn ``[B, T, H, d]`` -> ``[B, T, D]``."""
+    return jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], attn.dtype))
+
+
+def _gated_mlp(h, layer):
+    """``W_d (silu(W_g h) * W_i h)`` for ``h [B, T, D]``."""
+    dt = h.dtype
+    up = jnp.einsum("btd,df->btf", h, _wdq(layer["wi"], dt))
+    gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], dt))
+    y = jax.nn.silu(gate) * up
+    return jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], dt))
+
+
+def _prenorm_block(x, layer, mixer):
+    """The dense model's block, ``x + mixer(rmsnorm(x))`` then ``x +
+    mlp(rmsnorm(x))``.  ``mixer(h) -> (output, what it updated)``: the cache
+    slices of the sequential path, the pool of the paged programs."""
+    mix, updated = mixer(_rmsnorm(x, layer["attn_norm"]))
+    x = x + mix
+    return x + _gated_mlp(_rmsnorm(x, layer["mlp_norm"]), layer), updated
+
+
+def _unembed(x, final_norm, unembed):
+    """Final norm and unembedding: ``x [B, T, D]`` -> logits ``[B, T, V]``."""
+    x = _rmsnorm(x, final_norm)
+    return jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
+
+
+def _attend(q, ck, cv, group, valid_at):
+    """The attention core of every program that reads a cache: q
+    ``[B, T, H, d]`` over ck/cv ``[B, K, Hkv, d]``, masked float32 softmax.
+    ``valid_at(K)`` is the program's mask, broadcastable to the scores
+    ``[B, Hkv, g, T, K]``: a function, because the text the digests hold
+    builds the mask after the scores."""
+    B, K, Hkv, d = ck.shape
+    T = q.shape[1]
+    scale = d**-0.5
+    # GQA stays grouped INSIDE the contraction — the cache is never
+    # materialized at the query-head count, which is the point of storing
+    # unexpanded heads in the bandwidth-bound decode loop.
+    qg = q.reshape(B, T, Hkv, group, d)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale
+    s = jnp.where(valid_at(K), s, -1e30)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv)
+    return out.reshape(B, T, Hkv * group, d)
+
+
 def _attend_cached(q, ck, cv, pos, group):
     """One-token attention against the cache.
 
     q: [B, 1, H, d]; ck/cv: [B, max_len, Hkv, d]; ``pos`` is the current
     absolute position (entries > pos are future/zero slots — masked).
     """
-    B, L, Hkv, d = ck.shape
-    scale = d**-0.5
-    # GQA stays grouped INSIDE the contraction — the cache is never
-    # materialized at the query-head count, which is the point of storing
-    # unexpanded heads in the bandwidth-bound decode loop.
-    qg = q.reshape(B, 1, Hkv, group, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale  # [B,Hkv,g,1,L]
-    valid = (jnp.arange(L) <= pos)[None, None, None, None, :]
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv)
-    return out.reshape(B, 1, Hkv * group, d)
+    return _attend(
+        q, ck, cv, group,
+        lambda L: (jnp.arange(L) <= pos)[None, None, None, None, :],
+    )
 
 
-def _block_step(x, pos, layer, ck, cv, cfg: TransformerConfig):
-    """One transformer block for ONE new token, reading+updating the cache.
-
-    x: [B, 1, D]; ck/cv: [B, max_len, Hkv, d] (this layer's cache slices).
-    Returns (x, ck, cv) with the token's KV rows written at ``pos``.
-    """
-    c = cfg
-    h = _rmsnorm(x, layer["attn_norm"])
-    q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
-    k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
-    v = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wv"], h.dtype))
-    positions = jnp.full((x.shape[0], 1), pos)
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
-    ck = lax.dynamic_update_slice(ck, k, (0, pos, 0, 0))
-    cv = lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
-    attn = _attend_cached(q, ck, cv, pos, c.n_heads // c.kv_heads)
-    x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
-
-    h = _rmsnorm(x, layer["mlp_norm"])
-    up = jnp.einsum("btd,df->btf", h, _wdq(layer["wi"], h.dtype))
-    gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
-    y = jax.nn.silu(gate) * up
-    x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-    return x, ck, cv
+def _with_qweights(params, qweights):
+    """``(layer tree, unembedding)`` a dense program scans and reads: the
+    float tree's, or with ``qweights`` (:func:`quantize_weights`) the int8
+    pairs in the quantized weights' places."""
+    blk = params["block"]
+    if qweights is None:
+        return blk, params["unembed"]
+    # Quantized (q, scale) pairs are ordinary pytree leaves-of-tuples:
+    # scan slices both halves per layer and _wdq sees the pair.
+    layers = {n: blk[n] for n in ("attn_norm", "mlp_norm")}
+    layers.update((n, qweights[n]) for n in QUANTIZED_BLOCK_WEIGHTS)
+    return layers, qweights["unembed"]
 
 
 def decode_step(
@@ -203,32 +270,26 @@ def decode_step(
     stream int8 from HBM, dequantized inside each contraction."""
     c = cfg
     x = params["embed"].astype(c.dtype)[token][:, None, :]  # [B,1,D]
+    layers, unembed = _with_qweights(params, qweights)
 
-    blk = params["block"]
-    if qweights is None:
-        layers = blk
-        unembed = params["unembed"]
-    else:
-        # Quantized (q, scale) pairs are ordinary pytree leaves-of-tuples:
-        # scan slices both halves per layer and _wdq sees the pair.
-        layers = {
-            "attn_norm": blk["attn_norm"],
-            "mlp_norm": blk["mlp_norm"],
-            **{k: qweights[k] for k in QUANTIZED_BLOCK_WEIGHTS},
-        }
-        unembed = qweights["unembed"]
+    def layer_body(x, inputs):
+        layer, ck, cv = inputs  # this layer's cache slices [B, max_len, Hkv, d]
 
-    def layer_body(carry, inputs):
-        x = carry
-        layer, ck, cv = inputs
-        x, ck, cv = _block_step(x, pos, layer, ck, cv, c)
-        return x, (ck, cv)
+        def mixer(h):
+            q, k, v = _qkv(h, layer)
+            q, k = _rotary(q, k, jnp.full((x.shape[0], 1), pos), c.rope_theta)
+            # the token's KV rows land at ``pos``; rows beyond it are masked
+            nk = lax.dynamic_update_slice(ck, k, (0, pos, 0, 0))
+            nv = lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
+            attn = _attend_cached(q, nk, nv, pos, c.n_heads // c.kv_heads)
+            return _attn_out(attn, layer), (nk, nv)
+
+        return _prenorm_block(x, layer, mixer)
 
     x, (new_ck, new_cv) = lax.scan(
         layer_body, x, (layers, cache["k"], cache["v"])
     )
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
+    logits = _unembed(x, params["final_norm"], unembed)
     return logits[:, 0].astype(jnp.float32), {"k": new_ck, "v": new_cv}
 
 
@@ -244,144 +305,18 @@ def prefill(
     MXU-shaped pass whose block is the exact code training runs, so
     prefill can never drift from it; only the cache write lives here.
     """
-    from polyaxon_tpu.models.transformer import forward
-
     logits, (k, v) = forward(params, tokens, cfg, return_kv=True)
     ck = lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0, 0))
     cv = lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0, 0))
     return logits[:, -1], {"k": ck, "v": cv}
 
 
-# -- slot-addressed cache ops (continuous batching) ------------------------
-# The serving engine (polyaxon_tpu/serving/engine.py) owns ONE fixed-shape
-# cache of ``slots`` rows and admits/retires requests at decode-step
-# granularity.  Everything below keeps the [L, S, max_len, Hkv, d] shapes
-# static — slot index, per-slot positions, and the active mask are all
-# DATA, so one compiled step serves any mix of in-flight requests with
-# zero steady-state recompilation.
-
-
-def insert_prompt(
-    cache: Dict[str, jax.Array], slot: jax.Array, k: jax.Array, v: jax.Array
-) -> Dict[str, jax.Array]:
-    """Write one prefilled prompt's KV into batch slot ``slot``.
-
-    k/v: [L, T, Hkv, d] (the ``return_kv`` stacks of a B=1 prefill);
-    ``slot`` is a traced scalar, so reusing a slot never recompiles —
-    only each distinct prompt length T mints a compilation (the engine
-    pads prompts to a small bucket set to bound that).
-    """
-    k = k.astype(cache["k"].dtype)[:, None]  # [L, 1, T, Hkv, d]
-    v = v.astype(cache["v"].dtype)[:, None]
-    return {
-        "k": lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0, 0)),
-        "v": lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0, 0)),
-    }
-
-
-def _attend_slots(q, ck, cv, pos, group):
-    """One-token attention where every slot is at its OWN position.
-
-    q: [S, 1, H, d]; ck/cv: [S, max_len, Hkv, d]; pos: [S] per-slot
-    absolute positions (entries > pos[s] in slot s are future/garbage —
-    masked; a freed slot's stale rows beyond a new occupant's prompt are
-    masked the same way until decode overwrites them in place).
-    """
-    S, L, Hkv, d = ck.shape
-    scale = d**-0.5
-    qg = q.reshape(S, 1, Hkv, group, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale  # [S,Hkv,g,1,L]
-    valid = (jnp.arange(L)[None, :] <= pos[:, None])[:, None, None, None, :]
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv)
-    return out.reshape(S, 1, Hkv * group, d)
-
-
-def _slot_block_step(x, pos, layer, ck, cv, cfg: TransformerConfig):
-    """One transformer block for one token PER SLOT, each at its own
-    position.  x: [S, 1, D]; ck/cv: [S, max_len, Hkv, d]; pos: [S].
-    The per-slot KV row lands via a vmapped dynamic_update_slice (XLA
-    lowers it to a batched scatter — the cache is updated in place, not
-    rewritten)."""
-    c = cfg
-    h = _rmsnorm(x, layer["attn_norm"])
-    q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
-    k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
-    v = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wv"], h.dtype))
-    positions = pos[:, None]  # [S, 1]
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
-    write = jax.vmap(
-        lambda cc, kk, p: lax.dynamic_update_slice(cc, kk, (p, 0, 0))
-    )
-    ck = write(ck, k, pos)
-    cv = write(cv, v, pos)
-    attn = _attend_slots(q, ck, cv, pos, c.n_heads // c.kv_heads)
-    x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
-
-    h = _rmsnorm(x, layer["mlp_norm"])
-    up = jnp.einsum("btd,df->btf", h, _wdq(layer["wi"], h.dtype))
-    gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
-    y = jax.nn.silu(gate) * up
-    x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-    return x, ck, cv
-
-
-def slot_decode_step(
-    params: Dict[str, Any],
-    cache: Dict[str, jax.Array],
-    tokens: jax.Array,
-    pos: jax.Array,
-    active: jax.Array,
-    cfg: TransformerConfig,
-    qweights: Optional[Dict[str, Any]] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Advance a MIXED batch one token: slot s feeds ``tokens[s]`` at
-    absolute position ``pos[s]`` → (logits [S, vocab] f32, updated cache).
-
-    ``active`` [S] bool gates the write position: inactive slots write
-    their (garbage) row at position 0 of their own FREE slot, which the
-    next occupant's prompt insert overwrites — so idle slots cost one
-    wasted lane of compute but can never corrupt a live slot.  This is
-    the engine's one jitted hot function; its shapes depend only on the
-    slot count, so steady-state serving never recompiles.
-    """
-    c = cfg
-    pos = jnp.where(active, pos, 0)
-    x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # [S,1,D]
-
-    blk = params["block"]
-    if qweights is None:
-        layers = blk
-        unembed = params["unembed"]
-    else:
-        layers = {
-            "attn_norm": blk["attn_norm"],
-            "mlp_norm": blk["mlp_norm"],
-            **{k: qweights[k] for k in QUANTIZED_BLOCK_WEIGHTS},
-        }
-        unembed = qweights["unembed"]
-
-    def layer_body(carry, inputs):
-        x = carry
-        layer, ck, cv = inputs
-        x, ck, cv = _slot_block_step(x, pos, layer, ck, cv, c)
-        return x, (ck, cv)
-
-    x, (new_ck, new_cv) = lax.scan(
-        layer_body, x, (layers, cache["k"], cache["v"])
-    )
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
-    return logits[:, 0].astype(jnp.float32), {"k": new_ck, "v": new_cv}
-
-
 # -- paged (block-table) cache ops -----------------------------------------
-# The vLLM-style refinement of the slot cache: KV lives in a POOL of
-# fixed-size blocks [L, num_blocks, block_size, Hkv, d] and each in-flight
-# sequence owns a BLOCK TABLE of physical block ids covering its logical
-# positions.  Two consequences the slot layout can't express:
+# The serving engine (polyaxon_tpu/serving/engine.py) admits and retires
+# requests at decode-step granularity over ONE pool of KV, vLLM-style: KV
+# lives in fixed-size blocks [L, num_blocks, block_size, Hkv, d] and each
+# in-flight sequence owns a BLOCK TABLE of physical block ids covering its
+# logical positions.  Two consequences a cache row per sequence can't express:
 #
 # - **sharing** — two sequences with a common token prefix point their
 #   leading table entries at the SAME physical blocks (the engine
@@ -392,11 +327,16 @@ def slot_decode_step(
 #   length.
 #
 # Shapes stay static everywhere (pool size, table width, chunk bucket);
-# tables, positions, and the active mask are DATA, so steady-state serving
-# still never recompiles.  Block 0 is reserved by the engine as a trash
-# lane: inactive decode lanes and prompt-pad writes land there, and unset
-# table entries point at it — every such read is masked by the position
-# mask before it can influence a live row.
+# tables, positions, and the active mask are DATA, so one compiled step
+# serves any mix of in-flight requests and steady-state serving never
+# recompiles.  Block 0 is reserved by the engine as a trash lane: inactive
+# decode lanes and prompt-pad writes land there, and unset table entries
+# point at it — every such read is masked by the position mask before it can
+# influence a live row.
+
+#: Pool leaves that hold a hybrid model's per-slot recurrent state
+#: (``models/hybrid.py``), not KV blocks.
+REC_LEAVES = ("rec_s", "rec_c")
 
 
 def init_block_pool(
@@ -525,12 +465,49 @@ def _pool_gather(
     return pool[name][layer_idx, table]
 
 
+def _kv_through_table(pool, layer_idx, k, v, table, write_blk, write_off, dtype):
+    """How every paged program of both stacks reads KV through a block table:
+    append this call's rows to layer ``layer_idx``, then gather the whole
+    table width.  ROADMAP S1 (attend without gathering every position of the
+    table) is a change to this function and the attention it feeds.
+
+    k, v: ``[B, T, Hkv, d]`` as projected.  ``write_blk`` / ``write_off`` hold
+    one address per row and lack the axis of size one: a chunk's batch
+    (``table [W]``, addresses ``[C]``), a decode step's T (``table [S, W]``,
+    addresses ``[S]``); a verify step's ``[S, T]`` has both.  Where a pool row
+    holds more heads than the model (``TransformerConfig.pool_kv_heads``) the
+    rows go in padded with zeros and come back sliced.  Returns ``(pool, ck,
+    cv)``, ck/cv ``[B, W * bs, Hkv, d]`` in logical-position order at
+    ``dtype``; the rows just written are among them, which makes a chunk's
+    and a verify run's own tokens their causal keys.
+    """
+    bs, Hp, d = pool_geometry(pool)
+    Hkv = k.shape[-2]
+    lanes = 1 if table.ndim == 1 else table.shape[0]
+
+    def rows(new):
+        if table.ndim == 1:
+            new = new[0]
+        elif write_blk.ndim == 1:
+            new = new[:, 0]
+        if Hp == Hkv:
+            return new
+        return jnp.pad(new, ((0, 0),) * (new.ndim - 2) + ((0, Hp - Hkv), (0, 0)))
+
+    def gathered(pool, name):
+        got = _pool_gather(pool, name, layer_idx, table, dtype)
+        got = got.reshape(lanes, table.shape[-1] * bs, Hp, d)
+        return got if Hp == Hkv else got[:, :, :Hkv]
+
+    # V's rows are cut out after K's are in: the order of the text the digests hold.
+    pool = _pool_append(pool, "k", layer_idx, rows(k), write_blk, write_off)
+    pool = _pool_append(pool, "v", layer_idx, rows(v), write_blk, write_off)
+    return pool, gathered(pool, "k"), gathered(pool, "v")
+
+
 def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """The leaves addressed by block: all but a hybrid model's per-slot
-    recurrent rows (``models/hybrid.py:REC_LEAVES``), which ride the same
-    dict."""
-    from polyaxon_tpu.models.hybrid import REC_LEAVES
-
+    recurrent rows (``REC_LEAVES``), which ride the same dict."""
     return {n: leaf for n, leaf in pool.items() if n not in REC_LEAVES}
 
 
@@ -592,6 +569,62 @@ def import_block(
     return out
 
 
+def _chunk_addresses(pool, table, start, length, C):
+    """A chunk's C rows: their absolute positions ``qpos``, which of them are
+    real (``valid``), the ``(block, offset)`` each is written at, and the
+    positions ``kpos [1, W * bs]`` of the keys the table gathers."""
+    W = table.shape[0]
+    bs = pool_geometry(pool)[0]
+    qpos = start + jnp.arange(C)  # [C] absolute positions
+    valid = jnp.arange(C) < length
+    # Pad writes are redirected to the trash block: their logical blocks
+    # may not be allocated yet (they belong to future generation).
+    write_blk = jnp.where(valid, table[jnp.clip(qpos // bs, 0, W - 1)], 0)
+    write_off = jnp.where(valid, qpos % bs, 0)
+    kpos = jnp.arange(W * bs)[None]  # gathered keys sit in logical order
+    return qpos, valid, write_blk, write_off, kpos
+
+
+def _chunk_mixer(cfg, table, positions, kpos, write_blk, write_off):
+    """The attention mixer of a prompt chunk, for the dense layers and the
+    hybrid stack's full layers.  The chunk's rows are written, then attended
+    with everything else in the table, in the training ``forward``'s own form
+    (GQA heads broadcast, ``_dense_attention``'s masked f32 softmax): greedy
+    outputs stay token-identical to the sequential :func:`generate` path."""
+    group = cfg.n_heads // cfg.kv_heads
+
+    def mixer(h, layer, li, pool):
+        q, k, v = _qkv(h, layer)
+        q, k = _rotary(q, k, positions, cfg.rope_theta)
+        pool, ck, cv = _kv_through_table(
+            pool, li, k, v, table, write_blk, write_off, h.dtype
+        )
+        if group > 1:
+            ck = jnp.repeat(ck, group, axis=2)
+            cv = jnp.repeat(cv, group, axis=2)
+        attn = _dense_attention(q, ck, cv, positions, kpos)
+        return _attn_out(attn, layer), pool
+
+    return mixer
+
+
+def _run_uniform_stack(x, layers, pool, n_layers, mixer):
+    """The dense programs' layer loop: a scan over the weights and the layer
+    index that CARRIES ``(x, pool)``, each layer the pre-norm block around the
+    program's ``mixer(h, layer, layer index, pool) -> (output, pool)``.  Each
+    layer writes and reads the stacked leaves at ``[layer, block, offset]`` in
+    place, so with the pool donated the buffer that enters is the one that
+    leaves: no per-layer slice, no second stacked pool."""
+
+    def body(carry, inputs):
+        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
+        layer, li = inputs
+        return _prenorm_block(x, layer, lambda h: mixer(h, layer, li, pool)), None
+
+    (x, pool), _ = lax.scan(body, (x, pool), (layers, jnp.arange(n_layers)))
+    return x, pool
+
+
 def paged_prefill_chunk(
     params: Dict[str, Any],
     pool: Dict[str, jax.Array],
@@ -616,15 +649,8 @@ def paged_prefill_chunk(
     prefix-reuse recompute the same operation.  Pad positions write their
     garbage rows to trash block 0 and are masked out of attention.
 
-    Numerics mirror the training ``forward`` block exactly (broadcast GQA
-    heads, ``_dense_attention``'s masked f32 softmax), so greedy outputs
-    stay token-identical to the sequential :func:`generate` path.
-
-    The layer loop CARRIES ``(x, pool)`` and scans over the weights and
-    the layer index only: each layer writes and reads the stacked leaves
-    at ``[layer, block, offset]`` in place, so with the pool donated the
-    buffer that enters is the one that leaves — no per-layer slice, no
-    second stacked pool.
+    Numerics mirror the training ``forward`` block (:func:`_chunk_mixer`);
+    the layer loop is :func:`_run_uniform_stack`.
 
     A model with a layer pattern (``cfg.layer_types``) goes through
     ``models/hybrid.py``'s form of this program instead: it also needs the
@@ -637,58 +663,14 @@ def paged_prefill_chunk(
         return hybrid.paged_prefill_chunk(
             params, pool, table, tokens, start, length, slot, cfg
         )
-    from polyaxon_tpu.models.transformer import _dense_attention
-
     c = cfg
-    C = tokens.shape[0]
-    W = table.shape[0]
-    bs, Hkv, d = pool_geometry(pool)
-    group = c.n_heads // c.kv_heads
-
-    qpos = start + jnp.arange(C)  # [C] absolute positions
-    valid = jnp.arange(C) < length
-    # Pad writes are redirected to the trash block: their logical blocks
-    # may not be allocated yet (they belong to future generation).
-    write_blk = jnp.where(valid, table[jnp.clip(qpos // bs, 0, W - 1)], 0)
-    write_off = jnp.where(valid, qpos % bs, 0)
-    kpos = jnp.arange(W * bs)[None]  # gathered keys sit in logical order
-
-    x = params["embed"].astype(c.dtype)[tokens][None]  # [1, C, D]
-    positions = qpos[None]  # [1, C]
-
-    def layer_body(carry, inputs):
-        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
-        layer, li = inputs
-        h = _rmsnorm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
-        q = _rope(q, positions, c.rope_theta)
-        k = _rope(k, positions, c.rope_theta)
-        # Write the chunk's KV rows, then attend against the whole table —
-        # the rows just written ARE the chunk's causal self-attention keys.
-        pool = _pool_append(pool, "k", li, k[0], write_blk, write_off)
-        pool = _pool_append(pool, "v", li, v[0], write_blk, write_off)
-        ck = _pool_gather(pool, "k", li, table, h.dtype).reshape(1, W * bs, Hkv, d)
-        cv = _pool_gather(pool, "v", li, table, h.dtype).reshape(1, W * bs, Hkv, d)
-        if group > 1:
-            ck = jnp.repeat(ck, group, axis=2)
-            cv = jnp.repeat(cv, group, axis=2)
-        attn = _dense_attention(q, ck, cv, positions, kpos)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, layer["wo"].astype(h.dtype))
-
-        h = _rmsnorm(x, layer["mlp_norm"])
-        up = jnp.einsum("btd,df->btf", h, layer["wi"].astype(h.dtype))
-        gate = jnp.einsum("btd,df->btf", h, layer["wg"].astype(h.dtype))
-        y = jax.nn.silu(gate) * up
-        x = x + jnp.einsum("btf,fd->btd", y, layer["wd"].astype(h.dtype))
-        return (x, pool), None
-
-    (x, new_pool), _ = lax.scan(
-        layer_body, (x, pool), (params["block"], jnp.arange(c.n_layers))
+    qpos, _, write_blk, write_off, kpos = _chunk_addresses(
+        pool, table, start, length, tokens.shape[0]
     )
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(x.dtype))
+    x = params["embed"].astype(c.dtype)[tokens][None]  # [1, C, D]
+    mixer = _chunk_mixer(c, table, qpos[None], kpos, write_blk, write_off)
+    x, new_pool = _run_uniform_stack(x, params["block"], pool, c.n_layers, mixer)
+    logits = _unembed(x, params["final_norm"], params["unembed"])
     last = jnp.take(logits[0], length - 1, axis=0)
     return last.astype(jnp.float32), new_pool
 
@@ -697,20 +679,16 @@ def _attend_paged(q, ck, cv, pos, group):
     """One-token attention over block-table-gathered KV.
 
     q: [S, 1, H, d]; ck/cv: [S, W*bs, Hkv, d] in logical-position order;
-    pos: [S] per-slot absolute positions.  Identical contraction shape to
-    :func:`_attend_slots` — the gather changed where keys LIVE, not how a
+    pos: [S] per-slot absolute positions (entries > pos[s] in lane s are
+    future or stale rows — masked).  The contraction is
+    :func:`_attend_cached`'s — the gather changed where keys LIVE, not how a
     row attends — which is what keeps paged greedy outputs token-identical
-    to the slot (and sequential) paths.
+    to the sequential path.
     """
-    S, K, Hkv, d = ck.shape
-    scale = d**-0.5
-    qg = q.reshape(S, 1, Hkv, group, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale  # [S,Hkv,g,1,K]
-    valid = (jnp.arange(K)[None, :] <= pos[:, None])[:, None, None, None, :]
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv)
-    return out.reshape(S, 1, Hkv * group, d)
+    return _attend(
+        q, ck, cv, group,
+        lambda K: (jnp.arange(K)[None, :] <= pos[:, None])[:, None, None, None, :],
+    )
 
 
 def paged_decode_step(
@@ -726,17 +704,16 @@ def paged_decode_step(
     """Advance a mixed batch one token against the paged pool.
 
     tables: [S, W] physical block ids per slot (the engine maps unset
-    entries to trash block 0); tokens/pos/active as in
-    :func:`slot_decode_step`.  Inactive lanes write their garbage row to
-    block 0 offset 0 — never into a live block — and every gathered
-    position beyond a slot's ``pos`` is masked.  Shapes depend only on
-    (slots, pool size, table width): steady-state serving never
-    recompiles, whichever requests come and go or how their blocks are
-    scattered across the pool.
-
-    The layer loop carries ``(x, pool)`` — the whole pool, updated in
-    place at ``[layer, block, offset]`` — and scans over the weights and
-    the layer index; see :func:`paged_prefill_chunk`.
+    entries to trash block 0); slot s feeds ``tokens[s]`` at absolute
+    position ``pos[s]``, and ``active`` [S] bool says which slots hold a
+    request.  Inactive lanes write their garbage row to block 0 offset 0 —
+    an idle slot costs one wasted lane of compute and can never corrupt a
+    live block — and every gathered position beyond a slot's ``pos`` is
+    masked.  Shapes depend only on (slots, pool size, table width):
+    steady-state serving never recompiles, whichever requests come and go
+    or how their blocks are scattered across the pool.  Returns
+    ``(logits [S, vocab] f32, new_pool)``; the layer loop is
+    :func:`_run_uniform_stack`.
     """
     if cfg.layer_types is not None:
         from polyaxon_tpu.models import hybrid
@@ -745,55 +722,26 @@ def paged_decode_step(
             params, pool, tables, tokens, pos, active, cfg, qweights=qweights
         )
     c = cfg
-    S, W = tables.shape
-    bs, Hkv, d = pool_geometry(pool)
+    S = tables.shape[0]
+    bs = pool_geometry(pool)[0]
     pos = jnp.where(active, pos, 0)
     write_blk = jnp.where(active, tables[jnp.arange(S), pos // bs], 0)
     write_off = jnp.where(active, pos % bs, 0)
 
     x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # [S,1,D]
+    layers, unembed = _with_qweights(params, qweights)
 
-    blk = params["block"]
-    if qweights is None:
-        layers = blk
-        unembed = params["unembed"]
-    else:
-        layers = {
-            "attn_norm": blk["attn_norm"],
-            "mlp_norm": blk["mlp_norm"],
-            **{k: qweights[k] for k in QUANTIZED_BLOCK_WEIGHTS},
-        }
-        unembed = qweights["unembed"]
-
-    def layer_body(carry, inputs):
-        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
-        layer, li = inputs
-        h = _rmsnorm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wv"], h.dtype))
-        positions = pos[:, None]  # [S, 1]
-        q = _rope(q, positions, c.rope_theta)
-        k = _rope(k, positions, c.rope_theta)
-        pool = _pool_append(pool, "k", li, k[:, 0], write_blk, write_off)
-        pool = _pool_append(pool, "v", li, v[:, 0], write_blk, write_off)
-        ck = _pool_gather(pool, "k", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
-        cv = _pool_gather(pool, "v", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
+    def mixer(h, layer, li, pool):
+        q, k, v = _qkv(h, layer)
+        q, k = _rotary(q, k, pos[:, None], c.rope_theta)
+        pool, ck, cv = _kv_through_table(
+            pool, li, k, v, tables, write_blk, write_off, h.dtype
+        )
         attn = _attend_paged(q, ck, cv, pos, c.n_heads // c.kv_heads)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
+        return _attn_out(attn, layer), pool
 
-        h = _rmsnorm(x, layer["mlp_norm"])
-        up = jnp.einsum("btd,df->btf", h, _wdq(layer["wi"], h.dtype))
-        gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
-        y = jax.nn.silu(gate) * up
-        x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-        return (x, pool), None
-
-    (x, new_pool), _ = lax.scan(
-        layer_body, (x, pool), (layers, jnp.arange(c.n_layers))
-    )
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
+    x, new_pool = _run_uniform_stack(x, layers, pool, c.n_layers, mixer)
+    logits = _unembed(x, params["final_norm"], unembed)
     return logits[:, 0].astype(jnp.float32), new_pool
 
 
@@ -803,22 +751,17 @@ def _attend_spec(q, ck, cv, qpos, group):
     The T-row generalization of :func:`_attend_paged` for speculative
     verification: q [S, T, H, d] carries one query row per drafted token,
     ck/cv [S, W*bs, Hkv, d] sit in logical-position order, and qpos
-    [S, T] gives each row's absolute position.  Per output element the
-    contraction and the masked f32 softmax are identical to the T=1
-    step's, which is what keeps a verify row's logits bit-identical to
+    [S, T] gives each row's absolute position.  The core is the T=1 step's
+    (:func:`_attend`), which keeps a verify row's logits bit-identical to
     the single-token decode step that would have produced them — the
     foundation of the greedy parity guarantee.
     """
-    S, K, Hkv, d = ck.shape
-    T = q.shape[1]
-    scale = d**-0.5
-    qg = q.reshape(S, T, Hkv, group, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale  # [S,Hkv,g,T,K]
-    valid = jnp.arange(K)[None, None, :] <= qpos[:, :, None]  # [S,T,K]
-    s = jnp.where(valid[:, None, None, :, :], s, -1e30)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv)
-    return out.reshape(S, T, Hkv * group, d)
+
+    def valid_at(K):
+        valid = jnp.arange(K)[None, None, :] <= qpos[:, :, None]  # [S,T,K]
+        return valid[:, None, None, :, :]
+
+    return _attend(q, ck, cv, group, valid_at)
 
 
 def paged_verify_step(
@@ -856,12 +799,9 @@ def paged_verify_step(
     proceeds; whole tail blocks are freed host-side
     (:func:`~polyaxon_tpu.serving.paging.truncate_table`).
 
-    Numerics mirror :func:`paged_decode_step` exactly — same ``_wdq``
-    weight streaming (int8 qweights compose), same ``_pool_append`` /
-    ``_pool_gather`` (int8 KV pools compose), same masked f32 softmax —
-    so greedy outputs stay token-identical to the non-speculative path.
-    The layer loop carries ``(x, pool)`` the same way: the whole pool,
-    written and read in place by (layer, block, offset).
+    The layer is :func:`paged_decode_step`'s, piece for piece (int8
+    qweights and int8 KV pools compose the same way), so greedy outputs
+    stay token-identical to the non-speculative path.
     """
     if cfg.layer_types is not None:
         from polyaxon_tpu.models.hybrid import RecurrentStateError
@@ -870,7 +810,7 @@ def paged_verify_step(
     c = cfg
     S, W = tables.shape
     T = tokens.shape[1]
-    bs, Hkv, d = pool_geometry(pool)
+    bs = pool_geometry(pool)[0]
     pos = jnp.where(active, pos, 0)
     qpos = pos[:, None] + jnp.arange(T)[None, :]  # [S, T] absolute
     row_ok = active[:, None] & (jnp.arange(T)[None, :] < n_tok[:, None])
@@ -882,49 +822,19 @@ def paged_verify_step(
     write_off = jnp.where(row_ok, qpos % bs, 0)
 
     x = params["embed"].astype(c.dtype)[tokens]  # [S, T, D]
+    layers, unembed = _with_qweights(params, qweights)
 
-    blk = params["block"]
-    if qweights is None:
-        layers = blk
-        unembed = params["unembed"]
-    else:
-        layers = {
-            "attn_norm": blk["attn_norm"],
-            "mlp_norm": blk["mlp_norm"],
-            **{k: qweights[k] for k in QUANTIZED_BLOCK_WEIGHTS},
-        }
-        unembed = qweights["unembed"]
-
-    def layer_body(carry, inputs):
-        x, pool = carry  # the WHOLE pool rides the loop: [L, NB, bs, Hkv, ...]
-        layer, li = inputs
-        h = _rmsnorm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wq"], h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wk"], h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, _wdq(layer["wv"], h.dtype))
-        q = _rope(q, qpos, c.rope_theta)
-        k = _rope(k, qpos, c.rope_theta)
-        # Write every row, then gather: rows written earlier in the run
-        # ARE later rows' causal keys, exactly like a prefill chunk.
-        pool = _pool_append(pool, "k", li, k, write_blk, write_off)
-        pool = _pool_append(pool, "v", li, v, write_blk, write_off)
-        ck = _pool_gather(pool, "k", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
-        cv = _pool_gather(pool, "v", li, tables, h.dtype).reshape(S, W * bs, Hkv, d)
+    def mixer(h, layer, li, pool):
+        q, k, v = _qkv(h, layer)
+        q, k = _rotary(q, k, qpos, c.rope_theta)
+        pool, ck, cv = _kv_through_table(
+            pool, li, k, v, tables, write_blk, write_off, h.dtype
+        )
         attn = _attend_spec(q, ck, cv, qpos, c.n_heads // c.kv_heads)
-        x = x + jnp.einsum("bthk,hkd->btd", attn, _wdq(layer["wo"], h.dtype))
+        return _attn_out(attn, layer), pool
 
-        h = _rmsnorm(x, layer["mlp_norm"])
-        up = jnp.einsum("btd,df->btf", h, _wdq(layer["wi"], h.dtype))
-        gate = jnp.einsum("btd,df->btf", h, _wdq(layer["wg"], h.dtype))
-        y = jax.nn.silu(gate) * up
-        x = x + jnp.einsum("btf,fd->btd", y, _wdq(layer["wd"], h.dtype))
-        return (x, pool), None
-
-    (x, new_pool), _ = lax.scan(
-        layer_body, (x, pool), (layers, jnp.arange(c.n_layers))
-    )
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
+    x, new_pool = _run_uniform_stack(x, layers, pool, c.n_layers, mixer)
+    logits = _unembed(x, params["final_norm"], unembed)
     return logits.astype(jnp.float32), new_pool
 
 

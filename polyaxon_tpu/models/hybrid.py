@@ -42,10 +42,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from polyaxon_tpu.models import decode
+from polyaxon_tpu.models.transformer import _rmsnorm
+from polyaxon_tpu.parallel.delta_rule import gated_delta_prefill, gated_delta_step
+
 FULL = "full_attention"
 LINEAR = "linear_attention"
-#: Pool leaves that hold per-slot recurrent state, not KV blocks.
-REC_LEAVES = ("rec_s", "rec_c")
 _SHARED = ("mixer_norm", "mlp_norm", "wi", "wg", "wd")
 
 
@@ -252,7 +254,7 @@ def _with_qweights(params, qweights):
 
 def init_rec_state(cfg, rows: int) -> Dict[str, jax.Array]:
     """Zeroed recurrent state for ``rows`` sequences (the engine's slots, or
-    the places of its snapshot store): the ``REC_LEAVES``."""
+    the places of its snapshot store): the ``decode.REC_LEAVES``."""
     c = cfg
     _, n_lin = _counts(c)
     return {
@@ -280,7 +282,7 @@ def take_snapshot(store, pool, slot, idx):
         name: lax.dynamic_update_slice_in_dim(
             store[name], lax.dynamic_slice_in_dim(pool[name], slot, 1, axis=1),
             idx, axis=1)
-        for name in REC_LEAVES
+        for name in decode.REC_LEAVES
     }
 
 
@@ -288,7 +290,7 @@ def restore_snapshot(pool, store, idx, slot):
     """Copy place ``idx`` of the snapshot store into slot ``slot``'s recurrent
     rows (jit with the POOL donated)."""
     out = dict(pool)
-    for name in REC_LEAVES:
+    for name in decode.REC_LEAVES:
         out[name] = lax.dynamic_update_slice_in_dim(
             pool[name], lax.dynamic_slice_in_dim(store[name], idx, 1, axis=1),
             slot, axis=1)
@@ -302,15 +304,7 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def _qk_norm(x, w):
-    """RMSNorm over the whole projection (all heads), then split again."""
-    from polyaxon_tpu.models.transformer import _rmsnorm
-
-    shape = x.shape
-    return _rmsnorm(x.reshape(shape[:-2] + (-1,)), w).reshape(shape)
-
-
-def _linear_inputs(h, lp, cfg, wdq):
+def _linear_inputs(h, lp, cfg):
     """What the rule needs of one layer, from its input ``h [..., D]``: the
     pre-convolution ``q|k|v`` channels (float32), the gate, log alpha, beta."""
     c = cfg
@@ -318,10 +312,10 @@ def _linear_inputs(h, lp, cfg, wdq):
     f32 = jnp.float32
     lead = h.shape[:-1]
     qkv = jnp.concatenate([
-        jnp.einsum("...d,dhk->...hk", h, wdq(lp[n], dt)).reshape(lead + (-1,))
+        jnp.einsum("...d,dhk->...hk", h, decode._wdq(lp[n], dt)).reshape(lead + (-1,))
         for n in ("wq", "wk", "wv")
     ], axis=-1).astype(f32)
-    gate = jnp.einsum("...d,dhk->...hk", h, wdq(lp["wg"], dt))
+    gate = jnp.einsum("...d,dhk->...hk", h, decode._wdq(lp["wg"], dt))
     a = jnp.einsum("...d,dh->...h", h, lp["wa"].astype(dt)).astype(f32)
     b = jnp.einsum("...d,dh->...h", h, lp["wb"].astype(dt)).astype(f32)
     g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(a + lp["dt_bias"].astype(f32))
@@ -347,21 +341,18 @@ def _conv_weights(lp):
     ).astype(jnp.float32)  # [K, channels]
 
 
-def _linear_out(o, gate, lp, dtype, wdq):
+def _linear_out(o, gate, lp, dtype):
     """``W_o (rmsnorm(o) * w * silu(gate))``, the norm over each head."""
-    from polyaxon_tpu.models.transformer import _rmsnorm
-
     y = _rmsnorm(o, lp["o_norm"]).astype(dtype) * jax.nn.silu(gate)
-    return jnp.einsum("...hv,hvd->...d", y, wdq(lp["wo"], dtype))
+    return jnp.einsum("...hv,hvd->...d", y, decode._wdq(lp["wo"], dtype))
 
 
-def _run_stack(x, blk, pool, cfg, full_fn, linear_fn, wdq):
+def _run_stack(x, blk, pool, cfg, full_fn, linear_fn):
     """The layer loop: a scan over periods, the period's layers unrolled.
     ``full_fn`` / ``linear_fn`` ``(x, layer weights, index among its kind,
     pool) -> (mixer output, pool)``; the pool (KV and recurrent leaves) is the
-    carry, updated in place."""
-    from polyaxon_tpu.models.transformer import _rmsnorm
-
+    carry, updated in place.  (The dense model's loop, a layer an iteration,
+    is ``decode._run_uniform_stack``.)"""
     per = period(cfg)
     n_periods = cfg.n_layers // len(per)
     n_full, n_lin = per.count(FULL), per.count(LINEAR)
@@ -393,37 +384,11 @@ def _run_stack(x, blk, pool, cfg, full_fn, linear_fn, wdq):
                 mix, pool = linear_fn(x, at(linear, jl), pi * n_lin + jl, pool)
                 jl += 1
             x = x + _rmsnorm(mix, lay["mixer_norm"])
-            up = jnp.einsum("...d,df->...f", x, wdq(lay["wi"], x.dtype))
-            gate = jnp.einsum("...d,df->...f", x, wdq(lay["wg"], x.dtype))
-            y = jax.nn.silu(gate) * up
-            m = jnp.einsum("...f,fd->...d", y, wdq(lay["wd"], x.dtype))
-            x = x + _rmsnorm(m, lay["mlp_norm"])
+            x = x + _rmsnorm(decode._gated_mlp(x, lay), lay["mlp_norm"])
         return (x, pool), None
 
     (x, pool), _ = lax.scan(body, (x, pool), xs)
     return x, pool
-
-
-def _full_qkv(x, lp, positions, cfg, wdq):
-    from polyaxon_tpu.models.transformer import _rope
-
-    dt = x.dtype
-    q = _qk_norm(jnp.einsum("btd,dhk->bthk", x, wdq(lp["wq"], dt)), lp["q_norm"])
-    k = _qk_norm(jnp.einsum("btd,dhk->bthk", x, wdq(lp["wk"], dt)), lp["k_norm"])
-    v = jnp.einsum("btd,dhk->bthk", x, wdq(lp["wv"], dt))
-    if cfg.rope_theta is not None:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    return q, k, v
-
-
-def _pad_heads(rows, heads):
-    """KV rows ``[..., Hkv, d]`` as the pool stores them: ``heads`` of them,
-    zeros after the model's own (``TransformerConfig.pool_kv_heads``)."""
-    extra = heads - rows.shape[-2]
-    if not extra:
-        return rows
-    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 2) + ((0, extra), (0, 0)))
 
 
 def _row(leaf, li, slot):
@@ -437,7 +402,7 @@ def _set_row(leaf, li, slot, value):
     return lax.dynamic_update_slice(leaf, value[None, None].astype(leaf.dtype), start)
 
 
-# -- the three paged programs ----------------------------------------------------
+# -- the two paged programs ------------------------------------------------------
 
 
 def paged_prefill_chunk(params, pool, table, tokens, start, length, slot, cfg):
@@ -447,45 +412,22 @@ def paged_prefill_chunk(params, pool, table, tokens, start, length, slot, cfg):
     chunkwise rule (from zeros where ``start == 0``) and put them back.  Pad
     positions write KV to the trash block and leave the recurrent rows as the
     last real token left them."""
-    from polyaxon_tpu.models.decode import (
-        _pool_append, _pool_gather, _wdq, pool_geometry,
-    )
-    from polyaxon_tpu.models.transformer import _dense_attention, _rmsnorm
-    from polyaxon_tpu.parallel.delta_rule import gated_delta_prefill
-
     c = cfg
     C = tokens.shape[0]
-    W = table.shape[0]
-    bs, Hp, d = pool_geometry(pool)  # a pool row holds Hp >= Hkv heads, the rest zeros
-    Hkv = c.kv_heads
-    group = c.n_heads // c.kv_heads
     K = c.linear_conv_kernel_dim
-
-    qpos = start + jnp.arange(C)
-    valid = jnp.arange(C) < length
-    write_blk = jnp.where(valid, table[jnp.clip(qpos // bs, 0, W - 1)], 0)
-    write_off = jnp.where(valid, qpos % bs, 0)
-    kpos = jnp.arange(W * bs)[None]
+    qpos, valid, write_blk, write_off, kpos = decode._chunk_addresses(
+        pool, table, start, length, C
+    )
     positions = qpos[None]
     fresh = start == 0
 
     x = params["embed"].astype(c.dtype)[tokens][None]  # [1, C, D]
 
-    def full_fn(x, lp, li, pool):
-        q, k, v = _full_qkv(x, lp, positions, c, _wdq)
-        pool = _pool_append(pool, "k", li, _pad_heads(k[0], Hp), write_blk, write_off)
-        pool = _pool_append(pool, "v", li, _pad_heads(v[0], Hp), write_blk, write_off)
-        ck = _pool_gather(pool, "k", li, table, x.dtype).reshape(1, W * bs, Hp, d)[:, :, :Hkv]
-        cv = _pool_gather(pool, "v", li, table, x.dtype).reshape(1, W * bs, Hp, d)[:, :, :Hkv]
-        if group > 1:
-            ck = jnp.repeat(ck, group, axis=2)
-            cv = jnp.repeat(cv, group, axis=2)
-        attn = _dense_attention(q, ck, cv, positions, kpos)
-        return jnp.einsum("bthk,hkd->btd", attn, _wdq(lp["wo"], x.dtype)), pool
+    full_fn = decode._chunk_mixer(c, table, positions, kpos, write_blk, write_off)
 
     def linear_fn(x, lp, li, pool):
         h = x[0]
-        qkv, gate, g, beta = _linear_inputs(h, lp, c, _wdq)
+        qkv, gate, g, beta = _linear_inputs(h, lp, c)
         tail = jnp.where(fresh, 0.0, _row(pool["rec_c"], li, slot).astype(jnp.float32))
         s0 = jnp.where(fresh, 0.0, _row(pool["rec_s"], li, slot).astype(jnp.float32))
         ext = jnp.concatenate([tail, qkv], axis=0)  # [K-1+C, ch]
@@ -502,9 +444,9 @@ def paged_prefill_chunk(params, pool, table, tokens, start, length, slot, cfg):
                 pool["rec_c"], li, slot,
                 lax.dynamic_slice_in_dim(ext, length, K - 1, axis=0)),
         }
-        return _linear_out(o, gate, lp, x.dtype, _wdq)[None], pool
+        return _linear_out(o, gate, lp, x.dtype)[None], pool
 
-    x, pool = _run_stack(x, params["block"], pool, c, full_fn, linear_fn, _wdq)
+    x, pool = _run_stack(x, params["block"], pool, c, full_fn, linear_fn)
     # Only the last real token's logits are read: unembed that one row, not
     # the chunk's C rows against the whole vocabulary.
     last = _rmsnorm(jnp.take(x[0], length - 1, axis=0), params["final_norm"])
@@ -516,16 +458,9 @@ def paged_decode_step(params, pool, tables, tokens, pos, active, cfg, qweights=N
     """``decode.paged_decode_step`` for the hybrid stack: every active slot one
     token.  The linear layers advance each active slot's recurrent rows one
     step of the rule; an inactive (free or parked) slot keeps its rows."""
-    from polyaxon_tpu.models.decode import (
-        _attend_paged, _pool_append, _pool_gather, _wdq, pool_geometry,
-    )
-    from polyaxon_tpu.models.transformer import _rmsnorm
-    from polyaxon_tpu.parallel.delta_rule import gated_delta_step
-
     c = cfg
-    S, W = tables.shape
-    bs, Hp, d = pool_geometry(pool)
-    Hkv = c.kv_heads
+    S = tables.shape[0]
+    bs = decode.pool_geometry(pool)[0]
     pos = jnp.where(active, pos, 0)
     write_blk = jnp.where(active, tables[jnp.arange(S), pos // bs], 0)
     write_off = jnp.where(active, pos % bs, 0)
@@ -535,16 +470,16 @@ def paged_decode_step(params, pool, tables, tokens, pos, active, cfg, qweights=N
     blk, unembed = _with_qweights(params, qweights)
 
     def full_fn(x, lp, li, pool):
-        q, k, v = _full_qkv(x, lp, positions, c, _wdq)
-        pool = _pool_append(pool, "k", li, _pad_heads(k[:, 0], Hp), write_blk, write_off)
-        pool = _pool_append(pool, "v", li, _pad_heads(v[:, 0], Hp), write_blk, write_off)
-        ck = _pool_gather(pool, "k", li, tables, x.dtype).reshape(S, W * bs, Hp, d)[:, :, :Hkv]
-        cv = _pool_gather(pool, "v", li, tables, x.dtype).reshape(S, W * bs, Hp, d)[:, :, :Hkv]
-        attn = _attend_paged(q, ck, cv, pos, c.n_heads // c.kv_heads)
-        return jnp.einsum("bthk,hkd->btd", attn, _wdq(lp["wo"], x.dtype)), pool
+        q, k, v = decode._qkv(x, lp)
+        q, k = decode._rotary(q, k, positions, c.rope_theta)
+        pool, ck, cv = decode._kv_through_table(
+            pool, li, k, v, tables, write_blk, write_off, x.dtype
+        )
+        attn = decode._attend_paged(q, ck, cv, pos, c.n_heads // c.kv_heads)
+        return decode._attn_out(attn, lp), pool
 
     def linear_fn(x, lp, li, pool):
-        qkv, gate, g, beta = _linear_inputs(x[:, 0], lp, c, _wdq)
+        qkv, gate, g, beta = _linear_inputs(x[:, 0], lp, c)
         rec_s, rec_c = pool["rec_s"], pool["rec_c"]
         tail = lax.dynamic_index_in_dim(rec_c, li, 0, keepdims=False).astype(jnp.float32)
         s0 = lax.dynamic_index_in_dim(rec_s, li, 0, keepdims=False).astype(jnp.float32)
@@ -559,9 +494,8 @@ def paged_decode_step(params, pool, tables, tokens, pos, active, cfg, qweights=N
             "rec_c": lax.dynamic_update_index_in_dim(
                 rec_c, jnp.where(keep, window[:, 1:], tail).astype(rec_c.dtype), li, 0),
         }
-        return _linear_out(o, gate, lp, x.dtype, _wdq)[:, None], pool
+        return _linear_out(o, gate, lp, x.dtype)[:, None], pool
 
-    x, pool = _run_stack(x, blk, pool, c, full_fn, linear_fn, _wdq)
-    x = _rmsnorm(x, params["final_norm"])
-    logits = jnp.einsum("btd,dv->btv", x, _wdq(unembed, x.dtype))
+    x, pool = _run_stack(x, blk, pool, c, full_fn, linear_fn)
+    logits = decode._unembed(x, params["final_norm"], unembed)
     return logits[:, 0].astype(jnp.float32), pool

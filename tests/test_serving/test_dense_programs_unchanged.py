@@ -20,6 +20,13 @@ programs the embeddings and matmul weights in ``cfg.dtype`` already
 arguments and its text has lost their ``convert`` lines.  The six program
 digests above it are taken on the float32 tree as before and did not move:
 ``models/decode.py``'s programs are the ones PR 28 left.
+
+PR 31 wrote the served layer once for both stacks (one walk through the block
+table, one attention core, one MLP) and retook nothing: the seven digests above
+are the parent's letter for letter.  It gave the hybrid stack's two programs
+the same guard first: ``HYBRID_AT_PR_30``, at ``olmo-hybrid-7b-serve``'s toy
+sizes (4 KV heads in pool rows of 8, so the padding is in the text), taken at
+a66de81, the commit PR 31 started from, before ``models/`` was edited.
 """
 
 import hashlib
@@ -32,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from polyaxon_tpu.models import TransformerConfig, decode, init_params
+from polyaxon_tpu.models import TransformerConfig, decode, hybrid, init_params
 
 ROOT = Path(__file__).resolve().parents[2]
 TOY = json.loads((ROOT / "benchmark/configs/mistral-7b-v0.3-serve.json").read_text())["toy"]
@@ -54,11 +61,37 @@ AT_PR_28 = {  # "engine-step" at PR 30, see above
 }
 
 
-def _lowered_text(kind, kvq):
+# The hybrid stack's two programs, at ``olmo-hybrid-7b-serve``'s toy sizes over
+# the configuration's own pattern and switches: see the docstring's last part.
+_HYBRID = json.loads((ROOT / "benchmark/configs/olmo-hybrid-7b-serve.json").read_text())
+_HYBRID = {**_HYBRID, **_HYBRID["toy"]}
+HYBRID_ENGINE = _HYBRID["engine"]
+HYBRID_CFG = TransformerConfig(
+    vocab_size=_HYBRID["vocab_size"], d_model=_HYBRID["hidden_size"],
+    n_layers=_HYBRID["num_hidden_layers"], n_heads=_HYBRID["num_attention_heads"],
+    head_dim=_HYBRID["head_dim"], d_ff=_HYBRID["intermediate_size"],
+    n_kv_heads=_HYBRID["num_key_value_heads"], max_seq=HYBRID_ENGINE["seq"],
+    rope_theta=_HYBRID["rope_theta"], layer_types=tuple(_HYBRID["layer_types"]),
+    **{k: v for k, v in _HYBRID.items() if k.startswith("linear_")})
+
+HYBRID_AT_PR_30 = {
+    "prefill-kv": "dfc70e50490ac7255b8d44c5ddad4e0556e5696a6bc3b038ced434bc4c4e22f5",
+    "decode-kv": "67b94d4c18bc65ecb70a260f8edbd8c31f5c618e809278fcd06fa437df8a0911",
+    "prefill-int8": "922225a09dff757ba5c0c2c344d53cbc9c747309d2710758d9ef38957549d589",
+    "decode-int8": "1d3dba72c687adc409ec58852e67c2c02d503f467aeb1085965a2023d12390b5",
+}
+
+
+def _lowered_text(kind, kvq, cfg=CFG, engine=ENGINE):
     i32, sds = jnp.int32, jax.ShapeDtypeStruct
-    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))
+    S, W, C = engine["slots"], engine["seq"] // engine["block_size"], engine["prefill_chunk"]
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     pool = jax.eval_shape(lambda: decode.init_block_pool(
-        CFG, ENGINE["kv_blocks"], ENGINE["block_size"], kv_dtype=kvq))
+        cfg, engine["kv_blocks"], engine["block_size"], kv_dtype=kvq))
+    slot = {}
+    if cfg.layer_types is not None:  # the recurrent rows ride the pool; a chunk names its slot
+        pool = {**pool, **jax.eval_shape(lambda: hybrid.init_rec_state(cfg, S))}
+        slot = {"slot": sds((), i32)} if kind == "prefill" else {}
     lanes = (sds((S,), i32), sds((S,), i32))
     fn, args = {
         "prefill": (decode.paged_prefill_chunk,
@@ -68,7 +101,7 @@ def _lowered_text(kind, kvq):
         "verify": (decode.paged_verify_step,
                    (sds((S, W), i32), sds((S, T), i32), *lanes, sds((S,), jnp.bool_))),
     }[kind]
-    return jax.jit(partial(fn, cfg=CFG)).lower(params, pool, *args).as_text()
+    return jax.jit(partial(fn, cfg=cfg)).lower(params, pool, *args, **slot).as_text()
 
 
 @pytest.mark.parametrize("kvq", [None, "int8"], ids=["kv", "int8"])
@@ -76,6 +109,13 @@ def _lowered_text(kind, kvq):
 def test_dense_program_lowers_to_the_text_it_had(kind, kvq):
     digest = hashlib.sha256(_lowered_text(kind, kvq).encode()).hexdigest()
     assert digest == AT_PR_28[f"{kind}-{kvq or 'kv'}"]
+
+
+@pytest.mark.parametrize("kvq", [None, "int8"], ids=["kv", "int8"])
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_hybrid_program_lowers_to_the_text_it_had(kind, kvq):
+    text = _lowered_text(kind, kvq, HYBRID_CFG, HYBRID_ENGINE)
+    assert hashlib.sha256(text.encode()).hexdigest() == HYBRID_AT_PR_30[f"{kind}-{kvq or 'kv'}"]
 
 
 def _engine():

@@ -264,6 +264,58 @@ def test_bit_identical_to_the_per_layer_slices(params, kind, kvq):
         assert not np.array_equal(got[:, 0], np.asarray(pool[name])[:, 0]), name
 
 
+# -- the table walk into a pool whose rows hold more heads than the model ----
+
+WIDE = 8  # heads a pool row holds (``TransformerConfig.pool_kv_heads`` of a hybrid model)
+
+
+def _widened(pool, key):
+    """``pool`` with every row padded to ``WIDE`` heads of GARBAGE: a walk that
+    read the extra heads, or left them unwritten, shows."""
+    out = {}
+    for sub, (name, leaf) in zip(jax.random.split(key, len(pool)), sorted(pool.items())):
+        shape = leaf.shape[:3] + (WIDE - leaf.shape[3],) + leaf.shape[4:]
+        extra = jax.random.randint(sub, shape, 1, 100).astype(leaf.dtype)
+        out[name] = jnp.concatenate([leaf, extra], axis=3)
+    return out
+
+
+@CASES
+@KINDS
+def test_table_walk_pads_rows_in_and_slices_them_out(kind, kvq):
+    """``_kv_through_table`` on a pool of ``WIDE``-head rows gathers bit for bit
+    what it gathers from the model's own heads, writes zeros into the extra
+    heads of the rows it appends and touches no other row."""
+    Hkv, d, li = CFG.kv_heads, CFG.head_dim, 1
+    tables = _tables()
+    lead, table = {"prefill": ((1, C), tables[0]), "decode": ((S, 1), tables),
+                   "verify": ((S, T), tables)}[kind]
+    n_rows = lead[0] * lead[1]
+    at = (n_rows,) if kind != "verify" else lead
+    k, v = jax.random.normal(jax.random.PRNGKey(5), (2,) + lead + (Hkv, d))
+    # distinct addresses, trash block 0 among them
+    where = np.random.default_rng(13).permutation(NB * BS)[:n_rows]
+    write_blk = jnp.asarray(where // BS, jnp.int32).reshape(at)
+    write_off = jnp.asarray(where % BS, jnp.int32).reshape(at)
+
+    narrow = _filled_pool(kvq)
+    wide = _widened(narrow, jax.random.PRNGKey(17))
+    walk = jax.jit(decode._kv_through_table, static_argnames="dtype")
+    want_pool, want_k, want_v = walk(narrow, li, k, v, table, write_blk, write_off, dtype=CFG.dtype)
+    got_pool, got_k, got_v = walk(wide, li, k, v, table, write_blk, write_off, dtype=CFG.dtype)
+
+    assert got_k.shape == got_v.shape == (lead[0], W * BS, Hkv, d)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    written = np.zeros((CFG.n_layers, NB, BS), bool)
+    written[li, where // BS, where % BS] = True
+    for name in narrow:
+        got, before = np.asarray(got_pool[name]), np.asarray(wide[name])
+        np.testing.assert_array_equal(got[:, :, :, :Hkv], np.asarray(want_pool[name]), err_msg=name)
+        assert not got[written][:, Hkv:].any(), f"{name}: an appended row's extra heads are not zero"
+        np.testing.assert_array_equal(got[~written], before[~written], err_msg=name)
+
+
 # -- the compiled step holds no second pool ----------------------------------
 
 
