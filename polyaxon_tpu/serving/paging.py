@@ -355,9 +355,11 @@ class PrefixCache:
         self._entries: "OrderedDict[int, Tuple[int, Tuple[int, ...]]]" = (
             OrderedDict()
         )
-        # chain key -> the FULL prefix token chain ending at this block
-        # (ancestors included) — the persistable identity of an entry.
-        self._chains: Dict[int, Tuple[int, ...]] = {}
+        # chain key -> (the offered token tuple, n): the FULL prefix token
+        # chain ending at this block (ancestors included) is its first n
+        # tokens — the persistable identity of an entry.  Every entry one
+        # offer() publishes shares the one tuple; hottest_chains() cuts it.
+        self._chains: Dict[int, Tuple[Tuple[int, ...], int]] = {}
         # Demoted entries: chain key <-> host tier handle.
         self._demoted: Dict[int, int] = {}
         self._handle_key: Dict[int, int] = {}
@@ -546,15 +548,16 @@ class PrefixCache:
         ``snapshots`` maps a position (a multiple of the block size) to the
         pending place that holds the recurrent state after that many
         tokens: each is attached to the entry of the block it ends on."""
-        chain: List[int] = []
+        tokens: Optional[Tuple[int, ...]] = None
         pending = dict(snapshots or {})
         for i, ((key, toks), block) in enumerate(zip(self._keys_for(prompt), blocks)):
-            chain.extend(toks)
             entry = self._entries.get(key)
             if entry is None:
+                if tokens is None:
+                    tokens = tuple(prompt)
                 self._alloc.incref(block)
                 self._entries[key] = (block, toks)
-                self._chains[key] = tuple(chain)
+                self._chains[key] = (tokens, (i + 1) * self.block_size)
                 self.mutations += 1
             self._entries.move_to_end(key)
             place = pending.pop((i + 1) * self.block_size, None)
@@ -579,7 +582,8 @@ class PrefixCache:
             self._alloc.decref(block)
             return False
         self._entries[key] = (block, toks)
-        self._chains[key] = tuple(int(t) for t in chain_tokens)
+        tokens = tuple(int(t) for t in chain_tokens)
+        self._chains[key] = (tokens, len(tokens))
         self._entries.move_to_end(key)
         self.mutations += 1
         return True
@@ -603,7 +607,7 @@ class PrefixCache:
             chain = self._chains.get(key)
             if chain is None:
                 continue
-            for k2, _ in self._keys_for(chain):
+            for k2, _ in self._keys_for(chain[0][: chain[1]]):
                 if k2 in seen or len(out) >= limit:
                     continue
                 entry = self._entries.get(k2)
@@ -611,7 +615,8 @@ class PrefixCache:
                 if entry is None or chain2 is None:
                     continue
                 seen.add(k2)
-                out.append((chain2, entry[0], self._demoted.get(k2)))
+                tokens, n = chain2
+                out.append((tokens[:n], entry[0], self._demoted.get(k2)))
         return out
 
     def evict(self, need: int = 1, demote: Optional[bool] = None) -> int:

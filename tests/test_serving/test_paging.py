@@ -10,6 +10,7 @@ copy-on-write keeps shared prefixes immutable, and ref-counts
 round-trip under admit/retire churn.
 """
 
+import functools
 import time
 
 import jax
@@ -18,7 +19,13 @@ import numpy as np
 import pytest
 
 from polyaxon_tpu.models import TransformerConfig, decode, init_params
-from polyaxon_tpu.serving import BlockAllocator, PrefixCache, ServingEngine
+from polyaxon_tpu.serving import (
+    BlockAllocator,
+    HostKVTier,
+    PrefixCache,
+    ServingEngine,
+)
+from polyaxon_tpu.serving.paging import StateSnapshots
 
 CFG = TransformerConfig(
     vocab_size=64,
@@ -161,6 +168,181 @@ class TestPrefixCache:
         m1 = pc.mutations
         pc.match(p2)  # a pure hit changes nothing persistable
         assert pc.mutations == m1
+
+
+def _rule_victims(pc, alloc, need):
+    """The plain reference, one block at a time: each freed block is that
+    of the FIRST entry in the map's order whose block is on the device and
+    held by the cache alone.  Whatever walk ``evict`` makes (ROADMAP S2:
+    in place, a chunk's worth a call) has to free these, in this order."""
+    gone, blocks = set(), []
+    for _ in range(need):
+        for key, (block, _) in list(pc._entries.items()):
+            if key not in gone and block >= 0 and alloc.refcount(block) == 1:
+                gone.add(key)
+                blocks.append(block)
+                break
+        else:
+            break
+    return blocks
+
+
+@functools.lru_cache(maxsize=None)
+def _churn(variant, batched):
+    """A seeded run of offer / match / decref / evict over one cache; an
+    eviction of ``k`` blocks is ONE ``evict(k)`` (``batched``) or ``k``
+    times ``evict(1)``.  Every eviction is held to :func:`_rule_victims`;
+    returns what the run left after each operation."""
+    bs = 4
+    rng = np.random.default_rng(33)
+    alloc = BlockAllocator(161)
+    snaps = StateSnapshots(12) if variant == "snapshots" else None
+    pc = PrefixCache(alloc, bs, snaps)
+    tier = None
+    log = []
+    dropped = [0]  # snapshots that went because their entry did
+    if snaps is not None:
+        drop = snaps.drop
+
+        def counting_drop(key):
+            dropped[0] += key in snaps._by_key
+            drop(key)
+
+        snaps.drop = counting_drop
+
+    def evict(k):
+        expected = _rule_victims(pc, alloc, k)
+        free0 = len(alloc._free)
+        if batched:
+            got = pc.evict(k)
+        else:
+            got = 0
+            while got < k and pc.evict(1):
+                got += 1
+        assert list(alloc._free)[free0:] == expected  # victims, in order
+        assert got == len(expected)
+        return got
+
+    if variant == "tier":
+        # Three payloads: nearly every spill drops an older one, whose
+        # on_drop deletes that entry while the eviction is still running.
+        tier = HostKVTier(capacity_blocks=3)
+
+        def alloc_retry():  # the engine's _alloc_block
+            block = alloc.alloc()
+            if block is None and pc.evict(1):
+                block = alloc.alloc()
+            return block
+
+        pc.attach_tier(
+            tier,
+            spill=lambda block: tier.put({"block": block}),
+            restore=lambda handle, block: tier.pop(handle),
+            alloc=alloc_retry,
+        )
+    docs = [list(rng.integers(0, 50, int(n) * bs)) for n in rng.integers(6, 40, 10)]
+    live = []
+    for _ in range(400):
+        op = rng.choice(["admit", "admit", "retire", "evict"])
+        if op == "admit":
+            doc = docs[int(rng.integers(len(docs)))]
+            cut = int(rng.integers(1, len(doc) // bs + 1)) * bs
+            prompt = doc[:cut] + list(rng.integers(50, 64, int(rng.integers(0, 12))))
+            if snaps is not None:
+                blocks, _ = pc.match_with_state(prompt)
+            else:
+                blocks = pc.match(prompt)
+            want = len(prompt) // bs - len(blocks)
+            if alloc.n_free < want:
+                evict(want - alloc.n_free)
+            if alloc.n_free < want:
+                for block in blocks:
+                    alloc.decref(block)
+            else:
+                blocks = blocks + [alloc.alloc() for _ in range(want)]
+                pending = None
+                if snaps is not None and len(blocks) >= 2:
+                    pending = {len(blocks) // 2 * bs: snaps.alloc()}
+                    pending = {p: i for p, i in pending.items() if i is not None}
+                pc.offer(prompt, blocks, pending)
+                live.append(blocks)
+        elif op == "retire" and live:
+            for block in live.pop(int(rng.integers(len(live)))):
+                alloc.decref(block)
+        elif op == "evict":
+            evict(int(rng.integers(1, 65)))
+        if snaps is not None:  # a dropped entry's snapshot went with it
+            assert set(snaps._by_key) <= set(pc._entries)
+        log.append((pc.hits, pc.lookups, pc.evictions, pc.demotions, len(pc),
+                    pc.n_demoted, alloc.n_free, snaps.used if snaps else 0,
+                    tier.dropped_total if tier else 0))
+    assert pc.evictions + pc.demotions > 300, "the run hardly evicted"
+    if tier is not None:
+        assert tier.dropped_total > 50, "no capacity drop in mid-walk"
+    if snaps is not None:
+        assert dropped[0] > 10, "no snapshot went with its entry"
+    return log
+
+
+class TestEvictionOrderAndChains:
+    """What ``evict`` frees, in what order, whatever ``need`` is asked at a
+    time; and what an entry remembers of its prompt."""
+
+    @pytest.mark.parametrize("batched", [False, True], ids=["k=1-repeated", "k-up-to-64"])
+    @pytest.mark.parametrize("variant", ["plain", "snapshots", "tier"])
+    def test_victims_are_the_one_by_one_rules(self, variant, batched):
+        log = _churn(variant, batched)
+        assert log == _churn(variant, not batched)
+
+    def test_a_victim_lost_while_the_call_runs_is_skipped_and_made_up_for(self):
+        """Every entry is looked at when the call reaches it, not when it
+        started: here the first spill takes a reference on the next
+        victim's block, so the call leaves it and frees the one after."""
+        alloc = BlockAllocator(8)
+        pc = PrefixCache(alloc, 2)
+        blocks = [alloc.alloc() for _ in range(4)]
+        for i, block in enumerate(blocks):
+            pc.offer([i, i], [block])
+            alloc.decref(block)
+        tier = HostKVTier()
+
+        def spill(block):
+            if block == blocks[0]:
+                alloc.incref(blocks[1])
+            return tier.put({"block": block})
+
+        pc.attach_tier(tier, spill=spill, restore=lambda h, b: tier.pop(h),
+                       alloc=alloc.alloc)
+        free0 = alloc.n_free
+        assert pc.evict(2) == 2
+        assert list(alloc._free)[free0:] == [blocks[0], blocks[2]]
+        assert pc.demotions == 2 and alloc.refcount(blocks[1]) == 2
+
+    @pytest.mark.parametrize("then", ["offer", "install", "evict-mid-chain"])
+    def test_a_prompts_entries_share_one_token_tuple(self, then):
+        """``hottest_chains`` returns what the parent's did: every entry's
+        FULL prefix, ancestors first, cut from the one stored tuple."""
+        bs, n = 4, 1000
+        alloc = BlockAllocator(n + 8)
+        pc = PrefixCache(alloc, bs)
+        rng = np.random.default_rng(7)
+        prompt = [int(t) for t in rng.integers(0, 64, n * bs + 3)]
+        blocks = [alloc.alloc() for _ in range(n)]
+        pc.offer(prompt, blocks)
+        assert len(pc._chains) == n
+        assert len({id(tokens) for tokens, _ in pc._chains.values()}) == 1
+        want = [(tuple(prompt[: (i + 1) * bs]), blocks[i], None) for i in range(n)]
+        if then == "install":
+            longer = prompt[: n * bs] + [1, 2, 3, 4]
+            fresh = alloc.alloc()
+            assert pc.install(longer, fresh)
+            want.append((tuple(longer), fresh, None))
+        elif then == "evict-mid-chain":
+            alloc.decref(blocks[500])  # the only block the cache holds alone
+            assert pc.evict(8) == 1
+            del want[500]
+        assert pc.hottest_chains(2 * n) == want
+        assert pc.hottest_chains(10) == want[:10]
 
 
 class TestPagedParity:
