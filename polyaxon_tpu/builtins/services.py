@@ -484,6 +484,23 @@ def lm_server(ctx: Context) -> None:
     layer, and a hit is cut back to the newest one (docs/serving.md).
     It refuses ``spec_decode``, ``kv_offload``, ``kv_persist`` and a
     multi-chip mesh with a ``RecurrentStateError`` naming the option.
+
+    ``rope_theta`` is the rotary base of any model (default 10000).
+
+    A latent-attention model (``models/latent_moe.py``) is declared by
+    ``kv_lora_rank`` with ``q_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim`` and ``v_head_dim`` under the published configs'
+    names, and by ``layer_types`` naming each layer's MLP: ``dense_mlp``
+    (``d_ff`` wide) or ``expert_mlp``.  The expert layers take
+    ``n_routed_experts`` (the router's width), ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``n_shared_experts``,
+    ``routed_scaling_factor``, and the chip's share of a layer's experts:
+    ``experts_held`` from ``expert_offset`` on (default: all).  The pool
+    then holds one latent row a token a layer (``kv_row_bytes`` in
+    ``/v1/stats``, beside ``moe_rows_routed`` / ``moe_rows_held`` /
+    ``moe_rows_busiest`` / ``moe_experts_hit`` / ``moe_call_shapes``).  It
+    refuses ``spec_decode`` and a multi-chip mesh with a ``LatentStackError``
+    naming the option.
     """
     import jax
 
@@ -504,6 +521,8 @@ def lm_server(ctx: Context) -> None:
     # layers are gated-delta-rule ("linear_attention") and which full
     # attention, the linear layers' sizes under the published configs'
     # names, and ``rope: 0`` for full layers without rotary embedding.
+    # (With ``kv_lora_rank``, below, the pattern names the latent stack's
+    # MLPs instead.)
     layer_types = ctx.get_param("layer_types")
     if layer_types is not None:
         if isinstance(layer_types, str):
@@ -521,6 +540,23 @@ def lm_server(ctx: Context) -> None:
         )
         if not _truthy(ctx.get_param("rope", True)):
             cfg_fields["rope_theta"] = None
+    if ctx.get_param("rope_theta") is not None:
+        cfg_fields["rope_theta"] = float(ctx.get_param("rope_theta"))
+    # Latent attention and routed experts (models/latent_moe.py), sized under
+    # the published configs' names; ``layer_types`` above names each layer's
+    # MLP, ``experts_held`` / ``expert_offset`` the chip's share of a layer.
+    for f in (
+        "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim", "n_routed_experts", "num_experts_per_tok",
+        "moe_intermediate_size", "n_shared_experts", "experts_held",
+        "expert_offset",
+    ):
+        if ctx.get_param(f) is not None:
+            cfg_fields[f] = int(ctx.get_param(f))
+    if ctx.get_param("routed_scaling_factor") is not None:
+        cfg_fields["routed_scaling_factor"] = float(
+            ctx.get_param("routed_scaling_factor")
+        )
     cfg = TransformerConfig(max_seq=seq, **cfg_fields)
     params = init_params(jax.random.PRNGKey(ctx.seed or 0), cfg)
 
@@ -542,7 +578,11 @@ def lm_server(ctx: Context) -> None:
     template = None
     param_shardings = None
     if mesh is not None and mesh.size > 1:
-        if cfg.layer_types is not None:
+        if cfg.stack == "latent":
+            from polyaxon_tpu.models.latent_moe import LatentStackError
+
+            raise LatentStackError("mesh")
+        if cfg.stack == "hybrid":
             from polyaxon_tpu.models.hybrid import RecurrentStateError
 
             raise RecurrentStateError("mesh")

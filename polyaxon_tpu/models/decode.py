@@ -34,6 +34,7 @@ from polyaxon_tpu.models.transformer import (
     _rmsnorm,
     _rope,
     forward,
+    stack_module,
 )
 
 
@@ -93,6 +94,10 @@ def quantize_weights(params: Dict[str, Any]) -> Dict[str, Any]:
         from polyaxon_tpu.models import hybrid
 
         return hybrid.quantize_weights(params, q)
+    if "wkv_a" in blk:  # the latent stack: its own tree too
+        from polyaxon_tpu.models import latent_moe
+
+        return latent_moe.quantize_weights(params, q)
     out = {
         name: q(blk[name], axes)
         for name, axes in QUANTIZED_BLOCK_WEIGHTS.items()
@@ -126,6 +131,10 @@ def serving_params(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, 
         from polyaxon_tpu.models import hybrid
 
         return hybrid.serving_params(params, cast)
+    if "wkv_a" in blk:  # the latent stack: its own leaf list
+        from polyaxon_tpu.models import latent_moe
+
+        return latent_moe.serving_params(params, cast)
     return {
         **params,
         "embed": cast(params["embed"]),
@@ -347,6 +356,12 @@ def init_block_pool(
 ) -> Dict[str, jax.Array]:
     """Zeroed paged KV pool: k/v [L, num_blocks, block_size, Hkv, d].
 
+    A latent-attention model (``cfg.stack == "latent"``) keeps ONE row a token
+    a layer instead, ``[c | k_rope]``: leaf ``c [L, num_blocks, block_size,
+    kv_lora_rank + qk_rope_head_dim]``, no head axis (the block's tokens are
+    the second-minor dimension, a whole bfloat16 tile of 16).  Its int8 form
+    is ``c_q`` / ``c_scale``, one scale a row.
+
     With ``kv_dtype="int8"`` the pool instead stores symmetric-quantized
     rows plus their scales — ``k_q``/``v_q`` int8 [L, NB, bs, Hkv, d] and
     ``k_scale``/``v_scale`` f32 [L, NB, bs, Hkv] (one scale per appended
@@ -357,31 +372,45 @@ def init_block_pool(
     memory budget.
     """
     c = cfg
-    shape = (c.n_kv_layers, num_blocks, block_size, c.pool_kv_heads, c.head_dim)
-    if kv_dtype is None:
-        return {
-            "k": jnp.zeros(shape, c.dtype),
-            "v": jnp.zeros(shape, c.dtype),
-        }
-    if str(kv_dtype) != "int8":
+    if kv_dtype is not None and str(kv_dtype) != "int8":
         raise ValueError(f"unsupported kv_dtype {kv_dtype!r} (int8 or None)")
-    return {
-        "k_q": jnp.zeros(shape, jnp.int8),
-        "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-        "v_q": jnp.zeros(shape, jnp.int8),
-        "v_scale": jnp.zeros(shape[:-1], jnp.float32),
-    }
+    if c.stack == "latent":
+        from polyaxon_tpu.models.latent_moe import pool_row_width
+
+        shape = (c.n_layers, num_blocks, block_size, pool_row_width(c))
+        names = ("c",)
+    else:
+        shape = (c.n_kv_layers, num_blocks, block_size, c.pool_kv_heads, c.head_dim)
+        names = ("k", "v")
+    if kv_dtype is None:
+        return {n: jnp.zeros(shape, c.dtype) for n in names}
+    pool = {}
+    for n in names:
+        pool[n + "_q"] = jnp.zeros(shape, jnp.int8)
+        pool[n + "_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+    return pool
+
+
+def _row_leaf(pool: Dict[str, jax.Array]) -> jax.Array:
+    """The leaf that holds a token's rows: K of a KV pool, ``c`` of a latent
+    one, in either layout."""
+    for name in ("k", "k_q", "c", "c_q"):
+        if name in pool:
+            return pool[name]
+    raise KeyError(f"no row leaf among {sorted(pool)}")
 
 
 def is_quantized_pool(pool: Dict[str, jax.Array]) -> bool:
-    """True for the (k_q, k_scale, v_q, v_scale) int8 pool layout."""
-    return "k_q" in pool
+    """True for the int8 pool layout (``k_q`` ... ``v_scale``, or ``c_q`` /
+    ``c_scale``)."""
+    return "k_q" in pool or "c_q" in pool
 
 
 def pool_geometry(pool: Dict[str, jax.Array]) -> Tuple[int, int, int]:
-    """(block_size, kv_heads, head_dim) for either pool layout."""
-    leaf = pool["k_q"] if is_quantized_pool(pool) else pool["k"]
-    return leaf.shape[2], leaf.shape[3], leaf.shape[4]
+    """(block_size, kv_heads, head_dim) for either pool layout; a latent
+    pool's row counts as one head of the row's width."""
+    leaf = _row_leaf(pool)
+    return leaf.shape[2], (leaf.shape[3] if leaf.ndim == 5 else 1), leaf.shape[-1]
 
 
 def kv_block_bytes(
@@ -393,12 +422,18 @@ def kv_block_bytes(
     budget B the pool holds ``B // kv_block_bytes(...)`` blocks.
     """
     c = cfg
-    rows = c.n_kv_layers * block_size * c.pool_kv_heads  # head-rows per block
-    if kv_dtype is None:
-        return 2 * rows * c.head_dim * jnp.dtype(c.dtype).itemsize
-    if str(kv_dtype) != "int8":
+    if kv_dtype is not None and str(kv_dtype) != "int8":
         raise ValueError(f"unsupported kv_dtype {kv_dtype!r} (int8 or None)")
-    return 2 * rows * (c.head_dim + 4)  # int8 row + one f32 scale
+    if c.stack == "latent":  # one row a token a layer, no k+v pair
+        from polyaxon_tpu.models.latent_moe import pool_row_width
+
+        rows, width = c.n_layers * block_size, pool_row_width(c)
+    else:
+        rows = 2 * c.n_kv_layers * block_size * c.pool_kv_heads  # head-rows, k+v
+        width = c.head_dim
+    if kv_dtype is None:
+        return rows * width * jnp.dtype(c.dtype).itemsize
+    return rows * (width + 4)  # int8 row + one f32 scale
 
 
 def _kv_quant(rows: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -503,6 +538,23 @@ def _kv_through_table(pool, layer_idx, k, v, table, write_blk, write_off, dtype)
     pool = _pool_append(pool, "k", layer_idx, rows(k), write_blk, write_off)
     pool = _pool_append(pool, "v", layer_idx, rows(v), write_blk, write_off)
     return pool, gathered(pool, "k"), gathered(pool, "v")
+
+
+def _latent_through_table(pool, layer_idx, row, table, write_blk, write_off, dtype):
+    """:func:`_kv_through_table` for a latent pool: one leaf, ``c``, whose row
+    is all a token keeps.  ``row [B, T, width]``; addresses and ``table`` as
+    there, for a chunk and a decode step (no verify step reads a latent
+    pool).  Returns ``(pool, rows [B, W * bs, width])``.  The gather is over
+    the whole table width here too (ROADMAP S1)."""
+    bs, _, width = pool_geometry(pool)
+    lanes = 1 if table.ndim == 1 else table.shape[0]
+    new = row[0] if table.ndim == 1 else row[:, 0]
+    used = row.shape[-1]
+    if width != used:
+        new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, width - used),))
+    pool = _pool_append(pool, "c", layer_idx, new, write_blk, write_off)
+    got = _pool_gather(pool, "c", layer_idx, table, dtype)
+    return pool, got.reshape(lanes, table.shape[-1] * bs, width)[..., :used]
 
 
 def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
@@ -655,9 +707,17 @@ def paged_prefill_chunk(
     A model with a layer pattern (``cfg.layer_types``) goes through
     ``models/hybrid.py``'s form of this program instead: it also needs the
     ``slot`` whose recurrent rows (leaves of the same pool) the chunk
-    advances.
+    advances.  A latent-attention model (``cfg.kv_lora_rank``) goes through
+    ``models/latent_moe.py``'s, which returns a third value beside these two:
+    what its expert layers routed in this call (``latent_moe.COUNT_NAMES``).
     """
-    if cfg.layer_types is not None:
+    if cfg.stack == "latent":
+        from polyaxon_tpu.models import latent_moe
+
+        return latent_moe.paged_prefill_chunk(
+            params, pool, table, tokens, start, length, cfg
+        )
+    if cfg.stack == "hybrid":
         from polyaxon_tpu.models import hybrid
 
         return hybrid.paged_prefill_chunk(
@@ -715,10 +775,8 @@ def paged_decode_step(
     ``(logits [S, vocab] f32, new_pool)``; the layer loop is
     :func:`_run_uniform_stack`.
     """
-    if cfg.layer_types is not None:
-        from polyaxon_tpu.models import hybrid
-
-        return hybrid.paged_decode_step(
+    if cfg.stack != "uniform":
+        return stack_module(cfg).paged_decode_step(
             params, pool, tables, tokens, pos, active, cfg, qweights=qweights
         )
     c = cfg
@@ -803,7 +861,11 @@ def paged_verify_step(
     qweights and int8 KV pools compose the same way), so greedy outputs
     stay token-identical to the non-speculative path.
     """
-    if cfg.layer_types is not None:
+    if cfg.stack == "latent":
+        from polyaxon_tpu.models.latent_moe import LatentStackError
+
+        raise LatentStackError("spec_decode")
+    if cfg.stack == "hybrid":
         from polyaxon_tpu.models.hybrid import RecurrentStateError
 
         raise RecurrentStateError("spec_decode")

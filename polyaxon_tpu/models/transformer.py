@@ -95,9 +95,14 @@ class TransformerConfig:
                 f"({self.kv_heads})"
             )
         if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.kv_lora_rank:
+            from polyaxon_tpu.models.latent_moe import check_config
+
+            check_config(self)
+        elif self.layer_types is not None:
             from polyaxon_tpu.models.hybrid import check_config
 
-            object.__setattr__(self, "layer_types", tuple(self.layer_types))
             check_config(self)
         elif self.rope_theta is None:
             raise ValueError(
@@ -123,11 +128,13 @@ class TransformerConfig:
     #: [B,chunk] slice at a time under jax.checkpoint, so the backward
     #: recomputes each chunk's logits instead of keeping them resident.
     ce_chunk: int = 0
-    #: A layer pattern makes this the HYBRID stack of ``models/hybrid.py``
-    #: (served through the paged programs only): one entry per layer,
+    #: A layer pattern, one entry per layer, served through the paged programs
+    #: only (:attr:`stack`).  The HYBRID stack of ``models/hybrid.py``:
     #: ``"linear_attention"`` (gated delta rule, sized by the ``linear_*``
     #: fields below, named as the published configs name them) or
-    #: ``"full_attention"``, a whole number of periods.  ``None`` = every
+    #: ``"full_attention"``, a whole number of periods.  The LATENT stack of
+    #: ``models/latent_moe.py`` (``kv_lora_rank`` > 0): ``"dense_mlp"`` or
+    #: ``"expert_mlp"``, every mixer latent attention.  ``None`` = every
     #: layer the dense block of this module.
     layer_types: Optional[Tuple[str, ...]] = None
     linear_num_key_heads: int = 0
@@ -137,6 +144,41 @@ class TransformerConfig:
     linear_conv_kernel_dim: int = 4
     #: beta in (0, 2) instead of (0, 1): the state transition may reflect.
     linear_allow_neg_eigval: bool = False
+    #: Latent attention (``models/latent_moe.py``), sized as the published
+    #: configs of the DeepSeek-V3 family name it.  ``kv_lora_rank`` > 0 makes
+    #: every mixer latent: queries through a ``q_lora_rank`` bottleneck, per
+    #: head ``qk_nope_head_dim`` unrotated + ``qk_rope_head_dim`` rotated
+    #: columns; keys and values up-projected from ONE row a token of
+    #: ``kv_lora_rank`` + ``qk_rope_head_dim`` values, which is what the paged
+    #: pool holds (``n_kv_heads`` and ``head_dim`` size nothing here).
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: The ``"expert_mlp"`` layers of that stack: a sigmoid router over
+    #: ``n_routed_experts`` with a selection bias, ``num_experts_per_tok``
+    #: chosen, their weights normalised and scaled by
+    #: ``routed_scaling_factor``, experts and ``n_shared_experts`` always-on
+    #: experts gated SiLU MLPs of width ``moe_intermediate_size``.  This chip
+    #: HOLDS experts ``[expert_offset, expert_offset + experts_held)`` of a
+    #: layer (0 = all): it routes over all of them and computes its own.
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def stack(self) -> str:
+        """Whose programs serve this configuration: ``"latent"``
+        (``models/latent_moe.py``), ``"hybrid"`` (``models/hybrid.py``) or
+        ``"uniform"`` (``models/decode.py``'s own)."""
+        if self.kv_lora_rank:
+            return "latent"
+        return "uniform" if self.layer_types is None else "hybrid"
 
     def scaled(self, **overrides) -> "TransformerConfig":
         return replace(self, **overrides)
@@ -144,7 +186,7 @@ class TransformerConfig:
     @property
     def n_kv_layers(self) -> int:
         """Layers that keep KV in the paged pool: the full-attention ones."""
-        if self.layer_types is None:
+        if self.stack != "hybrid":
             return self.n_layers
         return sum(1 for t in self.layer_types if t == "full_attention")
 
@@ -156,7 +198,7 @@ class TransformerConfig:
         every program to get there (chipless v5e compile: 6 pool-sized copies
         in a decode step, 8 in a chunk; none at 32).  The dense model's pool
         is as it was."""
-        if self.layer_types is None:
+        if self.stack != "hybrid":
             return self.kv_heads
         return -(-self.kv_heads // 8) * 8
 
@@ -164,10 +206,8 @@ class TransformerConfig:
     def n_params(self) -> int:
         """Parameter count (for MFU math)."""
         c = self
-        if c.layer_types is not None:
-            from polyaxon_tpu.models.hybrid import n_params
-
-            return n_params(c)
+        if c.stack != "uniform":
+            return stack_module(c).n_params(c)
         attn = c.d_model * c.head_dim * (2 * c.n_heads + 2 * c.kv_heads)
         if c.n_experts:
             mlp = c.d_model * c.n_experts + c.n_experts * c.d_model * c.d_ff * 3
@@ -175,6 +215,14 @@ class TransformerConfig:
             mlp = c.d_model * c.d_ff * 3
         per_layer = attn + mlp + 2 * c.d_model
         return c.vocab_size * c.d_model * 2 + c.n_layers * per_layer + c.d_model
+
+
+def stack_module(cfg: TransformerConfig):
+    """The model file of a configuration whose ``stack`` is not ``"uniform"``:
+    its ``init_params``, ``n_params`` and paged programs."""
+    from polyaxon_tpu.models import hybrid, latent_moe
+
+    return latent_moe if cfg.stack == "latent" else hybrid
 
 
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -210,10 +258,8 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     c = cfg
-    if c.layer_types is not None:
-        from polyaxon_tpu.models import hybrid
-
-        return hybrid.init_params(key, c)
+    if c.stack != "uniform":
+        return stack_module(c).init_params(key, c)
     k = iter(jax.random.split(key, 16))
     dt = c.param_dtype
 

@@ -54,7 +54,7 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -454,6 +454,10 @@ class ServingEngine:
         hit is cut back to the newest snapshot on its chain.  Such a model
         refuses ``spec_decode``, ``kv_offload``, ``kv_persist_dir`` and a
         ``mesh`` with :class:`~polyaxon_tpu.models.hybrid.RecurrentStateError`.
+        A latent-attention model (``cfg.kv_lora_rank``, ``models/latent_moe.py``)
+        keeps one latent row a token a layer in the pool and takes no option of
+        its own; it refuses ``spec_decode`` and a ``mesh`` with
+        :class:`~polyaxon_tpu.models.latent_moe.LatentStackError`.
     stats : a stats backend receiving latency histograms
         (``serving.queue_wait_s`` / ``serving.ttft_s`` /
         ``serving.decode_step_s`` / ``serving.batch_occupancy``) and
@@ -555,7 +559,7 @@ class ServingEngine:
         self.block_allocator = BlockAllocator(num_blocks)
         # Recurrent state (a model with a layer pattern): per-slot rows in
         # the pool, and the snapshot store that prefix reuse resumes from.
-        self._recurrent = cfg.layer_types is not None
+        self._recurrent = cfg.stack == "hybrid"
         self._snaps: Optional[StateSnapshots] = None
         self._snap_store: Optional[Any] = None
         self._snap_every = 0
@@ -595,6 +599,30 @@ class ServingEngine:
         self.kv_pool_bytes = int(
             sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._pool))
         )
+        #: Pool bytes ONE token costs over all layers: 2 x Hkv x d a layer for
+        #: K and V, one latent row a layer for a latent-attention model.
+        self.kv_row_bytes = (
+            decode.kv_block_bytes(cfg, self.block_size, self.kv_quantize)
+            // self.block_size
+        )
+        # Routed experts: each program returns what it routed in the call
+        # beside its result (``latent_moe.COUNT_NAMES``).  The scheduler keeps
+        # the counts of the calls it has dispatched and fetches them in the
+        # blocking read it makes anyway (``_host_read``).
+        self._moe = bool(cfg.n_routed_experts)
+        self._moe_pending: List[Tuple[int, Any]] = []  # (rows of the call's shape, counts)
+        self._moe_totals: Dict[str, int] = {}
+        #: By the rows of a call's shape (tokens of the program's shape x
+        #: choices): ``[the expert product's calls (program calls x expert
+        #: layers), rows held, experts that had a row]``.
+        self._moe_shapes: Dict[int, List[int]] = {}
+        if self._moe:
+            from polyaxon_tpu.models import latent_moe
+
+            self._moe_totals = dict.fromkeys(latent_moe.COUNT_NAMES, 0)
+            self._moe_layers = sum(
+                n for kind, n in latent_moe.runs(cfg) if kind == latent_moe.EXPERTS
+            )
         self._state_row_bytes = 0
         if self._recurrent:
             from polyaxon_tpu.models import hybrid
@@ -721,6 +749,8 @@ class ServingEngine:
                 self.kv_persist_dir = None
         if self._recurrent:
             self._refuse_what_recurrent_state_cannot_follow()
+        if cfg.stack == "latent":
+            self._refuse_what_the_latent_stack_cannot_follow()
         self._kv_persist_interval_s = knob_float(
             "POLYAXON_TPU_KV_PERSIST_INTERVAL_S"
         )
@@ -822,6 +852,46 @@ class ServingEngine:
             if asked:
                 raise RecurrentStateError(option)
 
+    def _refuse_what_the_latent_stack_cannot_follow(self) -> None:
+        """``models/latent_moe.py:REFUSED``, by name, where the engine is built."""
+        from polyaxon_tpu.models.latent_moe import LatentStackError
+
+        for option, asked in (
+            ("spec_decode", self.spec_decode),
+            ("mesh", self._mesh is not None),
+        ):
+            if asked:
+                raise LatentStackError(option)
+
+    def _note_counts(self, tokens: int, counts: Sequence[Any]) -> None:
+        """Keep what a dispatched program routed (a device array, not read
+        here) until the next blocking read; ``tokens`` is the program's shape."""
+        if self._moe:
+            self._moe_pending.append(
+                (tokens * self.cfg.num_experts_per_tok, counts[0])
+            )
+
+    def _host_read(self, result: Any) -> np.ndarray:
+        """The loop's blocking read of a program's result.  The expert counts
+        of the calls dispatched since the last one come to the host in the
+        same fetch: they are ready when ``result`` is."""
+        if not self._moe_pending:
+            return np.asarray(result)
+        import jax
+
+        pending, self._moe_pending = self._moe_pending, []
+        result, counted = jax.device_get((result, [c for _, c in pending]))
+        with self._stats_lock:
+            for (rows, _), got in zip(pending, counted):
+                got = dict(zip(self._moe_totals, map(int, got)))
+                for name, n in got.items():
+                    self._moe_totals[name] += n
+                shape = self._moe_shapes.setdefault(rows, [0, 0, 0])
+                shape[0] += self._moe_layers
+                shape[1] += got["moe_rows_held"]
+                shape[2] += got["moe_experts_hit"]
+        return result
+
     # -- compiled functions ----------------------------------------------------
 
     def _donate(self) -> tuple:
@@ -841,7 +911,8 @@ class ServingEngine:
         cfg = self.cfg
 
         def step(params, pool, tables, tokens, pos, active, temps, key, qweights):
-            logits, pool = paged_decode_step(
+            # ``counts``: what a model with routed experts routed, else nothing
+            logits, pool, *counts = paged_decode_step(
                 params, pool, tables, tokens, pos, active, cfg,
                 qweights=qweights,
             )
@@ -854,7 +925,7 @@ class ServingEngine:
                 keys, logits / safe[:, None]
             )
             tok = jnp.where(temps > 0, sampled, greedy_tok)
-            return jnp.where(active, tok, 0).astype(jnp.int32), pool
+            return (jnp.where(active, tok, 0).astype(jnp.int32), pool, *counts)
 
         return jax.jit(step, donate_argnums=self._donate())
 
@@ -1139,7 +1210,7 @@ class ServingEngine:
                     tables = np.where(
                         self._tables >= 0, self._tables, 0
                     ).astype(np.int32)
-                    toks, self._pool = self._step_fn(
+                    toks, self._pool, *_ = self._step_fn(
                         self._params,
                         self._pool,
                         jnp.asarray(tables),
@@ -1159,7 +1230,7 @@ class ServingEngine:
                     for c_pad in buckets:
                         if self._stop.is_set():
                             break
-                        logits, self._pool = self._get_chunk(c_pad)(
+                        logits, self._pool, *_ = self._get_chunk(c_pad)(
                             self._params,
                             self._pool,
                             table0,
@@ -1327,6 +1398,8 @@ class ServingEngine:
                 host_restored_blocks_total=paging["host_restored_blocks_total"],
                 prefill_backlog_chunks=paging["prefill_backlog_chunks"],
                 kv_pool_bytes=paging["kv_pool_bytes"],
+                kv_row_bytes=paging["kv_row_bytes"],
+                **{k: v for k, v in paging.items() if k.startswith("moe_rows_")},
                 kv_dtype=paging["kv_dtype"],
                 weight_bytes=paging["weight_bytes"],
                 weight_dtype=paging["weight_dtype"],
@@ -1514,6 +1587,12 @@ class ServingEngine:
             preloaded = self._kv_preloaded_blocks
             persisted = self._kv_persisted_blocks
             state_restores = self._n_state_restores
+            moe = dict(self._moe_totals)
+            if self._moe:
+                moe["moe_call_shapes"] = {
+                    str(rows): dict(zip(("calls", "rows_held", "experts_hit"), v))
+                    for rows, v in sorted(self._moe_shapes.items())
+                }
             now = time.time()
             pc_rate_window = 0.0
             if pc is not None:
@@ -1529,6 +1608,15 @@ class ServingEngine:
             "block_size": self.block_size,
             "kv_dtype": self.kv_dtype,
             "kv_pool_bytes": self.kv_pool_bytes,
+            "kv_row_bytes": self.kv_row_bytes,
+            # Routed experts (absent for a model without): token x choice rows
+            # the programs routed, those that fell to experts held here, the
+            # largest single expert's rows summed over calls and layers
+            # (busiest x experts held / held = the straggler over the mean),
+            # the experts that had a row likewise; and ``moe_call_shapes``, by
+            # the rows of a call's shape, the expert product's calls with the
+            # rows held and the experts hit in them.
+            **moe,
             "weight_dtype": self.weight_dtype,
             "weight_bytes": self.weight_bytes,
             "blocks_total": total,
@@ -1730,6 +1818,8 @@ class ServingEngine:
             "kv_heads": int(c.kv_heads),
             "head_dim": int(c.head_dim),
             "vocab_size": int(c.vocab_size),
+            # a latent pool's row is sized by neither of the two above
+            **({"kv_row_bytes": self.kv_row_bytes} if c.stack == "latent" else {}),
         }
 
     def persist_prefixes(self) -> int:
@@ -2109,7 +2199,7 @@ class ServingEngine:
         chunk = np.zeros(c_pad, np.int32)
         chunk[:n] = req.prompt[job.next_pos : job.next_pos + n]
         table = np.where(self._tables[slot] >= 0, self._tables[slot], 0)
-        logits, self._pool = self._get_chunk(c_pad)(
+        logits, self._pool, *counts = self._get_chunk(c_pad)(
             self._params,
             self._pool,
             jnp.asarray(table.astype(np.int32)),
@@ -2118,6 +2208,7 @@ class ServingEngine:
             jnp.int32(n),
             *((jnp.int32(slot),) if self._recurrent else ()),
         )
+        self._note_counts(c_pad, counts)
         job.next_pos += n
         if self._snaps is not None and job.next_pos % self._snap_every == 0:
             self._snapshot_state(slot, job.next_pos)
@@ -2141,7 +2232,7 @@ class ServingEngine:
             # Every chunk before this one was only dispatched: here
             # the host waits for the device to finish the prompt.
             with clock.phase(PH_DEVICE_WAIT):
-                logits = np.asarray(logits)
+                logits = self._host_read(logits)
             self._finalize_prefill(job, logits)
         with bookkeeping:
             self._record_gauges()
@@ -2427,7 +2518,7 @@ class ServingEngine:
         if drafts:
             emitted = self._verify_once(drafts, tables, sub)
         else:
-            toks, self._pool = self._step_fn(
+            toks, self._pool, *counts = self._step_fn(
                 self._params,
                 self._pool,
                 jnp.asarray(tables),
@@ -2438,8 +2529,9 @@ class ServingEngine:
                 sub,
                 self._qweights,
             )
+            self._note_counts(self.slots, counts)
             with clock.phase(PH_DEVICE_WAIT):
-                toks = np.asarray(toks)  # host sync — the loop's one device read
+                toks = self._host_read(toks)  # host sync — the loop's one device read
             with clock.phase(PH_EMIT):
                 for slot in np.nonzero(self._active)[0]:
                     slot = int(slot)

@@ -29,8 +29,15 @@ HYBRID = dict(vocab_size=256, d_model=64, n_layers=4, n_heads=4, head_dim=16, d_
               linear_num_key_heads=2, linear_num_value_heads=2, linear_key_head_dim=16,
               linear_value_head_dim=32, linear_conv_kernel_dim=4,
               linear_allow_neg_eigval=True)
+LATENT = dict(vocab_size=256, d_model=64, n_layers=3, n_heads=4, head_dim=16, d_ff=128,
+              rope_theta=32e6, layer_types=("dense_mlp", "expert_mlp", "expert_mlp"),
+              kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+              v_head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
+              moe_intermediate_size=32, n_shared_experts=1, routed_scaling_factor=2.5,
+              experts_held=8, expert_offset=4)
+SIZES = {"dense": DENSE, "hybrid": HYBRID, "latent": LATENT}
 BS, W, SLOTS, CHUNK = 8, 16, 3, 32
-KINDS = ["dense", "hybrid"]
+KINDS = ["dense", "hybrid", "latent"]
 
 #: What ``serving_params`` must leave in ``param_dtype``: the norm scales, and
 #: what the hybrid programs read in float32.
@@ -40,6 +47,9 @@ KEPT = {
                "block.full.q_norm", "block.full.k_norm",
                "block.linear.A_log", "block.linear.dt_bias", "block.linear.o_norm",
                "block.linear.conv_q", "block.linear.conv_k", "block.linear.conv_v"},
+    # the latent stack's norms, and the router, which chooses in float32
+    "latent": {"final_norm", "block.attn_norm", "block.mlp_norm", "block.q_norm",
+               "block.kv_norm", "block.experts.router", "block.experts.router_bias"},
 }
 
 
@@ -50,7 +60,7 @@ def _leaves(tree):
 
 def _model(kind, dtype=jnp.bfloat16):
     """The toy configuration and a float32 tree with EVERY leaf random."""
-    cfg = TransformerConfig(max_seq=BS * W, dtype=dtype, **(DENSE if kind == "dense" else HYBRID))
+    cfg = TransformerConfig(max_seq=BS * W, dtype=dtype, **SIZES[kind])
     params = init_params(jax.random.PRNGKey(7), cfg)
     leaves, treedef = jax.tree.flatten(params)
     keys = jax.random.split(jax.random.PRNGKey(11), len(leaves))
@@ -69,7 +79,7 @@ def model(request):
 
 def _pool(cfg):
     pool = decode.init_block_pool(cfg, 1 + SLOTS * W, BS)
-    if cfg.layer_types is not None:
+    if cfg.stack == "hybrid":
         pool.update(hybrid.init_rec_state(cfg, SLOTS))
     return pool
 
@@ -78,7 +88,7 @@ def _through_the_programs(cfg, params, tokens, n_prompt, slot=1):
     """Prefill ``tokens[:n_prompt]`` in chunks of ``CHUNK`` (the last one
     padded), decode the rest a token a step beside two inactive lanes, then
     (dense only) verify three rows.  Returns every logits array and the pool."""
-    recurrent = cfg.layer_types is not None
+    recurrent = cfg.stack == "hybrid"
     chunk = jax.jit(partial(decode.paged_prefill_chunk, cfg=cfg))
     step = jax.jit(partial(decode.paged_decode_step, cfg=cfg))
     pool = _pool(cfg)
@@ -91,7 +101,7 @@ def _through_the_programs(cfg, params, tokens, n_prompt, slot=1):
         buf = np.zeros(CHUNK, np.int32)
         buf[:n] = tokens[start:start + n]
         kw = {"slot": jnp.int32(slot)} if recurrent else {}
-        logits, pool = chunk(params, pool, jnp.asarray(table), jnp.asarray(buf),
+        logits, pool, *_ = chunk(params, pool, jnp.asarray(table), jnp.asarray(buf),
                              jnp.int32(start), jnp.int32(n), **kw)
         out.append(logits)
     tables = np.zeros((SLOTS, W), np.int32)
@@ -99,10 +109,10 @@ def _through_the_programs(cfg, params, tokens, n_prompt, slot=1):
     active = np.arange(SLOTS) == slot
     lane = lambda v: jnp.asarray(np.where(active, v, 0).astype(np.int32))  # noqa: E731
     for i in range(n_prompt, len(tokens)):
-        logits, pool = step(params, pool, jnp.asarray(tables), lane(tokens[i]), lane(i),
+        logits, pool, *_ = step(params, pool, jnp.asarray(tables), lane(tokens[i]), lane(i),
                             jnp.asarray(active))
         out.append(logits)
-    if not recurrent:
+    if cfg.stack == "uniform":
         verify = jax.jit(partial(decode.paged_verify_step, cfg=cfg))
         rows = np.zeros((SLOTS, 3), np.int32)
         rows[slot] = tokens[:3]
@@ -147,7 +157,7 @@ def test_an_engine_serves_the_tokens_and_leaves_the_pool_the_float32_tree_gave(m
         kw.update(state_snapshot_every=32, state_snapshots=8)
     ours, before = ServingEngine(params, cfg, **kw), ServingEngine(params, cfg, **kw)
     assert ours.weight_dtype == "bfloat16"
-    assert _leaves(ours._params)["block.wi"].dtype == jnp.bfloat16
+    assert _leaves(ours._params)["unembed"].dtype == jnp.bfloat16
     before._params = params
     ours.start(), before.start()
     try:
