@@ -540,21 +540,77 @@ def _kv_through_table(pool, layer_idx, k, v, table, write_blk, write_off, dtype)
     return pool, gathered(pool, "k"), gathered(pool, "v")
 
 
-def _latent_through_table(pool, layer_idx, row, table, write_blk, write_off, dtype):
-    """:func:`_kv_through_table` for a latent pool: one leaf, ``c``, whose row
-    is all a token keeps.  ``row [B, T, width]``; addresses and ``table`` as
-    there, for a chunk and a decode step (no verify step reads a latent
-    pool).  Returns ``(pool, rows [B, W * bs, width])``.  The gather is over
-    the whole table width here too (ROADMAP S1)."""
-    bs, _, width = pool_geometry(pool)
-    lanes = 1 if table.ndim == 1 else table.shape[0]
-    new = row[0] if table.ndim == 1 else row[:, 0]
-    used = row.shape[-1]
-    if width != used:
-        new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, width - used),))
-    pool = _pool_append(pool, "c", layer_idx, new, write_blk, write_off)
+#: Keys of one tile of a prompt chunk's walk through its block table
+#: (:func:`_walk_table_tiles`).  A constant of the shapes: the engine's five
+#: chunk buckets stay all that mints a compilation.
+TILE_KEYS = 1024
+
+
+def _tile_blocks(table_width: int, block_size: int) -> int:
+    """Blocks of one key tile: ``TILE_KEYS`` keys, or the whole of a narrower
+    table."""
+    return min(max(TILE_KEYS // block_size, 1), table_width)
+
+
+def chunk_keys_attended(
+    cfg: TransformerConfig, live_end: int, table_width: int, block_size: int
+) -> int:
+    """Key positions a prompt chunk that ends at ``live_end`` (``start +
+    length``) attends, by the program that serves ``cfg``: the latent stack
+    walks whole tiles, first to the one that holds ``live_end - 1``
+    (:func:`_walk_table_tiles`); the dense and the hybrid stack attend the
+    whole table.  The host's count for ``/v1/stats``."""
+    if cfg.stack != "latent":
+        return table_width * block_size
+    tile = _tile_blocks(table_width, block_size) * block_size
+    return -(-live_end // tile) * tile
+
+
+def _walk_table_tiles(table, block_size, live_end, turn, carry):
+    """Fold ``turn(carry, blocks [tile blocks], first key position) -> carry``
+    over the key tiles of one sequence's ``table [W]``, from the first tile to
+    the one that holds position ``live_end - 1``.  The trip count is a traced
+    scalar (a ``while`` in the program), so one compilation serves every live
+    end and nothing of the table's width is formed; a table that is no whole
+    number of tiles is padded with the trash block, whose positions no query
+    reaches."""
+    W = table.shape[0]
+    tb = _tile_blocks(W, block_size)
+    table = jnp.pad(table, (0, -W % tb))
+    keys = tb * block_size
+
+    def body(t, carry):
+        return turn(carry, lax.dynamic_slice(table, (t * tb,), (tb,)), t * keys)
+
+    return lax.fori_loop(0, -(-live_end // keys), body, carry)
+
+
+def _latent_append(pool, layer_idx, new, write_blk, write_off):
+    """Write rows ``new [..., width]`` of layer ``layer_idx`` into a latent
+    pool's one leaf, ``c``, padded with zeros to the width the pool holds."""
+    pad = pool_geometry(pool)[2] - new.shape[-1]
+    if pad:
+        new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, pad),))
+    return _pool_append(pool, "c", layer_idx, new, write_blk, write_off)
+
+
+def _latent_gather(pool, layer_idx, table, dtype, used):
+    """Layer ``layer_idx``'s rows for ``table [..., W]`` (a whole table, a
+    tile's blocks) in logical-position order, ``[..., W * bs, used]``: the
+    pool's pad sliced off."""
     got = _pool_gather(pool, "c", layer_idx, table, dtype)
-    return pool, got.reshape(lanes, table.shape[-1] * bs, width)[..., :used]
+    return got.reshape(*table.shape[:-1], -1, got.shape[-1])[..., :used]
+
+
+def _latent_through_table(pool, layer_idx, row, tables, write_blk, write_off, dtype):
+    """:func:`_kv_through_table` for a decode step over a latent pool: one
+    leaf, ``c``, whose row is all a token keeps.  ``row [S, 1, width]``, one
+    address a lane, ``tables [S, W]``.  Returns ``(pool, rows [S, W * bs,
+    width])``: a step still gathers the whole table width (ROADMAP S1).  A
+    prompt chunk does not: it appends, then walks its table by tiles
+    (:func:`_walk_table_tiles`, ``latent_moe._chunk_mixer``)."""
+    pool = _latent_append(pool, layer_idx, row[:, 0], write_blk, write_off)
+    return pool, _latent_gather(pool, layer_idx, tables, dtype, row.shape[-1])
 
 
 def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:

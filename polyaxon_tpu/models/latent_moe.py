@@ -21,10 +21,15 @@ unembedding.
 (``kv_lora_rank + qk_rope_head_dim`` values) is all a token leaves behind: the
 paged pool's row, leaf ``c [layers, blocks, block, row]``, written and read in
 place through the block table as the dense model's K and V are.  Two forms of
-the one equation: a prompt chunk up-projects the gathered rows to keys and
-values (``mla.chunk_attend``: fewer multiply-adds a key where there are many
-queries), a decode step folds ``W_kvb`` into the query and the output and
-attends over the rows themselves (``mla.step_attend``, the absorbed form: no
+the one equation.  A prompt chunk walks its block table by key tiles, first
+tile to the one that holds its own last position (``decode._walk_table_tiles``:
+the trip count is traced, one compilation a chunk bucket): a tile's rows are
+gathered and up-projected to keys and values (fewer multiply-adds a key where
+there are many queries) and attended in the flash forward kernel, whose scores
+stay in VMEM, under a running ``(o, lse)`` (``mla.chunk_attend``,
+``_chunk_mixer``); nothing has the table's width.  A decode step folds
+``W_kvb`` into the query and the output and attends over the rows themselves,
+gathered over the whole table width (``mla.step_attend``, the absorbed form: no
 per-head keys for a table's width of rows).  Rotary pairs are split by halves
 (``transformer._rope``); no YaRN factor.
 
@@ -49,6 +54,7 @@ from jax import lax
 
 from polyaxon_tpu.models import decode
 from polyaxon_tpu.models.transformer import _rmsnorm, _rope
+from polyaxon_tpu.parallel import flash
 from polyaxon_tpu.parallel.experts import experts_mlp, route
 
 DENSE = "dense_mlp"
@@ -304,19 +310,6 @@ def _softmax_over(s, mask, dtype):
     return jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(dtype)
 
 
-def _attend_up_projected(q_nope, q_rope, rows, mask, layer, cfg):
-    """The equation as it reads: keys and values up-projected from the
-    gathered rows ``[B, K, row]``.  ``mask`` broadcasts to ``[B, H, T, K]``."""
-    dt = q_nope.dtype
-    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
-    kv = jnp.einsum("bkr,rhe->bkhe", rows[..., :rkv], decode._wdq(layer["wkv_b"], dt))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :dn])
-    s = (s + jnp.einsum("bqhd,bkd->bhqk", q_rope, rows[..., rkv:])) * scale
-    p = _softmax_over(s, mask, dt)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, kv[..., dn:])
-
-
 def _attend_absorbed(q_nope, q_rope, rows, mask, layer, cfg):
     """The same equation with ``W_kvb`` folded into the query and the output:
     scores against ``c`` itself, the value read from ``c`` and up-projected
@@ -416,19 +409,61 @@ def _run_stack(x, blk, pool, cfg, attend, valid):
     return x, pool, counts
 
 
-def _mixer(cfg, positions, table, write_blk, write_off, mask, scope, form):
-    """A program's attention mixer ``(h, layer, layer index, pool) -> (output,
-    pool)``: this call's rows written and the table's gathered, then attended
-    in ``form`` (one of the two above) under the named scope ``scope``."""
+def _chunk_mixer(cfg, qpos, table, write_blk, write_off, live_end):
+    """A prompt chunk's attention mixer ``(h [1, C, D], layer, layer index,
+    pool) -> (output, pool)``: the chunk's rows written, then the chunk attends
+    in the up-projected form by key tiles of ``table``, first tile to the one
+    that holds ``live_end - 1`` (``decode._walk_table_tiles``).  A turn gathers
+    one tile's rows, up-projects them to keys and values and attends them in
+    the flash forward kernel (scores float32, in VMEM; the query's offset
+    against the tile is its causal mask), and the tile's ``(o, lse)`` is merged
+    into the running pair: no array has the table's width.  A row no tile
+    admitted a key to (a chunk of length 0, the warm-up's) comes out zero."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+
+    def attend(h, layer, li, pool):
+        dt = h.dtype
+        q_nope, q_rope = _queries(h, layer, qpos[None], cfg)
+        row = _latent_row(h, layer, qpos[None], cfg)[0]
+        pool = decode._latent_append(pool, li, row, write_blk, write_off)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)[0].swapaxes(0, 1)  # [H, C, nope + rope]
+        wkv_b = decode._wdq(layer["wkv_b"], dt)
+        H, C = q.shape[:2]
+
+        def turn(carry, blocks, k0):
+            rows = decode._latent_gather(pool, li, blocks, dt, row.shape[-1])
+            c, k_rope = rows[:, :rkv], rows[:, rkv:]
+            kv = jnp.einsum("kr,rhe->hke", c, wkv_b)
+            k_rope = jnp.broadcast_to(k_rope, (H, *k_rope.shape))
+            tile = flash.flash_block_fwd(
+                q, jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:],
+                causal=True, sm_scale=scale, q_offset=qpos[0] - k0, name="mla_chunk_tile",
+            )
+            return flash._merge(*carry, *tile)
+
+        with jax.named_scope("mla.chunk_attend"):
+            o, _ = decode._walk_table_tiles(
+                table, decode.pool_geometry(pool)[0], live_end, turn,
+                (jnp.zeros((H, C, cfg.v_head_dim), jnp.float32), jnp.full((H, C), -jnp.inf)),
+            )
+        return decode._attn_out(o.astype(dt).swapaxes(0, 1)[None], layer), pool
+
+    return attend
+
+
+def _step_mixer(cfg, positions, tables, write_blk, write_off, mask):
+    """A decode step's attention mixer: every lane's row written and its
+    table's rows gathered, then attended in the absorbed form."""
 
     def attend(h, layer, li, pool):
         q_nope, q_rope = _queries(h, layer, positions, cfg)
         row = _latent_row(h, layer, positions, cfg)
         pool, rows = decode._latent_through_table(
-            pool, li, row, table, write_blk, write_off, h.dtype
+            pool, li, row, tables, write_blk, write_off, h.dtype
         )
-        with jax.named_scope(scope):
-            attn = form(q_nope, q_rope, rows, mask, layer, cfg)
+        with jax.named_scope("mla.step_attend"):
+            attn = _attend_absorbed(q_nope, q_rope, rows, mask, layer, cfg)
         return decode._attn_out(attn, layer), pool
 
     return attend
@@ -439,21 +474,17 @@ def _mixer(cfg, positions, table, write_blk, write_off, mask, scope, form):
 
 def paged_prefill_chunk(params, pool, table, tokens, start, length, cfg):
     """``decode.paged_prefill_chunk`` for the latent stack: the chunk's rows
-    ``[c | k_rope]`` are written at ``table``, then the chunk attends to every
-    row the table holds in the up-projected form.  Pad rows write to the trash
-    block, are masked as keys and route to no expert.  Returns ``(logits
-    [vocab] f32, new_pool, counts)``."""
+    ``[c | k_rope]`` are written at ``table``, then the chunk attends, tile by
+    tile in the up-projected form (``_chunk_mixer``), to the rows the table
+    holds up to ``start + length``.  Pad rows write to the trash block, are
+    keys to no real row and route to no expert.  Returns ``(logits [vocab]
+    f32, new_pool, counts)``."""
     c = cfg
     C = tokens.shape[0]
-    qpos, valid, write_blk, write_off, kpos = decode._chunk_addresses(
+    qpos, valid, write_blk, write_off, _ = decode._chunk_addresses(
         pool, table, start, length, C
     )
-    positions = qpos[None]
-    mask = (positions[:, None, :, None] >= kpos[:, None, None, :])
-    attend = _mixer(
-        c, positions, table, write_blk, write_off, mask,
-        "mla.chunk_attend", _attend_up_projected,
-    )
+    attend = _chunk_mixer(c, qpos, table, write_blk, write_off, start + length)
     x = params["embed"].astype(c.dtype)[tokens][None]  # [1, C, D]
     x, pool, counts = _run_stack(x, params["block"], pool, c, attend, valid[None])
     # Only the last real token's logits are read: one row against the vocabulary.
@@ -475,10 +506,7 @@ def paged_decode_step(params, pool, tables, tokens, pos, active, cfg, qweights=N
     positions = pos[:, None]
     kpos = jnp.arange(tables.shape[1] * bs)
     mask = (kpos[None, :] <= pos[:, None])[:, None, None, :]  # [S, 1, 1, K]
-    attend = _mixer(
-        c, positions, tables, write_blk, write_off, mask,
-        "mla.step_attend", _attend_absorbed,
-    )
+    attend = _step_mixer(c, positions, tables, write_blk, write_off, mask)
     x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # [S, 1, D]
     blk, unembed = _with_qweights(params, qweights)
     x, pool, counts = _run_stack(x, blk, pool, c, attend, active[:, None])

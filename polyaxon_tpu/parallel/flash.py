@@ -97,12 +97,17 @@ def _pick_block(t: int, want: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-    *, sm_scale, causal, bq, bk, nk,
-):
+def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False):
+    # With ``offset`` the first ref is a prefetched scalar: how far the first
+    # query row lies past the first key in a causal block (see flash_block_fwd).
+    if offset:
+        q0_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    row0 = qi * bq
+    if offset:
+        row0 = row0 + q0_ref[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -111,7 +116,7 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
 
     # Causal: blocks entirely above the diagonal contribute nothing.
-    run = (ki * bk <= qi * bq + bq - 1) if causal else (ki >= 0)
+    run = (ki * bk <= row0 + bq - 1) if causal else (ki >= 0)
 
     @pl.when(run)
     def _compute():
@@ -122,7 +127,7 @@ def _fwd_kernel(
         )
         s = s * sm_scale
         if causal:
-            rows = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            rows = row0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             keep = rows >= cols
             s = jnp.where(keep, s, _NEG_BIG)
@@ -166,49 +171,59 @@ def flash_block_fwd(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: bool | None = None,
+    q_offset: jax.Array | None = None,
+    name: str = "flash_fwd",
 ) -> Tuple[jax.Array, jax.Array]:
     """One attention block: returns ``(o, lse)`` with o float32-normalized.
 
-    q: [BH, Tq, d]; k, v: [BH, Tk, d].  ``causal`` masks assuming q and k
-    share a global offset (the ring's diagonal block).
+    q: [BH, Tq, d]; k: [BH, Tk, d]; v: [BH, Tk, dv].  ``causal`` masks assuming
+    q and k share a global offset (the ring's diagonal block), or, with
+    ``q_offset`` (a traced int32 scalar: first query position less first key
+    position, of either sign), a query that stands that far past the keys: a
+    key tile of a longer sequence.  A row that admits no key of the block
+    comes back as ``o = 0``, ``lse = -inf``, which :func:`_merge` folds away.
     """
     if interpret is None:
         interpret = pallas_interpret()
     BH, Tq, d = q.shape
-    Tk = k.shape[1]
+    Tk, dv = k.shape[1], v.shape[2]
     bq = _pick_block(Tq, block_q)
     bk = _pick_block(Tk, block_k)
     nq, nk = Tq // bq, Tk // bk
     from jax.experimental.pallas import tpu as pltpu
 
-    scratch = [
-        pltpu.VMEM((bq, d), jnp.float32),
-        pltpu.VMEM((bq, 128), jnp.float32),
-        pltpu.VMEM((bq, 128), jnp.float32),
-    ]
+    offset = q_offset is not None
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=int(offset),
+        grid=(BH, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j, *_: (b, j, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bq, dv), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b, i, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, dv), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+        ],
     )
     o, lse_pad = pl.pallas_call(
         kernel,
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, d), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Tq, dv), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tq, 128), jnp.float32),
         ],
-        scratch_shapes=scratch,
         interpret=interpret,
-        name="flash_fwd",
-    )(q, k, v)
+        name=name,
+    )(*([jnp.reshape(q_offset, (1,)).astype(jnp.int32)] if offset else []), q, k, v)
     return o, lse_pad[:, :, 0]
 
 

@@ -605,6 +605,10 @@ class ServingEngine:
             decode.kv_block_bytes(cfg, self.block_size, self.kv_quantize)
             // self.block_size
         )
+        #: Key positions a prompt chunk that ends at ``live_end`` attends.
+        self._chunk_keys = lambda live_end: decode.chunk_keys_attended(
+            cfg, live_end, self._table_width, self.block_size
+        )
         # Routed experts: each program returns what it routed in the call
         # beside its result (``latent_moe.COUNT_NAMES``).  The scheduler keeps
         # the counts of the calls it has dispatched and fetches them in the
@@ -808,6 +812,9 @@ class ServingEngine:
         self._n_steps = 0
         self._n_parks = 0
         self._n_cow = 0
+        # Key positions the prompt chunks attended, and what their tables held.
+        self._n_keys_attended = 0
+        self._n_keys_table = 0
         self._backlog_chunks = 0
         self._prefill_jobs = 0
         self._window: "deque[tuple]" = deque()  # (t, n_tokens)
@@ -1580,6 +1587,8 @@ class ServingEngine:
             jobs = self._prefill_jobs
             parks = self._n_parks
             cow = self._n_cow
+            keys_attended = self._n_keys_attended
+            keys_table = self._n_keys_table
             cancelled = self._n_cancelled
             shed = self._n_shed
             spilled = self._n_spilled_blocks
@@ -1649,6 +1658,11 @@ class ServingEngine:
             "prefill_jobs": jobs,
             "block_parks": parks,
             "cow_copies": cow,
+            # Key positions the prompt chunks attended against what their block
+            # tables span: equal where a chunk attends the whole table, fewer
+            # where it stops at its own live end (the latent stack's tiles).
+            "prefill_keys_attended": keys_attended,
+            "prefill_keys_table": keys_table,
             "requests_cancelled": cancelled,
             # Recurrent state (all 0 for a dense model): snapshots taken,
             # snapshots restored into a slot, snapshots lost (the store's
@@ -2210,6 +2224,9 @@ class ServingEngine:
         )
         self._note_counts(c_pad, counts)
         job.next_pos += n
+        with self._stats_lock:
+            self._n_keys_attended += self._chunk_keys(job.next_pos)
+            self._n_keys_table += self._table_width * bs
         if self._snaps is not None and job.next_pos % self._snap_every == 0:
             self._snapshot_state(slot, job.next_pos)
         done = job.next_pos >= t

@@ -110,3 +110,44 @@ def test_flash_fwd_bwd_cross_lower_to_three_mosaic_calls():
     assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert f'kernel_name = "{name}"' in text
+
+
+@pytest.mark.parametrize("offset", [0, 24, -8, -40, 100])
+def test_a_key_tile_under_a_query_offset_is_the_masked_softmax_and_merges(offset):
+    """``flash_block_fwd`` with ``q_offset`` (the first query that far past the
+    tile's first key, of either sign; keys wider than values): against the plain
+    softmax under ``row + offset >= column``, a row the tile admits no key to
+    comes back ``o = 0``, ``lse = -inf``, and two tiles' pairs merge to the
+    softmax over both."""
+    import numpy as np
+
+    rng = np.random.default_rng(offset % 7)
+    H, Tq, Tk, d, dv = 2, 32, 64, 24, 16
+    q = jnp.asarray(rng.normal(size=(H, Tq, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(H, 2 * Tk, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(H, 2 * Tk, dv)), jnp.float32)
+    scale = d**-0.5
+
+    def plain(k, v, off):
+        keep = (jnp.arange(Tq)[:, None] + off >= jnp.arange(k.shape[1])[None])[None]
+        s = jnp.where(keep, jnp.einsum("hqd,hkd->hqk", q, k) * scale, -jnp.inf)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.where(keep, jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0)[..., None]), 0.0)
+        return jnp.einsum("hqk,hkd->hqd", p, v), lse
+
+    def tile(i, off):
+        return flash.flash_block_fwd(
+            q, k[:, i * Tk:(i + 1) * Tk], v[:, i * Tk:(i + 1) * Tk], causal=True,
+            sm_scale=scale, block_q=16, block_k=32, q_offset=jnp.int32(off))
+
+    with jax.default_matmul_precision("highest"):
+        (o, lse), (want_o, want_lse) = tile(0, offset), plain(k[:, :Tk], v[:, :Tk], offset)
+        dead = np.asarray(jnp.isneginf(want_lse))
+        assert dead.any() == (offset < 0) and (np.asarray(jnp.isneginf(lse)) == dead).all()
+        assert not np.asarray(o)[dead].any()
+        assert float(jnp.max(jnp.abs(o - want_o))) < 1e-5
+        assert float(jnp.max(jnp.abs(jnp.where(dead, 0.0, lse - want_lse)))) < 1e-5
+        both = flash._merge(o, lse, *tile(1, offset - Tk))
+        want_o, want_lse = plain(k, v, offset)
+        assert float(jnp.max(jnp.abs(both[0] - want_o))) < 1e-5
+        assert float(jnp.max(jnp.abs(jnp.where(dead, 0.0, both[1] - want_lse)))) < 1e-5

@@ -1,5 +1,6 @@
 """A latent-attention model with routed experts through the paged programs and
-the engine: logits against the plain reference, the two forms of the attention,
+the engine: logits against the plain reference, the two forms of the attention
+and a chunk's walk by key tiles against the plain rule over the whole table,
 the dropless expert product against "every expert over every row", the chip's
 share against the uncut layer, prefix hits and copy-on-write through the latent
 pool, the counters, and the options refused.
@@ -12,6 +13,7 @@ over twenty times what the two read apart here (1e-6 to 2e-6), and far under
 what one token routed to another expert reads (asserted below: over 1e-3).
 """
 
+import re
 from functools import partial
 
 import jax
@@ -81,17 +83,19 @@ def reference_logits(mine, tokens, rows):
     return fn(mine, jnp.asarray(padded), jnp.asarray(at))[: len(rows)]
 
 
-def _pool(cfg, kv_dtype=None):
-    return decode.init_block_pool(cfg, 1 + SLOTS * W, BS, kv_dtype=kv_dtype)
+def _pool(cfg, kv_dtype=None, width=W):
+    return decode.init_block_pool(cfg, 1 + SLOTS * width, BS, kv_dtype=kv_dtype)
 
 
 def _serve_through_the_programs(cfg, params, tokens, n_prompt, chunks, pool, slot=1):
     """Prefill ``tokens[:n_prompt]`` in ``chunks`` [(start, n, padded)], then decode
-    the rest one token a step in ``slot`` beside two inactive lanes.  Returns the
-    logits and what the calls' expert layers routed in all (``latent_moe.COUNT_NAMES``)."""
+    the rest one token a step in ``slot`` beside two inactive lanes; the tables are
+    as wide as ``pool`` gives each lane blocks.  Returns the logits and what the
+    calls' expert layers routed in all (``latent_moe.COUNT_NAMES``)."""
     chunk, step = _PROGRAMS.setdefault(cfg, (
         jax.jit(partial(decode.paged_prefill_chunk, cfg=cfg)),
         jax.jit(partial(decode.paged_decode_step, cfg=cfg))))
+    W = (decode._row_leaf(pool).shape[1] - 1) // SLOTS
     table = np.zeros(W, np.int32)
     table[: -(-len(tokens) // BS)] = 1 + slot * W + np.arange(-(-len(tokens) // BS))
     out, routed = [], np.zeros(4, np.int64)
@@ -124,6 +128,8 @@ def test_program_draws_the_references_weights(tiny):
 
 
 CHUNKS = [(0, 32, 32), (32, 32, 32), (64, 13, 16)]  # the last in a bucket of 16
+#: Blocks of a table three key tiles wide (``decode.TILE_KEYS`` a tile).
+W_TILES = 3 * decode.TILE_KEYS // BS
 
 
 def test_whole_prefill_chunked_prefill_and_decode_agree_with_the_references_forward(tiny):
@@ -133,9 +139,13 @@ def test_whole_prefill_chunked_prefill_and_decode_agree_with_the_references_forw
     whole, pool_a, counts_a = _serve_through_the_programs(
         cfg, params, tokens, 77, [(0, 77, 128)], _pool(cfg))
     chunked, _, counts_b = _serve_through_the_programs(cfg, params, tokens, 77, CHUNKS, _pool(cfg))
+    # a table two tiles wider than what is live: the chunks walk its first tile only
+    wide, _, counts_c = _serve_through_the_programs(
+        cfg, params, tokens, 77, CHUNKS, _pool(cfg, width=W_TILES))
     assert float(jnp.max(jnp.abs(want))) > 1.0
     assert float(jnp.max(jnp.abs(whole - want))) < LOGIT_TOL
     assert float(jnp.max(jnp.abs(chunked - want))) < LOGIT_TOL
+    assert float(jnp.max(jnp.abs(wide - want))) < LOGIT_TOL and list(counts_c) == list(counts_b)
     # the pool holds one row a token a layer, the row padded to whole lane tiles
     assert pool_a["c"].shape == (3, 1 + SLOTS * W, BS, 128)
     assert latent_moe.row_width(cfg) == 40 and not bool(jnp.any(pool_a["c"][..., 40:]))
@@ -147,18 +157,131 @@ def test_whole_prefill_chunked_prefill_and_decode_agree_with_the_references_forw
     assert counts_a[1] == counts_b[1]  # the same rows fall to the same experts, however cut
 
 
+def _attend_up_projected(q_nope, q_rope, rows, mask, layer, cfg):
+    """The plain rule a prompt chunk is held to, the equation as it reads: keys
+    and values up-projected from ALL the rows a table gathers ``[B, K, row]``,
+    one softmax over them; ``mask`` broadcasts to ``[B, H, T, K]``."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    kv = jnp.einsum("bkr,rhe->bkhe", rows[..., :rkv], layer["wkv_b"])
+    s = jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :dn])
+    s = (s + jnp.einsum("bqhd,bkd->bhqk", q_rope, rows[..., rkv:])) * scale
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, kv[..., dn:])
+
+
+def _attention_layer(params, at=1):
+    return jax.tree.map(lambda w: w[at], {n: params["block"][n] for n in latent_moe._ATTN + latent_moe._NORMS})
+
+
 def test_the_absorbed_form_is_the_up_projected_form(tiny):
     cfg, params, _ = tiny
     rng = np.random.default_rng(2)
-    layer = jax.tree.map(lambda w: w[1], {n: params["block"][n] for n in latent_moe._ATTN})
+    layer = _attention_layer(params)
     q_nope = jnp.asarray(rng.normal(size=(2, 3, 4, 16)), jnp.float32)
     q_rope = jnp.asarray(rng.normal(size=(2, 3, 4, 8)), jnp.float32)
     rows = jnp.asarray(rng.normal(size=(2, 24, 40)), jnp.float32)
     mask = jnp.asarray(rng.random((2, 1, 3, 24)) < 0.7).at[..., 0].set(True)
-    up = latent_moe._attend_up_projected(q_nope, q_rope, rows, mask, layer, cfg)
+    up = _attend_up_projected(q_nope, q_rope, rows, mask, layer, cfg)
     absorbed = latent_moe._attend_absorbed(q_nope, q_rope, rows, mask, layer, cfg)
     assert up.shape == (2, 3, 4, 16) and float(jnp.max(jnp.abs(up))) > 0.1
     assert float(jnp.max(jnp.abs(up - absorbed))) < 1e-5
+
+
+TILE = decode.TILE_KEYS
+
+
+@pytest.mark.parametrize("start,length,C,width,kv_dtype", [
+    (0, 32, 32, W_TILES, None),                # the first tile of three, from position 0
+    (TILE - 14, 32, 32, W_TILES, None),        # across a tile edge
+    (TILE + 3, 29, 32, W_TILES, None),         # off a block edge, pad rows in the bucket
+    (2 * TILE - 32, 32, 32, W_TILES, None),    # the live end exactly on a tile edge
+    (3 * TILE - 40, 13, 16, W_TILES, None),    # the table's last tile
+    (TILE + 200, 32, 32, W_TILES // 2, None),  # a table of one tile and a half
+    (64, 32, 32, W, None),                     # a table narrower than a tile: one tile
+    (TILE - 14, 32, 32, W_TILES, "int8"),      # the int8 latent pool, across a tile edge
+], ids=["first-tile", "tile-edge", "block-edge-pad", "ends-on-edge", "last-tile",
+        "ragged-table", "single-tile", "int8-pool"])
+def test_a_chunk_walking_its_table_by_tiles_is_the_plain_rule_over_the_whole_table(
+        tiny, start, length, C, width, kv_dtype):
+    """``_chunk_mixer`` (rows written, then key tiles up to the live end under a
+    running softmax) against the rows written the same way, ALL of the table
+    gathered and one softmax under the mask ``qpos >= kpos``."""
+    cfg, params, _ = tiny
+    rng = np.random.default_rng(start + length)
+    layer, li = _attention_layer(params), jnp.int32(1)
+    # every block holds rows already: normal values, or int8 values under scales near 1 / 127
+    pool = {
+        name: jnp.asarray(
+            rng.integers(-127, 128, leaf.shape) if leaf.dtype == jnp.int8
+            else rng.uniform(0.5, 1.5, leaf.shape) / 127 if name.endswith("_scale")
+            else rng.normal(size=leaf.shape), leaf.dtype)
+        for name, leaf in decode.init_block_pool(cfg, 1 + width, BS, kv_dtype=kv_dtype).items()}
+    table = jnp.asarray(1 + rng.permutation(width), jnp.int32)
+    h = jnp.asarray(rng.normal(size=(1, C, 64)), jnp.float32)
+    qpos, valid, write_blk, write_off, kpos = decode._chunk_addresses(
+        pool, table, jnp.int32(start), jnp.int32(length), C)
+
+    @jax.jit
+    def tiled(h, pool):
+        attend = latent_moe._chunk_mixer(cfg, qpos, table, write_blk, write_off, start + length)
+        return attend(h, layer, li, pool)
+
+    @jax.jit
+    def plain(h, pool):
+        q_nope, q_rope = latent_moe._queries(h, layer, qpos[None], cfg)
+        row = latent_moe._latent_row(h, layer, qpos[None], cfg)
+        pool = decode._latent_append(pool, li, row[0], write_blk, write_off)
+        rows = decode._latent_gather(pool, li, table, h.dtype, row.shape[-1])[None]
+        mask = qpos[None, None, :, None] >= kpos[:, None, None, :]
+        attn = _attend_up_projected(q_nope, q_rope, rows, mask, layer, cfg)
+        return decode._attn_out(attn, layer), pool
+
+    (got, pool_a), (want, pool_b) = tiled(h, pool), plain(h, pool)
+    assert want.shape == (1, C, 64) and float(jnp.max(jnp.abs(want[0, :length]))) > 0.1
+    assert float(jnp.max(jnp.abs(got[0, :length] - want[0, :length]))) < LOGIT_TOL
+    assert bool(jnp.all(jnp.isfinite(got)))  # pad rows: not read, and never NaN into the pool
+    for a, b in zip(jax.tree.leaves(pool_a), jax.tree.leaves(pool_b)):
+        assert bool(jnp.all(a == b))
+    # and a chunk of length 0, the warm-up's: no tile walked, zeros out
+    nothing = jax.jit(lambda h, pool: latent_moe._chunk_mixer(
+        cfg, qpos, table, write_blk * 0, write_off * 0, 0)(h, layer, li, pool))(h, pool)[0]
+    assert not bool(jnp.any(nothing))
+
+
+def test_the_chunk_program_forms_nothing_of_the_tables_width_and_compiles_once(tiny, monkeypatch):
+    """No array of the chunk program has the table's ``W * bs`` positions as a
+    dimension (the scores ``heads x C x (W * bs)`` and the keys and values ``(W
+    * bs) x heads x (nope + v)`` among them); lowered for the TPU a tile's
+    scores are not in the text either, they stay in the kernel; and the walk's
+    trip count is data: one compilation serves every ``start``."""
+    from jax import export
+
+    from polyaxon_tpu.parallel import flash
+
+    cfg, params, _ = tiny
+    C, positions = 16, W_TILES * BS  # 16 rows: no other array is heads x 16 x a tile
+    pool = _pool(cfg, width=W_TILES)
+    fn = jax.jit(partial(decode.paged_prefill_chunk, cfg=cfg))
+    table = jnp.asarray(1 + np.arange(W_TILES), jnp.int32)
+    args = (params, pool, table, jnp.zeros(C, jnp.int32))
+
+    def shapes(text):
+        assert "stablehlo.while" in text
+        found = set(re.findall(r"tensor<([0-9x]+)x[a-z]", text))
+        assert str(W_TILES) in found and f"{TILE}x{latent_moe.row_width(cfg)}" in found  # table, a tile's rows
+        assert not [s for s in found if str(positions) in s.split("x")], found
+        return found
+
+    shapes(fn.lower(*args, jnp.int32(0), jnp.int32(C)).as_text())
+    out = [fn(*args, jnp.int32(start), jnp.int32(C))[0] for start in (0, 2 * TILE + 8)]
+    assert fn._cache_size() == 1 and float(jnp.max(jnp.abs(out[0] - out[1]))) > 0
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    on_tpu = export.export(
+        jax.jit(partial(decode.paged_prefill_chunk, cfg=cfg)), platforms=["tpu"]
+    )(*args, jnp.int32(0), jnp.int32(C)).mlir_module()
+    assert 'kernel_name = "mla_chunk_tile"' in on_tpu
+    assert f"{cfg.n_heads}x{C}x{TILE}" not in shapes(on_tpu)
 
 
 def _every_expert_over_every_row(h, chosen, gates, valid, wi, wg, wd, offset):
@@ -325,6 +448,28 @@ def test_stats_carry_the_expert_counters_and_the_row_bytes(served):
     phases = sum(v for k, v in stats.items()
                  if k.startswith("loop_") and k.endswith("_s") and k != "loop_wall_s")
     assert phases == pytest.approx(stats["loop_wall_s"], abs=1e-4)
+
+
+@pytest.mark.parametrize("stack", ["latent", "dense"])
+def test_stats_count_the_keys_a_chunk_attended_against_its_tables_width(tiny, stack):
+    """A table three tiles wide and a prompt of two chunks inside its first tile:
+    the latent stack's chunks attend one tile each, a dense model's the whole table."""
+    if stack == "latent":
+        cfg, params = make_cfg(TINY, seq=W_TILES * BS), tiny[1]
+    else:
+        cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+                                d_ff=64, n_kv_heads=2, max_seq=W_TILES * BS, dtype=jnp.float32)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    engine = _engine(cfg, params, slots=2)
+    try:
+        assert engine.stats()["prefill_keys_table"] == engine.stats()["prefill_keys_attended"] == 0
+        engine.generate(np.random.default_rng(6).integers(0, 64, 40).tolist(), 2, timeout=300)
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    assert stats["prefill_keys_table"] == 2 * W_TILES * BS  # chunks of 32 and 8 rows
+    attended = 2 * TILE if stack == "latent" else 2 * W_TILES * BS
+    assert stats["prefill_keys_attended"] == attended <= stats["prefill_keys_table"]
 
 
 def test_a_dense_model_reports_row_bytes_and_no_expert_counters():
