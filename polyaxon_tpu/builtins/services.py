@@ -501,6 +501,23 @@ def lm_server(ctx: Context) -> None:
     ``moe_rows_busiest`` / ``moe_experts_hit`` / ``moe_call_shapes``).  It
     refuses ``spec_decode`` and a multi-chip mesh with a ``LatentStackError``
     naming the option.
+
+    A model of window and full attention layers (``models/window_moe.py``) is
+    declared by ``mlp_layer_types`` (``dense`` / ``sparse``, a layer each)
+    beside ``layer_types`` (``full_attention`` / ``sliding_attention``), with
+    ``sliding_window``, ``sliding_n_heads`` (query heads of the window layers;
+    ``n_heads`` are the full layers'), ``head_gate`` (one sigmoid gate a
+    head), the rotary forms ``rope_theta`` / ``partial_rotary_factor`` /
+    ``rope_yarn_factor`` / ``rope_yarn_original_max`` / ``rope_yarn_beta_fast``
+    / ``rope_yarn_beta_slow`` / ``rope_attention_factor`` (full layers) and
+    ``sliding_rope_theta`` (window layers), and the expert sizes above (the
+    router a softmax, no selection bias).  Its window layers keep a ring of
+    ``sliding_window`` K and V rows a slot beside the pool, snapshotted for
+    prefix hits as the hybrid model's state is (``state_snapshot_every``,
+    ``state_snapshots``); ``/v1/stats`` carries ``window_pairs`` and
+    ``window_chunk_calls``.  It refuses ``spec_decode``, ``kv_offload``,
+    ``kv_persist`` and a multi-chip mesh with a ``WindowStackError`` naming
+    the option.
     """
     import jax
 
@@ -557,6 +574,24 @@ def lm_server(ctx: Context) -> None:
         cfg_fields["routed_scaling_factor"] = float(
             ctx.get_param("routed_scaling_factor")
         )
+    # Window and full attention mixed (models/window_moe.py): the second list
+    # names each layer's MLP and selects the stack; heads, the gate and the
+    # rotary forms by layer kind.
+    mlp_layer_types = ctx.get_param("mlp_layer_types")
+    if mlp_layer_types is not None:
+        if isinstance(mlp_layer_types, str):
+            mlp_layer_types = [t.strip() for t in mlp_layer_types.split(",") if t.strip()]
+        cfg_fields["mlp_layer_types"] = tuple(str(t) for t in mlp_layer_types)
+        cfg_fields["head_gate"] = _truthy(ctx.get_param("head_gate", False))
+    for f, kind in (
+        ("sliding_window", int), ("sliding_n_heads", int),
+        ("rope_yarn_original_max", int), ("partial_rotary_factor", float),
+        ("rope_yarn_factor", float), ("rope_yarn_beta_fast", float),
+        ("rope_yarn_beta_slow", float), ("rope_attention_factor", float),
+        ("sliding_rope_theta", float),
+    ):
+        if ctx.get_param(f) is not None:
+            cfg_fields[f] = kind(ctx.get_param(f))
     cfg = TransformerConfig(max_seq=seq, **cfg_fields)
     params = init_params(jax.random.PRNGKey(ctx.seed or 0), cfg)
 
@@ -578,14 +613,10 @@ def lm_server(ctx: Context) -> None:
     template = None
     param_shardings = None
     if mesh is not None and mesh.size > 1:
-        if cfg.stack == "latent":
-            from polyaxon_tpu.models.latent_moe import LatentStackError
+        if cfg.stack != "uniform":  # hybrid, latent, window: typed, by name
+            from polyaxon_tpu.models.transformer import stack_module
 
-            raise LatentStackError("mesh")
-        if cfg.stack == "hybrid":
-            from polyaxon_tpu.models.hybrid import RecurrentStateError
-
-            raise RecurrentStateError("mesh")
+            raise stack_module(cfg).refusal("mesh")
         from polyaxon_tpu.models.decode import decode_param_shardings
         from polyaxon_tpu.parallel import template_for
 

@@ -98,6 +98,10 @@ def quantize_weights(params: Dict[str, Any]) -> Dict[str, Any]:
         from polyaxon_tpu.models import latent_moe
 
         return latent_moe.quantize_weights(params, q)
+    if "window" in blk:  # the window stack: likewise
+        from polyaxon_tpu.models import window_moe
+
+        return window_moe.quantize_weights(params, q)
     out = {
         name: q(blk[name], axes)
         for name, axes in QUANTIZED_BLOCK_WEIGHTS.items()
@@ -135,6 +139,10 @@ def serving_params(params: Dict[str, Any], cfg: TransformerConfig) -> Dict[str, 
         from polyaxon_tpu.models import latent_moe
 
         return latent_moe.serving_params(params, cast)
+    if "window" in blk:  # the window stack: its own leaf list
+        from polyaxon_tpu.models import window_moe
+
+        return window_moe.serving_params(params, cast)
     return {
         **params,
         "embed": cast(params["embed"]),
@@ -346,6 +354,12 @@ def prefill(
 #: Pool leaves that hold a hybrid model's per-slot recurrent state
 #: (``models/hybrid.py``), not KV blocks.
 REC_LEAVES = ("rec_s", "rec_c")
+#: Pool leaves that hold a window layer's per-slot ring of K and V rows
+#: (``models/window_moe.py``), in the compute dtype or as int8 rows and scales.
+WIN_LEAVES = ("win_k", "win_v")
+WIN_LEAVES_INT8 = ("win_k_q", "win_k_scale", "win_v_q", "win_v_scale")
+#: Every per-slot leaf: what a block copy leaves alone and a snapshot copies.
+SLOT_LEAVES = REC_LEAVES + WIN_LEAVES + WIN_LEAVES_INT8
 
 
 def init_block_pool(
@@ -558,9 +572,10 @@ def chunk_keys_attended(
     """Key positions a prompt chunk that ends at ``live_end`` (``start +
     length``) attends, by the program that serves ``cfg``: the latent stack
     walks whole tiles, first to the one that holds ``live_end - 1``
-    (:func:`_walk_table_tiles`); the dense and the hybrid stack attend the
-    whole table.  The host's count for ``/v1/stats``."""
-    if cfg.stack != "latent":
+    (:func:`_walk_table_tiles`), and so do the window stack's full layers;
+    the dense and the hybrid stack attend the whole table.  The host's count
+    for ``/v1/stats``."""
+    if cfg.stack not in ("latent", "window"):
         return table_width * block_size
     tile = _tile_blocks(table_width, block_size) * block_size
     return -(-live_end // tile) * tile
@@ -583,6 +598,33 @@ def _walk_table_tiles(table, block_size, live_end, turn, carry):
         return turn(carry, lax.dynamic_slice(table, (t * tb,), (tb,)), t * keys)
 
     return lax.fori_loop(0, -(-live_end // keys), body, carry)
+
+
+def _kv_append(pool, layer_idx, k, v, write_blk, write_off):
+    """Append rows ``k`` / ``v [..., Hkv, d]`` of layer ``layer_idx`` at one
+    address a row, padded with zero heads where the pool's rows hold more
+    (:func:`_kv_through_table`'s first half, for a caller that reads the table
+    by tiles instead of whole)."""
+    pad = pool_geometry(pool)[1] - k.shape[-2]
+    for name, new in (("k", k), ("v", v)):
+        if pad:
+            new = jnp.pad(new, ((0, 0),) * (new.ndim - 2) + ((0, pad), (0, 0)))
+        pool = _pool_append(pool, name, layer_idx, new, write_blk, write_off)
+    return pool
+
+
+def _kv_tile(pool, layer_idx, blocks, dtype, kv_heads):
+    """K and V of layer ``layer_idx`` for one key tile's ``blocks [tile
+    blocks]``, each ``[Hkv, tile keys, d]`` in logical-position order at
+    ``dtype``: what a turn of :func:`_walk_table_tiles` attends where the pool
+    keeps K and V (int8 rows dequantised in the read, heads the pool's rows
+    hold beyond the model's sliced off)."""
+    def one(name):
+        got = _pool_gather(pool, name, layer_idx, blocks, dtype)
+        got = got.reshape((-1,) + got.shape[2:])[:, :kv_heads]
+        return got.swapaxes(0, 1)
+
+    return one("k"), one("v")
 
 
 def _latent_append(pool, layer_idx, new, write_blk, write_off):
@@ -614,9 +656,32 @@ def _latent_through_table(pool, layer_idx, row, tables, write_blk, write_off, dt
 
 
 def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-    """The leaves addressed by block: all but a hybrid model's per-slot
-    recurrent rows (``REC_LEAVES``), which ride the same dict."""
-    return {n: leaf for n, leaf in pool.items() if n not in REC_LEAVES}
+    """The leaves addressed by block: all but the per-slot rows of a hybrid or
+    a window model (``SLOT_LEAVES``), which ride the same dict."""
+    return {n: leaf for n, leaf in pool.items() if n not in SLOT_LEAVES}
+
+
+def take_snapshot(store, pool, slot, idx, names):
+    """Copy slot ``slot``'s per-slot rows (leaves ``names``, slots on axis 1)
+    out of the pool into place ``idx`` of the snapshot store (jit with the
+    STORE donated; the pool is only read)."""
+    return {
+        name: lax.dynamic_update_slice_in_dim(
+            store[name], lax.dynamic_slice_in_dim(pool[name], slot, 1, axis=1),
+            idx, axis=1)
+        for name in names
+    }
+
+
+def restore_snapshot(pool, store, idx, slot, names):
+    """Copy place ``idx`` of the snapshot store into slot ``slot``'s per-slot
+    rows (jit with the POOL donated)."""
+    out = dict(pool)
+    for name in names:
+        out[name] = lax.dynamic_update_slice_in_dim(
+            pool[name], lax.dynamic_slice_in_dim(store[name], idx, 1, axis=1),
+            slot, axis=1)
+    return out
 
 
 def copy_block(
@@ -763,7 +828,9 @@ def paged_prefill_chunk(
     A model with a layer pattern (``cfg.layer_types``) goes through
     ``models/hybrid.py``'s form of this program instead: it also needs the
     ``slot`` whose recurrent rows (leaves of the same pool) the chunk
-    advances.  A latent-attention model (``cfg.kv_lora_rank``) goes through
+    advances; so does a model with window layers (``models/window_moe.py``:
+    the slot's rings, and the expert counts as a third value).  A
+    latent-attention model (``cfg.kv_lora_rank``) goes through
     ``models/latent_moe.py``'s, which returns a third value beside these two:
     what its expert layers routed in this call (``latent_moe.COUNT_NAMES``).
     """
@@ -773,10 +840,8 @@ def paged_prefill_chunk(
         return latent_moe.paged_prefill_chunk(
             params, pool, table, tokens, start, length, cfg
         )
-    if cfg.stack == "hybrid":
-        from polyaxon_tpu.models import hybrid
-
-        return hybrid.paged_prefill_chunk(
+    if cfg.stack in ("hybrid", "window"):  # per-slot rows beside the blocks
+        return stack_module(cfg).paged_prefill_chunk(
             params, pool, table, tokens, start, length, slot, cfg
         )
     c = cfg
@@ -917,14 +982,8 @@ def paged_verify_step(
     qweights and int8 KV pools compose the same way), so greedy outputs
     stay token-identical to the non-speculative path.
     """
-    if cfg.stack == "latent":
-        from polyaxon_tpu.models.latent_moe import LatentStackError
-
-        raise LatentStackError("spec_decode")
-    if cfg.stack == "hybrid":
-        from polyaxon_tpu.models.hybrid import RecurrentStateError
-
-        raise RecurrentStateError("spec_decode")
+    if cfg.stack != "uniform":  # hybrid, latent, window: each refuses it, typed
+        raise stack_module(cfg).refusal("spec_decode")
     c = cfg
     S, W = tables.shape
     T = tokens.shape[1]

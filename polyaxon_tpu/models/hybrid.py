@@ -76,6 +76,10 @@ class RecurrentStateError(ValueError):
         self.option = option
 
 
+#: What the engine raises for an option of ``REFUSED``.
+refusal = RecurrentStateError
+
+
 def check_config(cfg) -> None:
     """What ``TransformerConfig.__post_init__`` holds a layer pattern to."""
     types = cfg.layer_types
@@ -252,9 +256,10 @@ def _with_qweights(params, qweights):
     return merged, qweights["unembed"]
 
 
-def init_rec_state(cfg, rows: int) -> Dict[str, jax.Array]:
+def init_rec_state(cfg, rows: int, kv_dtype=None) -> Dict[str, jax.Array]:
     """Zeroed recurrent state for ``rows`` sequences (the engine's slots, or
-    the places of its snapshot store): the ``decode.REC_LEAVES``."""
+    the places of its snapshot store): the ``decode.REC_LEAVES``, float32
+    whatever ``kv_dtype`` the pool's blocks are kept at."""
     c = cfg
     _, n_lin = _counts(c)
     return {
@@ -267,7 +272,7 @@ def init_rec_state(cfg, rows: int) -> Dict[str, jax.Array]:
     }
 
 
-def rec_row_bytes(cfg) -> int:
+def rec_row_bytes(cfg, kv_dtype=None) -> int:
     """Device bytes of ONE sequence's recurrent state (one snapshot)."""
     c = cfg
     _, n_lin = _counts(c)
@@ -278,23 +283,13 @@ def rec_row_bytes(cfg) -> int:
 def take_snapshot(store, pool, slot, idx):
     """Copy slot ``slot``'s recurrent rows out of the pool into place ``idx``
     of the snapshot store (jit with the STORE donated; the pool is only read)."""
-    return {
-        name: lax.dynamic_update_slice_in_dim(
-            store[name], lax.dynamic_slice_in_dim(pool[name], slot, 1, axis=1),
-            idx, axis=1)
-        for name in decode.REC_LEAVES
-    }
+    return decode.take_snapshot(store, pool, slot, idx, decode.REC_LEAVES)
 
 
 def restore_snapshot(pool, store, idx, slot):
     """Copy place ``idx`` of the snapshot store into slot ``slot``'s recurrent
     rows (jit with the POOL donated)."""
-    out = dict(pool)
-    for name in decode.REC_LEAVES:
-        out[name] = lax.dynamic_update_slice_in_dim(
-            pool[name], lax.dynamic_slice_in_dim(store[name], idx, 1, axis=1),
-            slot, axis=1)
-    return out
+    return decode.restore_snapshot(pool, store, idx, slot, decode.REC_LEAVES)
 
 
 # -- the layers -----------------------------------------------------------------

@@ -55,19 +55,12 @@ from jax import lax
 from polyaxon_tpu.models import decode
 from polyaxon_tpu.models.transformer import _rmsnorm, _rope
 from polyaxon_tpu.parallel import flash
-from polyaxon_tpu.parallel.experts import experts_mlp, route
+from polyaxon_tpu.parallel.experts import COUNT_NAMES, experts_mlp, route, route_softmax
 
 DENSE = "dense_mlp"
 EXPERTS = "expert_mlp"
 _ATTN = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
 _NORMS = ("attn_norm", "mlp_norm", "q_norm", "kv_norm")
-
-#: What a program's expert layers routed in one call, summed over the layers:
-#: int32 ``[rows routed (token x choice; padding and idle lanes left out), rows
-#: that fell to experts held here, the busiest expert's rows, experts that had
-#: a row]``.  The third value both paged programs return; the engine reads it
-#: with the call's result and ``/v1/stats`` carries the totals under these names.
-COUNT_NAMES = ("moe_rows_routed", "moe_rows_held", "moe_rows_busiest", "moe_experts_hit")
 
 #: What this stack cannot follow yet, and what each would take.
 REFUSED = {
@@ -87,6 +80,10 @@ class LatentStackError(ValueError):
             f"{REFUSED[option]}"
         )
         self.option = option
+
+
+#: What the engine raises for an option of ``REFUSED``.
+refusal = LatentStackError
 
 
 def check_config(cfg) -> None:
@@ -132,6 +129,10 @@ def experts_held(cfg) -> int:
 
 def _counts(cfg) -> Tuple[int, int]:
     return cfg.layer_types.count(DENSE), cfg.layer_types.count(EXPERTS)
+
+
+def expert_layers(cfg) -> int:
+    return cfg.layer_types.count(EXPERTS)
 
 
 def runs(cfg) -> List[Tuple[str, int]]:
@@ -338,10 +339,11 @@ def _expert_mlp(h, layer, valid, cfg, stacks, index):
     flat = h.reshape(B * T, D)
     ok = valid.reshape(B * T)
     with jax.named_scope("moe.route"):
-        chosen, gates = route(
-            flat, layer["router"], layer["router_bias"],
-            cfg.num_experts_per_tok, cfg.routed_scaling_factor,
-        )
+        k, scale = cfg.num_experts_per_tok, cfg.routed_scaling_factor
+        if "router_bias" in layer:
+            chosen, gates = route(flat, layer["router"], layer["router_bias"], k, scale)
+        else:  # the window stack's layers: a softmax router, no selection bias
+            chosen, gates = route_softmax(flat, layer["router"], k, scale)
     dt = h.dtype
     weights, at = [stacks[n] for n in ("wi", "wg", "wd")], index
     if isinstance(weights[0], tuple):

@@ -96,7 +96,12 @@ class TransformerConfig:
             )
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        if self.kv_lora_rank:
+        if self.mlp_layer_types is not None:
+            object.__setattr__(self, "mlp_layer_types", tuple(self.mlp_layer_types))
+            from polyaxon_tpu.models.window_moe import check_config
+
+            check_config(self)
+        elif self.kv_lora_rank:
             from polyaxon_tpu.models.latent_moe import check_config
 
             check_config(self)
@@ -170,12 +175,45 @@ class TransformerConfig:
     routed_scaling_factor: float = 1.0
     experts_held: int = 0
     expert_offset: int = 0
+    #: The WINDOW stack of ``models/window_moe.py``: a layer is described by its
+    #: mixer AND its MLP, one list each, as the published configs name them.
+    #: ``layer_types``: ``"full_attention"`` or ``"sliding_attention"`` (a query
+    #: at ``i`` admits the keys of ``(i - sliding_window, i]``);
+    #: ``mlp_layer_types`` (naming it selects this stack): ``"dense"`` (``d_ff``
+    #: wide) or ``"sparse"`` (the routed experts sized above, under a SOFTMAX
+    #: router without a selection bias).  ``n_heads`` query heads in the full
+    #: layers, ``sliding_n_heads`` in the window layers (0 = the same), KV heads
+    #: and ``head_dim`` the same in both; ``head_gate``: one sigmoid scalar a
+    #: head from the layer's normed input, on the attention output before
+    #: ``W_o``.
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    sliding_n_heads: int = 0
+    head_gate: bool = False
+    #: Rotary embedding by layer kind.  The full layers rotate the first
+    #: ``partial_rotary_factor`` of each head at ``rope_theta``, under YaRN
+    #: where ``rope_yarn_factor`` > 0 (frequencies blended between
+    #: ``rope_theta`` and ``rope_theta`` stretched by the factor, by the linear
+    #: ramp between the correction dims of ``rope_yarn_beta_fast`` /
+    #: ``_beta_slow`` at ``rope_yarn_original_max`` positions; cos and sin times
+    #: ``rope_attention_factor``).  The window layers rotate the whole head at
+    #: ``sliding_rope_theta``, plainly.
+    partial_rotary_factor: float = 1.0
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
+    sliding_rope_theta: float = 10000.0
 
     @property
     def stack(self) -> str:
-        """Whose programs serve this configuration: ``"latent"``
-        (``models/latent_moe.py``), ``"hybrid"`` (``models/hybrid.py``) or
-        ``"uniform"`` (``models/decode.py``'s own)."""
+        """Whose programs serve this configuration: ``"window"``
+        (``models/window_moe.py``), ``"latent"`` (``models/latent_moe.py``),
+        ``"hybrid"`` (``models/hybrid.py``) or ``"uniform"``
+        (``models/decode.py``'s own)."""
+        if self.mlp_layer_types is not None:
+            return "window"
         if self.kv_lora_rank:
             return "latent"
         return "uniform" if self.layer_types is None else "hybrid"
@@ -185,8 +223,9 @@ class TransformerConfig:
 
     @property
     def n_kv_layers(self) -> int:
-        """Layers that keep KV in the paged pool: the full-attention ones."""
-        if self.stack != "hybrid":
+        """Layers that keep KV in the paged pool: the full-attention ones (a
+        linear or a window layer keeps fixed-size rows a sequence instead)."""
+        if self.stack not in ("hybrid", "window"):
             return self.n_layers
         return sum(1 for t in self.layer_types if t == "full_attention")
 
@@ -198,7 +237,7 @@ class TransformerConfig:
         every program to get there (chipless v5e compile: 6 pool-sized copies
         in a decode step, 8 in a chunk; none at 32).  The dense model's pool
         is as it was."""
-        if self.stack != "hybrid":
+        if self.stack not in ("hybrid", "window"):
             return self.kv_heads
         return -(-self.kv_heads // 8) * 8
 
@@ -220,9 +259,9 @@ class TransformerConfig:
 def stack_module(cfg: TransformerConfig):
     """The model file of a configuration whose ``stack`` is not ``"uniform"``:
     its ``init_params``, ``n_params`` and paged programs."""
-    from polyaxon_tpu.models import hybrid, latent_moe
+    from polyaxon_tpu.models import hybrid, latent_moe, window_moe
 
-    return latent_moe if cfg.stack == "latent" else hybrid
+    return {"latent": latent_moe, "hybrid": hybrid, "window": window_moe}[cfg.stack]
 
 
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -441,6 +480,10 @@ def forward(
     each shard's global token positions.
     """
     c = cfg
+    if c.stack == "window":
+        from polyaxon_tpu.models.window_moe import WindowStackError
+
+        raise WindowStackError("forward")
     if c.layer_types is not None:
         raise NotImplementedError(
             "a model with a layer pattern has no training forward: it is "

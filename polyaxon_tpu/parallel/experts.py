@@ -28,6 +28,28 @@ import jax.numpy as jnp
 from jax import lax
 
 
+#: What a program's expert layers routed in one call, summed over the layers:
+#: int32 ``[rows routed (token x choice; padding and idle lanes left out), rows
+#: that fell to experts held here, the busiest expert's rows, experts that had
+#: a row]``.  The third value the paged programs of a model with routed experts
+#: return; the engine reads it with the call's result and ``/v1/stats`` carries
+#: the totals under these names.
+COUNT_NAMES = ("moe_rows_routed", "moe_rows_held", "moe_rows_busiest", "moe_experts_hit")
+
+
+def _router_logits(h, router):
+    return jnp.einsum(
+        "nd,de->ne", h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+
+
+def _gates(s, chosen, scale: float):
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    gates = scale * w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), gates
+
+
 def route(h, router, bias, k: int, scale: float):
     """The sigmoid router with a selection bias (``noaux_tc``, one group).
 
@@ -36,15 +58,20 @@ def route(h, router, bias, k: int, scale: float):
     must fall as it does in the plain reference.  The bias picks, the unbiased
     score weighs: returns ``(chosen [N, k] int32, gates [N, k] float32)`` with
     ``gates = scale * s / (sum of the chosen s + 1e-20)``."""
-    logits = jnp.einsum(
-        "nd,de->ne", h.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    )
-    s = jax.nn.sigmoid(logits)
+    s = jax.nn.sigmoid(_router_logits(h, router))
     _, chosen = lax.top_k(s + bias.astype(jnp.float32), k)
-    w = jnp.take_along_axis(s, chosen, axis=-1)
-    gates = scale * w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), gates
+    return _gates(s, chosen, scale)
+
+
+def route_softmax(h, router, k: int, scale: float):
+    """The softmax router (the Qwen-MoE family's, ``norm_topk_prob``): ``s =
+    softmax(h W_r)`` over ALL experts, ``chosen = top_k(s)``, no selection
+    bias.  The contract is :func:`route`'s: float32 at ``highest``, returns
+    ``(chosen [N, k] int32, gates [N, k] float32)`` with ``gates = scale * s /
+    (sum of the chosen s + 1e-20)``."""
+    s = jax.nn.softmax(_router_logits(h, router), axis=-1)
+    _, chosen = lax.top_k(s, k)
+    return _gates(s, chosen, scale)
 
 
 def experts_mlp(

@@ -97,11 +97,15 @@ def _pick_block(t: int, want: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False):
+def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False, window=None):
     # With ``offset`` the first ref is a prefetched scalar: how far the first
     # query row lies past the first key in a causal block (see flash_block_fwd).
+    # With ``window`` a row admits only the last ``window`` keys up to itself,
+    # and a second prefetched scalar names the first key that is there at all.
     if offset:
         q0_ref, *refs = refs
+    if window is not None:
+        k0_ref, *refs = refs
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -117,6 +121,9 @@ def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False):
 
     # Causal: blocks entirely above the diagonal contribute nothing.
     run = (ki * bk <= row0 + bq - 1) if causal else (ki >= 0)
+    if window is not None:
+        # ... and so do blocks entirely behind the first row's window.
+        run = run & (ki * bk + bk - 1 > row0 - window) & (ki * bk + bk - 1 >= k0_ref[0])
 
     @pl.when(run)
     def _compute():
@@ -130,6 +137,8 @@ def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False):
             rows = row0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             keep = rows >= cols
+            if window is not None:
+                keep = keep & (rows - cols < window) & (cols >= k0_ref[0])
             s = jnp.where(keep, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -173,6 +182,9 @@ def flash_block_fwd(
     interpret: bool | None = None,
     q_offset: jax.Array | None = None,
     name: str = "flash_fwd",
+    window: int | None = None,
+    k_first: jax.Array | int = 0,
+    group: int = 1,
 ) -> Tuple[jax.Array, jax.Array]:
     """One attention block: returns ``(o, lse)`` with o float32-normalized.
 
@@ -182,7 +194,17 @@ def flash_block_fwd(
     position, of either sign), a query that stands that far past the keys: a
     key tile of a longer sequence.  A row that admits no key of the block
     comes back as ``o = 0``, ``lse = -inf``, which :func:`_merge` folds away.
+    ``window`` (causal only) bounds the keys from below too: the query at
+    position ``i`` admits the keys of ``(i - window, i]``, and a key tile wholly
+    behind a query tile's window is skipped, not masked; ``k_first`` (a traced
+    int32 scalar) is the first key of the block that is there at all, the ones
+    before it stale rows of a ring not yet filled.  With ``group`` > 1 k
+    and v hold ``BH // group`` heads and query head ``b`` reads head ``b //
+    group`` of them where it lies: grouped-query attention without a copy of
+    the keys at the query heads' count.
     """
+    if window is not None and not causal:
+        raise ValueError("a window bounds a causal block")
     if interpret is None:
         interpret = pallas_interpret()
     BH, Tq, d = q.shape
@@ -193,16 +215,24 @@ def flash_block_fwd(
     from jax.experimental.pallas import tpu as pltpu
 
     offset = q_offset is not None
+    scalars = [q_offset] if offset else []
+    if window is not None:
+        scalars.append(k_first)
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset,
+        window=window,
     )
+    if group == 1:
+        kv_at = lambda b, i, j, *_: (b, j, 0)  # noqa: E731
+    else:
+        kv_at = lambda b, i, j, *_: (b // group, j, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=int(offset),
+        num_scalar_prefetch=len(scalars),
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), kv_at),
+            pl.BlockSpec((1, bk, dv), kv_at),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda b, i, j, *_: (b, i, 0)),
@@ -223,7 +253,7 @@ def flash_block_fwd(
         ],
         interpret=interpret,
         name=name,
-    )(*([jnp.reshape(q_offset, (1,)).astype(jnp.int32)] if offset else []), q, k, v)
+    )(*(jnp.reshape(x, (1,)).astype(jnp.int32) for x in scalars), q, k, v)
     return o, lse_pad[:, :, 0]
 
 

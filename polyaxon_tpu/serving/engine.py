@@ -458,6 +458,13 @@ class ServingEngine:
         keeps one latent row a token a layer in the pool and takes no option of
         its own; it refuses ``spec_decode`` and a ``mesh`` with
         :class:`~polyaxon_tpu.models.latent_moe.LatentStackError`.
+        A model of window and full attention layers (``cfg.mlp_layer_types``,
+        ``models/window_moe.py``) keeps a ring of ``sliding_window`` K and V
+        rows a slot for each window layer beside the blocks of its full
+        layers, snapshotted by the two options above as a hybrid model's
+        state is, and refuses ``spec_decode``, ``kv_offload``,
+        ``kv_persist_dir`` and ``mesh`` with
+        :class:`~polyaxon_tpu.models.window_moe.WindowStackError`.
     stats : a stats backend receiving latency histograms
         (``serving.queue_wait_s`` / ``serving.ttft_s`` /
         ``serving.decode_step_s`` / ``serving.batch_occupancy``) and
@@ -511,6 +518,7 @@ class ServingEngine:
         import jax
 
         from polyaxon_tpu.models import decode
+        from polyaxon_tpu.models.transformer import stack_module
 
         if max_len is None:
             max_len = cfg.max_seq
@@ -557,9 +565,12 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = 1 + self.slots * self._table_width
         self.block_allocator = BlockAllocator(num_blocks)
-        # Recurrent state (a model with a layer pattern): per-slot rows in
-        # the pool, and the snapshot store that prefix reuse resumes from.
-        self._recurrent = cfg.stack == "hybrid"
+        # Per-sequence state of fixed size (a hybrid model's recurrent rows, a
+        # window layer's ring of K and V): per-slot rows in the pool, and the
+        # snapshot store that prefix reuse resumes from.  The model file says
+        # whether it keeps any (``init_rec_state``).
+        self._stack = stack_module(cfg) if cfg.stack != "uniform" else None
+        self._recurrent = hasattr(self._stack, "init_rec_state")
         self._snaps: Optional[StateSnapshots] = None
         self._snap_store: Optional[Any] = None
         self._snap_every = 0
@@ -610,7 +621,7 @@ class ServingEngine:
             cfg, live_end, self._table_width, self.block_size
         )
         # Routed experts: each program returns what it routed in the call
-        # beside its result (``latent_moe.COUNT_NAMES``).  The scheduler keeps
+        # beside its result (``experts.COUNT_NAMES``).  The scheduler keeps
         # the counts of the calls it has dispatched and fetches them in the
         # blocking read it makes anyway (``_host_read``).
         self._moe = bool(cfg.n_routed_experts)
@@ -621,22 +632,30 @@ class ServingEngine:
         #: layers), rows held, experts that had a row]``.
         self._moe_shapes: Dict[int, List[int]] = {}
         if self._moe:
-            from polyaxon_tpu.models import latent_moe
+            from polyaxon_tpu.parallel.experts import COUNT_NAMES
 
-            self._moe_totals = dict.fromkeys(latent_moe.COUNT_NAMES, 0)
-            self._moe_layers = sum(
-                n for kind, n in latent_moe.runs(cfg) if kind == latent_moe.EXPERTS
-            )
+            self._moe_totals = dict.fromkeys(COUNT_NAMES, 0)
+            self._moe_layers = self._stack.expert_layers(cfg)
         self._state_row_bytes = 0
         if self._recurrent:
-            from polyaxon_tpu.models import hybrid
-
-            # The recurrent rows ride the pool dict: the paged programs
+            # The per-slot rows ride the pool dict: the paged programs
             # carry and donate one tree, KV blocks and per-slot state alike.
-            self._pool.update(hybrid.init_rec_state(cfg, self.slots))
-            self._state_row_bytes = hybrid.rec_row_bytes(cfg)
+            self._pool.update(
+                self._stack.init_rec_state(cfg, self.slots, self.kv_quantize)
+            )
+            self._state_row_bytes = self._stack.rec_row_bytes(cfg, self.kv_quantize)
             if self._snaps is not None:
-                self._snap_store = hybrid.init_rec_state(cfg, self._snaps.capacity)
+                self._snap_store = self._stack.init_rec_state(
+                    cfg, self._snaps.capacity, self.kv_quantize
+                )
+        #: A model with window layers: by the rows of a chunk's shape (its
+        #: bucket, which the window kernel's name carries) ``[the kernel's
+        #: calls (one a window layer a chunk), the (query, key) pairs admitted
+        #: in them]``, counted on the host from a chunk's start and length.
+        self._window_layers = (
+            cfg.layer_types.count("sliding_attention") if cfg.stack == "window" else 0
+        )
+        self._window_shapes: Dict[int, List[int]] = {}
         #: slot -> {position: place}: snapshots of the slot's prefill in
         #: flight, attached to their chain entries when the prompt is in.
         self._pending_snaps: Dict[int, Dict[int, int]] = {}
@@ -751,10 +770,7 @@ class ServingEngine:
                     stacklevel=2,
                 )
                 self.kv_persist_dir = None
-        if self._recurrent:
-            self._refuse_what_recurrent_state_cannot_follow()
-        if cfg.stack == "latent":
-            self._refuse_what_the_latent_stack_cannot_follow()
+        self._refuse_what_the_stack_cannot_follow()
         self._kv_persist_interval_s = knob_float(
             "POLYAXON_TPU_KV_PERSIST_INTERVAL_S"
         )
@@ -845,30 +861,20 @@ class ServingEngine:
         self._ledger: Optional[Any] = None
         self._occ_weighted_s = 0.0
 
-    def _refuse_what_recurrent_state_cannot_follow(self) -> None:
-        """``models/hybrid.py:REFUSED``: each option asked for raises by name
-        rather than run on the KV alone."""
-        from polyaxon_tpu.models.hybrid import RecurrentStateError
-
+    def _refuse_what_the_stack_cannot_follow(self) -> None:
+        """The model file's ``REFUSED`` (``models/hybrid.py``,
+        ``models/latent_moe.py``, ``models/window_moe.py``): each option asked
+        for raises its typed error by name, here where the engine is built,
+        rather than run on the KV blocks alone."""
+        refused = getattr(self._stack, "REFUSED", {})
         for option, asked in (
             ("spec_decode", self.spec_decode),
             ("kv_offload", self.kv_offload),
             ("kv_persist_dir", self.kv_persist_dir),
             ("mesh", self._mesh is not None),
         ):
-            if asked:
-                raise RecurrentStateError(option)
-
-    def _refuse_what_the_latent_stack_cannot_follow(self) -> None:
-        """``models/latent_moe.py:REFUSED``, by name, where the engine is built."""
-        from polyaxon_tpu.models.latent_moe import LatentStackError
-
-        for option, asked in (
-            ("spec_decode", self.spec_decode),
-            ("mesh", self._mesh is not None),
-        ):
-            if asked:
-                raise LatentStackError(option)
+            if asked and option in refused:
+                raise self._stack.refusal(option)
 
     def _note_counts(self, tokens: int, counts: Sequence[Any]) -> None:
         """Keep what a dispatched program routed (a device array, not read
@@ -978,21 +984,21 @@ class ServingEngine:
     def _get_snapshot(self):
         import jax
 
-        from polyaxon_tpu.models.hybrid import take_snapshot
-
         if self._snapshot_fn is None:
             # The STORE is donated; the pool is only read (and is donated
             # to the next chunk or step, which the runtime orders after).
-            self._snapshot_fn = jax.jit(take_snapshot, donate_argnums=(0,))
+            self._snapshot_fn = jax.jit(
+                self._stack.take_snapshot, donate_argnums=(0,)
+            )
         return self._snapshot_fn
 
     def _get_restore(self):
         import jax
 
-        from polyaxon_tpu.models.hybrid import restore_snapshot
-
         if self._restore_fn is None:
-            self._restore_fn = jax.jit(restore_snapshot, donate_argnums=(0,))
+            self._restore_fn = jax.jit(
+                self._stack.restore_snapshot, donate_argnums=(0,)
+            )
         return self._restore_fn
 
     def _get_export(self):
@@ -1596,6 +1602,15 @@ class ServingEngine:
             preloaded = self._kv_preloaded_blocks
             persisted = self._kv_persisted_blocks
             state_restores = self._n_state_restores
+            window = (
+                {"window_pairs": sum(v[1] for v in self._window_shapes.values()),
+                 "window_chunk_calls": sum(v[0] for v in self._window_shapes.values()),
+                 "window_call_shapes": {
+                     str(rows): {"calls": v[0], "pairs": v[1]}
+                     for rows, v in sorted(self._window_shapes.items())
+                 }}
+                if self._window_layers else {}
+            )
             moe = dict(self._moe_totals)
             if self._moe:
                 moe["moe_call_shapes"] = {
@@ -1663,6 +1678,11 @@ class ServingEngine:
             # where it stops at its own live end (the latent stack's tiles).
             "prefill_keys_attended": keys_attended,
             "prefill_keys_table": keys_table,
+            # A model with window layers (absent for one without): the (query,
+            # key) pairs the window kernel admitted over its calls, one call a
+            # window layer a chunk; ``window_call_shapes`` the same by the rows
+            # of a chunk's shape, which the kernel's name ends in.
+            **window,
             "requests_cancelled": cancelled,
             # Recurrent state (all 0 for a dense model): snapshots taken,
             # snapshots restored into a slot, snapshots lost (the store's
@@ -2227,6 +2247,12 @@ class ServingEngine:
         with self._stats_lock:
             self._n_keys_attended += self._chunk_keys(job.next_pos)
             self._n_keys_table += self._table_width * bs
+            if self._window_layers:
+                shape = self._window_shapes.setdefault(c_pad, [0, 0])
+                shape[0] += self._window_layers
+                shape[1] += self._window_layers * self._stack.chunk_window_pairs(
+                    self.cfg, job.next_pos - n, n
+                )
         if self._snaps is not None and job.next_pos % self._snap_every == 0:
             self._snapshot_state(slot, job.next_pos)
         done = job.next_pos >= t

@@ -151,3 +151,79 @@ def test_a_key_tile_under_a_query_offset_is_the_masked_softmax_and_merges(offset
         want_o, want_lse = plain(k, v, offset)
         assert float(jnp.max(jnp.abs(both[0] - want_o))) < 1e-5
         assert float(jnp.max(jnp.abs(jnp.where(dead, 0.0, both[1] - want_lse)))) < 1e-5
+
+
+def _windowed(q, k, v, off, window, k_first, group):
+    """The plain rule the window bound is held to: masked ``jax.numpy`` softmax,
+    row ``i`` (at ``i + off`` on the key axis) admits column ``j`` if ``j <= i +
+    off``, ``i + off - j < window`` and ``j >= k_first``; query head ``h`` reads
+    KV head ``h // group``."""
+    Tq, Tk = q.shape[1], k.shape[1]
+    rows = jnp.arange(Tq)[:, None] + off
+    cols = jnp.arange(Tk)[None]
+    keep = ((rows >= cols) & (rows - cols < window) & (cols >= k_first))[None]
+    k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
+    s = jnp.where(keep, jnp.einsum("hqd,hkd->hqk", q, k) * q.shape[-1] ** -0.5, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.where(keep, jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0)[..., None]), 0.0)
+    return jnp.einsum("hqk,hkd->hqd", p, v), lse
+
+
+@pytest.mark.parametrize("offset,window,k_first,group", [
+    (16, 16, 0, 1),     # a ring of 16 before the queries: the window chunk's own layout
+    (16, 16, 9, 3),     # ... the ring not yet filled, three query heads a KV head
+    (0, 24, 0, 1),      # no keys before the first query
+    (36, 8, 0, 2),      # a window narrower than a key tile: whole tiles skipped
+    (-8, 16, 0, 1),     # the first rows stand before every key
+    (100, 16, 0, 1),    # every key behind every row's window
+], ids=["ring", "ring-unfilled-grouped", "no-ring", "narrow", "negative", "all-behind"])
+def test_the_window_bound_is_the_masked_softmax(offset, window, k_first, group):
+    """``flash_block_fwd(window=...)`` against a masked ``jax.numpy`` attention,
+    offsets of either sign; a row no key is admitted to comes back ``o = 0``,
+    ``lse = -inf``."""
+    import numpy as np
+
+    rng = np.random.default_rng(offset % 5 + window)
+    Hkv, Tq, Tk, d = 2, 32, 64, 16
+    q = jnp.asarray(rng.normal(size=(Hkv * group, Tq, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(Hkv, Tk, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(Hkv, Tk, d)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        o, lse = flash.flash_block_fwd(
+            q, k, v, causal=True, sm_scale=d**-0.5, block_q=16, block_k=8,
+            q_offset=jnp.int32(offset), window=window, k_first=jnp.int32(k_first), group=group)
+        want_o, want_lse = _windowed(q, k, v, offset, window, k_first, group)
+    dead = np.asarray(jnp.isneginf(want_lse))
+    assert dead.any() == (offset in (-8, 100)) and dead.all() == (offset == 100)
+    assert (np.asarray(jnp.isneginf(lse)) == dead).all() and not np.asarray(o)[dead].any()
+    assert float(jnp.max(jnp.abs(o - want_o))) < 1e-5
+    assert float(jnp.max(jnp.abs(jnp.where(dead, 0.0, lse - want_lse)))) < 1e-5
+
+
+def test_a_window_needs_a_causal_block():
+    x = jnp.zeros((1, 8, 8), jnp.float32)
+    with pytest.raises(ValueError, match="causal"):
+        flash.flash_block_fwd(x, x, x, causal=False, sm_scale=1.0, window=8)
+
+
+@pytest.mark.parametrize("form,digest", [
+    ("causal", "72f2901fb47edc85"), ("full", "532d62079b40a46f"), ("offset", "145977fb146be4ac"),
+])
+def test_without_a_window_the_forward_block_is_the_operations_it_was(form, digest):
+    """The training step's caller (``causal``, ``full``) and the latent chunk's
+    (``offset``) pass no window: their ``pallas_call``, kernel body, grid and
+    block maps, prints as it did before the window was written (digests of the
+    jaxpr's text taken at the parent commit, addresses struck out)."""
+    import hashlib
+    import re
+
+    x = jax.ShapeDtypeStruct((4, 256, 64), jnp.bfloat16)
+    kw = dict(sm_scale=0.125, block_q=128, block_k=128, interpret=False, causal=form != "full")
+    if form == "offset":
+        text = str(jax.make_jaxpr(
+            lambda q, k, v, o: flash.flash_block_fwd(q, k, v, q_offset=o, **kw)
+        )(x, x, x, jax.ShapeDtypeStruct((), jnp.int32)))
+    else:
+        text = str(jax.make_jaxpr(lambda q, k, v: flash.flash_block_fwd(q, k, v, **kw))(x, x, x))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyaxon_tpu.models import TransformerConfig, decode, hybrid, init_params
+from polyaxon_tpu.models import TransformerConfig, decode, init_params
+from polyaxon_tpu.models.transformer import stack_module
 from polyaxon_tpu.serving import ServingEngine
 
 DENSE = dict(vocab_size=256, d_model=64, n_layers=3, n_heads=4, head_dim=16, d_ff=128,
@@ -35,9 +36,19 @@ LATENT = dict(vocab_size=256, d_model=64, n_layers=3, n_heads=4, head_dim=16, d_
               v_head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
               moe_intermediate_size=32, n_shared_experts=1, routed_scaling_factor=2.5,
               experts_held=8, expert_offset=4)
-SIZES = {"dense": DENSE, "hybrid": HYBRID, "latent": LATENT}
+WINDOWED = dict(vocab_size=256, d_model=64, n_layers=5, n_heads=4, head_dim=16, d_ff=128,
+                n_kv_heads=2, rope_theta=500000.0,
+                layer_types=("full_attention",) + ("sliding_attention",) * 3 + ("full_attention",),
+                mlp_layer_types=("dense",) + ("sparse",) * 4, sliding_window=16,
+                sliding_n_heads=6, head_gate=True, partial_rotary_factor=0.5,
+                rope_yarn_factor=128.0, rope_yarn_original_max=512, rope_attention_factor=1.485,
+                n_routed_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+                n_shared_experts=1, routed_scaling_factor=2.5, experts_held=8, expert_offset=4)
+SIZES = {"dense": DENSE, "hybrid": HYBRID, "latent": LATENT, "window": WINDOWED}
 BS, W, SLOTS, CHUNK = 8, 16, 3, 32
-KINDS = ["dense", "hybrid", "latent"]
+KINDS = ["dense", "hybrid", "latent", "window"]
+#: The stacks that keep per-slot rows beside the blocks and snapshot them.
+PER_SLOT = ("hybrid", "window")
 
 #: What ``serving_params`` must leave in ``param_dtype``: the norm scales, and
 #: what the hybrid programs read in float32.
@@ -50,6 +61,8 @@ KEPT = {
     # the latent stack's norms, and the router, which chooses in float32
     "latent": {"final_norm", "block.attn_norm", "block.mlp_norm", "block.q_norm",
                "block.kv_norm", "block.experts.router", "block.experts.router_bias"},
+    # the window stack's norms and its router
+    "window": {"final_norm", "block.attn_norm", "block.mlp_norm", "block.experts.router"},
 }
 
 
@@ -79,8 +92,8 @@ def model(request):
 
 def _pool(cfg):
     pool = decode.init_block_pool(cfg, 1 + SLOTS * W, BS)
-    if cfg.stack == "hybrid":
-        pool.update(hybrid.init_rec_state(cfg, SLOTS))
+    if cfg.stack in PER_SLOT:
+        pool.update(stack_module(cfg).init_rec_state(cfg, SLOTS))
     return pool
 
 
@@ -88,7 +101,7 @@ def _through_the_programs(cfg, params, tokens, n_prompt, slot=1):
     """Prefill ``tokens[:n_prompt]`` in chunks of ``CHUNK`` (the last one
     padded), decode the rest a token a step beside two inactive lanes, then
     (dense only) verify three rows.  Returns every logits array and the pool."""
-    recurrent = cfg.stack == "hybrid"
+    recurrent = cfg.stack in PER_SLOT
     chunk = jax.jit(partial(decode.paged_prefill_chunk, cfg=cfg))
     step = jax.jit(partial(decode.paged_decode_step, cfg=cfg))
     pool = _pool(cfg)
@@ -153,7 +166,7 @@ def test_an_engine_serves_the_tokens_and_leaves_the_pool_the_float32_tree_gave(m
     tree back in place of its cast one, which is the engine of before PR 30."""
     kind, cfg, params = model
     kw = dict(slots=SLOTS, block_size=BS, num_blocks=1 + 64, prefill_chunk=CHUNK, warmup=False)
-    if kind == "hybrid":
+    if kind in PER_SLOT:
         kw.update(state_snapshot_every=32, state_snapshots=8)
     ours, before = ServingEngine(params, cfg, **kw), ServingEngine(params, cfg, **kw)
     assert ours.weight_dtype == "bfloat16"
@@ -167,7 +180,7 @@ def test_an_engine_serves_the_tokens_and_leaves_the_pool_the_float32_tree_gave(m
         for key in ("prefix_cache_hits", "state_restores", "state_snapshots", "prefix_cache_misses"):
             assert a[key] == b[key]
         assert a["prefix_cache_hits"] > 0
-        assert (a["state_restores"] >= 1) == (kind == "hybrid")
+        assert (a["state_restores"] >= 1) == (kind in PER_SLOT)
     finally:
         ours.stop(), before.stop()
     assert set(ours._pool) == set(before._pool)
