@@ -36,6 +36,7 @@ from polyaxon_tpu.models.transformer import (
     forward,
     stack_module,
 )
+from polyaxon_tpu.parallel import flash
 
 
 def init_cache(
@@ -581,6 +582,26 @@ def chunk_keys_attended(
     return -(-live_end // tile) * tile
 
 
+def step_keys_attended(
+    cfg: TransformerConfig,
+    live_ends,
+    table_width: int,
+    block_size: int,
+    kv_dtype: Optional[str] = None,
+) -> int:
+    """Key positions ONE decode step attends over its active lanes, whose keys
+    number ``live_ends`` (each lane's position + 1), by the program that serves
+    ``cfg``: the latent stack and the window stack's full layers read each
+    lane's pages by whole compute blocks up to the one that holds its live end
+    (``flash.paged_step_attend``); the dense and the hybrid stack, and any
+    stack over an int8 pool, gather the whole table a lane.  The host's count
+    for ``/v1/stats``, beside :func:`chunk_keys_attended`."""
+    if cfg.stack not in ("latent", "window") or kv_dtype is not None:
+        return len(live_ends) * table_width * block_size
+    block = flash.step_block_pages(table_width, block_size) * block_size
+    return sum(-(-int(end) // block) * block for end in live_ends)
+
+
 def _walk_table_tiles(table, block_size, live_end, turn, carry):
     """Fold ``turn(carry, blocks [tile blocks], first key position) -> carry``
     over the key tiles of one sequence's ``table [W]``, from the first tile to
@@ -642,17 +663,6 @@ def _latent_gather(pool, layer_idx, table, dtype, used):
     pool's pad sliced off."""
     got = _pool_gather(pool, "c", layer_idx, table, dtype)
     return got.reshape(*table.shape[:-1], -1, got.shape[-1])[..., :used]
-
-
-def _latent_through_table(pool, layer_idx, row, tables, write_blk, write_off, dtype):
-    """:func:`_kv_through_table` for a decode step over a latent pool: one
-    leaf, ``c``, whose row is all a token keeps.  ``row [S, 1, width]``, one
-    address a lane, ``tables [S, W]``.  Returns ``(pool, rows [S, W * bs,
-    width])``: a step still gathers the whole table width (ROADMAP S1).  A
-    prompt chunk does not: it appends, then walks its table by tiles
-    (:func:`_walk_table_tiles`, ``latent_moe._chunk_mixer``)."""
-    pool = _latent_append(pool, layer_idx, row[:, 0], write_blk, write_off)
-    return pool, _latent_gather(pool, layer_idx, tables, dtype, row.shape[-1])
 
 
 def _kv_leaves(pool: Dict[str, jax.Array]) -> Dict[str, jax.Array]:

@@ -28,9 +28,12 @@ gathered and up-projected to keys and values (fewer multiply-adds a key where
 there are many queries) and attended in the flash forward kernel, whose scores
 stay in VMEM, under a running ``(o, lse)`` (``mla.chunk_attend``,
 ``_chunk_mixer``); nothing has the table's width.  A decode step folds
-``W_kvb`` into the query and the output and attends over the rows themselves,
-gathered over the whole table width (``mla.step_attend``, the absorbed form: no
-per-head keys for a table's width of rows).  Rotary pairs are split by halves
+``W_kvb`` into the query and the output and attends over the rows themselves
+(``mla.step_attend``, the absorbed form: no per-head keys), read where they
+lie: one kernel copies each lane's pages up to the one that holds its position
+(``flash.paged_step_attend``, device operation ``paged_step_attend``; an int8
+pool's rows are gathered over the whole table width and attended by
+``_attend_absorbed``, the plain rule).  Rotary pairs are split by halves
 (``transformer._rope``); no YaRN factor.
 
 **The expert MLP** (``parallel/experts.py``): a float32 sigmoid router over
@@ -454,18 +457,37 @@ def _chunk_mixer(cfg, qpos, table, write_blk, write_off, live_end):
     return attend
 
 
-def _step_mixer(cfg, positions, tables, write_blk, write_off, mask):
-    """A decode step's attention mixer: every lane's row written and its
-    table's rows gathered, then attended in the absorbed form."""
+def _step_mixer(cfg, positions, tables, write_blk, write_off, pos):
+    """A decode step's attention mixer ``(h [S, 1, D], layer, layer index,
+    pool) -> (output, pool)`` in the absorbed form (``W_kvb`` folded into the
+    query and the output, the rows themselves keys and values).  Every lane's
+    row is written; then, by what the pool holds: rows in the compute dtype are
+    read where they lie, ``[q_c | q_rope]`` against each lane's pages up to the
+    one that holds ``pos`` (``flash.paged_step_attend``: nothing has the
+    table's width); int8 rows under a scale a row are gathered over the whole
+    table and attended by :func:`_attend_absorbed`, the plain rule."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
 
     def attend(h, layer, li, pool):
+        dt = h.dtype
         q_nope, q_rope = _queries(h, layer, positions, cfg)
         row = _latent_row(h, layer, positions, cfg)
-        pool, rows = decode._latent_through_table(
-            pool, li, row, tables, write_blk, write_off, h.dtype
-        )
+        pool = decode._latent_append(pool, li, row[:, 0], write_blk, write_off)
         with jax.named_scope("mla.step_attend"):
-            attn = _attend_absorbed(q_nope, q_rope, rows, mask, layer, cfg)
+            if decode.is_quantized_pool(pool):
+                rows = decode._latent_gather(pool, li, tables, dt, row.shape[-1])
+                mask = (jnp.arange(rows.shape[1])[None, :] <= pos[:, None])[:, None, None, :]
+                attn = _attend_absorbed(q_nope, q_rope, rows, mask, layer, cfg)
+            else:
+                wkv_b = decode._wdq(layer["wkv_b"], dt)
+                q_c = jnp.einsum("bqhd,rhd->bqhr", q_nope, wkv_b[..., :dn])
+                q = jnp.concatenate([q_c, q_rope], axis=-1)  # [S, 1, H, row]: one KV head, H its group
+                q = jnp.pad(q, ((0, 0),) * 3 + ((0, pool["c"].shape[-1] - q.shape[-1]),))
+                o_c = flash.paged_step_attend(
+                    q, pool["c"], None, li, tables, pos + 1, sm_scale=scale, v_lanes=rkv
+                ).astype(dt)
+                attn = jnp.einsum("bqhr,rhd->bqhd", o_c, wkv_b[..., dn:])
         return decode._attn_out(attn, layer), pool
 
     return attend
@@ -505,10 +527,7 @@ def paged_decode_step(params, pool, tables, tokens, pos, active, cfg, qweights=N
     pos = jnp.where(active, pos, 0)
     write_blk = jnp.where(active, tables[jnp.arange(S), pos // bs], 0)
     write_off = jnp.where(active, pos % bs, 0)
-    positions = pos[:, None]
-    kpos = jnp.arange(tables.shape[1] * bs)
-    mask = (kpos[None, :] <= pos[:, None])[:, None, None, :]  # [S, 1, 1, K]
-    attend = _step_mixer(c, positions, tables, write_blk, write_off, mask)
+    attend = _step_mixer(c, pos[:, None], tables, write_blk, write_off, pos)
     x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # [S, 1, D]
     blk, unembed = _with_qweights(params, qweights)
     x, pool, counts = _run_stack(x, blk, pool, c, attend, active[:, None])

@@ -21,8 +21,11 @@ each head's output is multiplied by ``sigmoid(h W_gate)[head]`` before ``W_o``.
 
 **What a layer keeps.**  A FULL layer keeps K and V of every position in the
 paged pool, written and read in place through the block table as the dense
-model's.  A decode step gathers the table's width (``decode._kv_through_table``
-/ ``_attend``); a prompt chunk appends its rows, then walks its table by key
+model's.  A decode step appends its rows and reads each lane's pages where they
+lie, up to the one that holds the lane's position, in one kernel
+(``flash.paged_step_attend``, device operation ``paged_step_attend``; over an
+int8 pool it gathers the table's width, ``decode._kv_through_table`` /
+``_attend``); a prompt chunk appends its rows, then walks its table by key
 tiles up to the tile that holds its own last position
 (``decode._walk_table_tiles``), each tile's K and V through the flash forward
 kernel under the query's offset (device operation ``full_chunk_tile``), the
@@ -562,15 +565,32 @@ def _window_chunk_mixer(cfg, qpos, start, length, slot):
 
 
 def _full_step_mixer(cfg, positions, tables, write_blk, write_off, pos):
-    """A decode step's full-attention mixer: the dense step's, and the gate."""
+    """A decode step's full-attention mixer ``(h [S, 1, D], layer, index among
+    the full layers, pool) -> (output, pool)``, and the gate.  By what the pool
+    holds: K and V in the compute dtype are appended and read where they lie,
+    each lane's pages up to the one that holds ``pos``, a KV head's query group
+    the rows of one product (``flash.paged_step_attend``: nothing has the
+    table's width, no view of a pool leaf is formed); int8 rows
+    under their scales take the dense step's gather and ``decode._attend_paged``."""
     c = cfg
+    H, Hkv, d = heads(c, FULL), c.kv_heads, c.head_dim
+    group = H // Hkv
 
     def attend(h, layer, li, pool):
         q, k, v = _qkv_rotated(h, layer, positions, c, FULL)
-        pool, ck, cv = decode._kv_through_table(
-            pool, li, k, v, tables, write_blk, write_off, h.dtype
-        )
-        attn = decode._attend_paged(q, ck, cv, pos, heads(c, FULL) // c.kv_heads)
+        if decode.is_quantized_pool(pool):
+            pool, ck, cv = decode._kv_through_table(
+                pool, li, k, v, tables, write_blk, write_off, h.dtype
+            )
+            return _gated_out(decode._attend_paged(q, ck, cv, pos, group), h, layer), pool
+        pool = decode._kv_append(pool, li, k[:, 0], v[:, 0], write_blk, write_off)
+        with jax.named_scope("window_moe.full_step_attend"):
+            qg = q[:, 0].reshape(-1, Hkv, group, d)
+            qg = jnp.pad(qg, ((0, 0), (0, 0), (0, -group % 8), (0, 0)))  # whole sublane tiles a head
+            o = flash.paged_step_attend(
+                qg, pool["k"], pool["v"], li, tables, pos + 1, sm_scale=d**-0.5
+            )
+        attn = o[:, :, :group].astype(h.dtype).reshape(-1, 1, H, d)
         return _gated_out(attn, h, layer), pool
 
     return attend

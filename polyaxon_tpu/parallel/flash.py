@@ -258,6 +258,221 @@ def flash_block_fwd(
 
 
 # ---------------------------------------------------------------------------
+# A decode step through its block table: q[S,Hkv,G,dk] over the pool's pages
+# ---------------------------------------------------------------------------
+
+#: Keys of one compute block of :func:`paged_step_attend`'s walk through a
+#: block table.  A constant of the shapes, as ``decode.TILE_KEYS`` is (half of
+#: it: K and V of two blocks in flight are 4 MB at 8 KV heads of 128, a quarter
+#: of the VMEM a kernel may use).
+STEP_BLOCK_KEYS = 512
+
+
+def step_block_pages(table_width: int, block_size: int) -> int:
+    """Pages of one compute block: ``STEP_BLOCK_KEYS`` keys, or the whole of a
+    narrower table."""
+    return min(max(STEP_BLOCK_KEYS // block_size, 1), table_width)
+
+
+def _paged_step_kernel(
+    layer_ref, tables_ref, live_ref, q_ref, *refs,
+    sm_scale, pages, page, width, heads, dv, shared,
+):
+    # One grid turn is one slot.  The slot's compute blocks (``pages`` pages of
+    # ``page`` rows) are copied out of the pool, which stays where it is, one
+    # block ahead of the one attended; the trip count is the slot's own.
+    if shared:  # the value is the first lanes of the key's row: one copy
+        k_hbm, o_ref, k_buf, sems, m_scr, l_scr, acc = refs
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc = refs
+    s = pl.program_id(0)
+    layer = layer_ref[0]
+    live = live_ref[s]
+    bk = pages * page
+    blocks = (live + bk - 1) // bk
+    from jax.experimental.pallas import tpu as pltpu
+
+    def copies(i, buf):
+        out = []
+        for p in range(pages):
+            at = tables_ref[s * width + i * pages + p]
+            rows = pl.ds(p * page, page)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[layer, at], k_buf.at[buf, rows], sems.at[0, buf]))
+            if not shared:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[layer, at], v_buf.at[buf, rows], sems.at[1, buf]))
+        return out
+
+    def head_rows(ref, buf, first, count):
+        """Rows ``[bk, d]`` of heads ``first .. first + count - 1`` of a block in
+        ``ref [2, bk, Hp, d]``: a head's rows lie ``Hp`` apart.  Two heads of a
+        16-bit dtype share a 32-bit word a lane (the even head its low half):
+        one strided read of words, then each half widened where it stands."""
+        flat = ref.at[buf].reshape(bk * ref.shape[2], ref.shape[3])
+        if count == 1:
+            return [flat[pl.ds(first, bk, stride=ref.shape[2])]]
+        words = flat.bitcast(jnp.uint32)[pl.ds(first // 2, bk, stride=ref.shape[2] // 2)]
+        halves = (words << 16, words & jnp.uint32(0xFFFF0000))
+        return [pltpu.bitcast(w, jnp.float32).astype(ref.dtype) for w in halves]
+
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc[...] = jnp.zeros_like(acc)
+    for c in copies(0, 0):
+        c.start()
+
+    def fold(i, buf, masked):
+        # ``masked``: the block that holds the live end.  Rows past it are
+        # another sequence's, or never written: out of the scores AND out of
+        # the values (0 x NaN is NaN).
+        if masked:
+            col_ok = i * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1) < live
+            row_ok = i * bk + lax.broadcasted_iota(jnp.int32, (bk, 1), 0) < live
+
+        def one(h, k, v):
+            sc = lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * sm_scale
+            if masked:
+                sc = jnp.where(col_ok, sc, _NEG_BIG)
+                v = jnp.where(row_ok, v, jnp.zeros_like(v))
+            m_prev = m_scr[h, :, :1]
+            m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)  # m_prev=-inf -> 0
+            p = jnp.exp(sc - m_cur)
+            if masked:
+                p = jnp.where(col_ok, p, 0.0)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv = lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            acc[h] = acc[h] * alpha + pv
+            m_scr[h] = jnp.broadcast_to(m_cur, m_scr.shape[1:])
+
+        if shared:  # a row is one head: its key the row, its value the first lanes
+            one(0, k_buf[buf], k_buf[buf, :, :dv])
+            return
+        count = 4 // jnp.dtype(k_buf.dtype).itemsize  # heads a 32-bit word holds
+        for h0 in range(0, heads, count):
+            ks, vs = head_rows(k_buf, buf, h0, count), head_rows(v_buf, buf, h0, count)
+            for h in range(h0, min(h0 + count, heads)):
+                one(h, ks[h - h0], vs[h - h0])
+
+    def body(i, _):
+        buf = i % 2
+
+        @pl.when(i + 1 < blocks)
+        def _ahead():
+            for c in copies(i + 1, 1 - buf):
+                c.start()
+
+        for c in copies(i, buf):
+            c.wait()
+
+        @pl.when(i + 1 < blocks)
+        def _whole():
+            fold(i, buf, masked=False)
+
+        @pl.when(i + 1 == blocks)
+        def _last():
+            fold(i, buf, masked=True)
+
+        return ()
+
+    lax.fori_loop(0, blocks, body, ())
+    l = l_scr[:, :, :1]
+    o_ref[0] = (acc[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def paged_step_attend(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array | None,
+    layer_idx: jax.Array,
+    tables: jax.Array,
+    live: jax.Array,
+    *,
+    sm_scale: float,
+    v_lanes: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """One query token a slot against the keys its block table names, read out
+    of the pool's pages where they lie: a decode step's attention with nothing
+    of the table's width formed.
+
+    ``k_pages`` / ``v_pages``: pool leaves WHOLE and as the pool holds them;
+    only layer ``layer_idx`` (a traced scalar) is read, through page copies the
+    kernel issues itself, so no layer's slice is cut out of the carried pool
+    and no view of it is formed (the TPU compiler answers a reshape of a pool
+    leaf with a copy of it).  Two page forms.  ``[layers, blocks, page rows,
+    Hp, d]``, K and V leaves: KV head ``h`` of a row, ``Hp`` heads of which the
+    first ``Hkv`` are the model's, is read by a strided load from the copied
+    page.  ``[layers, blocks, page rows, lanes]`` with ``v_pages`` None: a row
+    is ONE head whose key is the whole row and whose value is its first
+    ``v_lanes`` lanes, read from the same copy in VMEM (a latent pool's ``[c |
+    k_rope | pad]``).
+
+    q: ``[S, Hkv, G, d]`` (``d = lanes`` for the second form), the ``G`` query
+    heads of a KV head its rows.  tables: ``[S, W]`` int32 block ids; live:
+    ``[S]`` int32, the keys slot ``s`` has (its position + 1, >= 1): the slot
+    walks ``ceil(live / STEP_BLOCK_KEYS)`` compute blocks, a traced trip
+    count, so one compilation serves every state; the last block's rows past
+    ``live`` are masked out of scores and values; table entries past them are
+    read as block ids (any valid id does, the trash block as well) and never
+    attended.  Scores, maximum and sum are float32, the probabilities go to
+    the pool's dtype for the second product, as :func:`flash_block_fwd` has
+    it.  Returns ``[S, Hkv, G, v_lanes or d]`` float32, normalised.
+    """
+    if interpret is None:
+        interpret = pallas_interpret()
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, heads, G, d = q.shape
+    shared = v_pages is None
+    page, row = k_pages.shape[2], k_pages.shape[3:]
+    dv = v_lanes if shared and v_lanes is not None else d
+    fits = (row == (d,) and heads == 1 and dv <= d) if shared else (
+        len(row) == 2 and row[1] == d and heads <= row[0] and v_pages.shape == k_pages.shape)
+    if not fits:
+        raise ValueError(
+            f"queries {q.shape} do not fit pages {k_pages.shape}"
+            + ("" if shared else f" / {v_pages.shape}") + f" (values of {dv})"
+        )
+    W = tables.shape[1]
+    pages = step_block_pages(W, page)
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -W % pages)))
+    kernel = functools.partial(
+        _paged_step_kernel, sm_scale=sm_scale, pages=pages, page=page, width=tables.shape[1],
+        heads=heads, dv=dv, shared=shared,
+    )
+    leaves = [k_pages] if shared else [k_pages, v_pages]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, heads, G, d), lambda s, *_: (s, 0, 0, 0))]
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in leaves],
+        out_specs=pl.BlockSpec((1, heads, G, dv), lambda s, *_: (s, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, pages * page, *row), leaf.dtype) for leaf in leaves] + [
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((heads, G, 128), jnp.float32),
+            pltpu.VMEM((heads, G, 128), jnp.float32),
+            pltpu.VMEM((heads, G, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, heads, G, dv), jnp.float32),
+        interpret=interpret,
+        name="paged_step_attend",
+    )(
+        jnp.reshape(layer_idx, (1,)).astype(jnp.int32), tables.reshape(-1),
+        live.astype(jnp.int32), q.astype(k_pages.dtype), *leaves,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Backward block kernels (flash-2 style, using saved lse and delta)
 # ---------------------------------------------------------------------------
 

@@ -620,6 +620,10 @@ class ServingEngine:
         self._chunk_keys = lambda live_end: decode.chunk_keys_attended(
             cfg, live_end, self._table_width, self.block_size
         )
+        #: Key positions a decode step attends over lanes with ``live_ends`` keys.
+        self._step_keys = lambda live_ends: decode.step_keys_attended(
+            cfg, live_ends, self._table_width, self.block_size, self.kv_quantize
+        )
         # Routed experts: each program returns what it routed in the call
         # beside its result (``experts.COUNT_NAMES``).  The scheduler keeps
         # the counts of the calls it has dispatched and fetches them in the
@@ -831,6 +835,9 @@ class ServingEngine:
         # Key positions the prompt chunks attended, and what their tables held.
         self._n_keys_attended = 0
         self._n_keys_table = 0
+        # The same for the decode steps' active lanes.
+        self._n_step_keys_attended = 0
+        self._n_step_keys_table = 0
         self._backlog_chunks = 0
         self._prefill_jobs = 0
         self._window: "deque[tuple]" = deque()  # (t, n_tokens)
@@ -1595,6 +1602,8 @@ class ServingEngine:
             cow = self._n_cow
             keys_attended = self._n_keys_attended
             keys_table = self._n_keys_table
+            step_keys_attended = self._n_step_keys_attended
+            step_keys_table = self._n_step_keys_table
             cancelled = self._n_cancelled
             shed = self._n_shed
             spilled = self._n_spilled_blocks
@@ -1678,6 +1687,12 @@ class ServingEngine:
             # where it stops at its own live end (the latent stack's tiles).
             "prefill_keys_attended": keys_attended,
             "prefill_keys_table": keys_table,
+            # The same for the decode steps, over their active lanes: fewer
+            # where a step reads each lane's pages up to its own live end (the
+            # latent stack, the window stack's full layers), equal where it
+            # gathers the table's width a lane.
+            "decode_keys_attended": step_keys_attended,
+            "decode_keys_table": step_keys_table,
             # A model with window layers (absent for one without): the (query,
             # key) pairs the window kernel admitted over its calls, one call a
             # window layer a chunk; ``window_call_shapes`` the same by the rows
@@ -2557,6 +2572,9 @@ class ServingEngine:
         self._key, sub = jax.random.split(self._key)
         tables = np.where(self._tables >= 0, self._tables, 0).astype(np.int32)
         n_live = int(self._active.sum())
+        with self._stats_lock:
+            self._n_step_keys_attended += self._step_keys(self._pos[self._active] + 1)
+            self._n_step_keys_table += n_live * self._table_width * bs
         emitted = 0
         if drafts:
             emitted = self._verify_once(drafts, tables, sub)

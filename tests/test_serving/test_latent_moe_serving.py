@@ -284,6 +284,51 @@ def test_the_chunk_program_forms_nothing_of_the_tables_width_and_compiles_once(t
     assert f"{cfg.n_heads}x{C}x{TILE}" not in shapes(on_tpu)
 
 
+def _step_program_checks(cfg, params, pool_of, monkeypatch, wide=200):
+    """What both stacks' decode step is held to (``test_window_moe_serving.py``
+    calls this too): over a float pool no array of the program has the table's
+    ``wide * BS`` positions as a dimension, on the CPU or lowered for the TPU,
+    where the kernel is a named device operation; one compilation serves two
+    different ``pos`` vectors; over an int8 pool the program still gathers the
+    table's width, and its logits stay close to the float pool's."""
+    from jax import export
+
+    from polyaxon_tpu.parallel import flash
+
+    positions = wide * BS  # 1,600: a number no other axis has
+    fn = jax.jit(partial(decode.paged_decode_step, cfg=cfg))
+    tables = jnp.asarray(1 + np.arange(SLOTS * wide).reshape(SLOTS, wide) % wide, jnp.int32)
+    toks, on = jnp.asarray([3, 5, 7], jnp.int32), jnp.asarray([True, True, False])
+    args = (params, pool_of(None, wide), tables, toks)
+
+    def dims(text):
+        return set(re.findall(r"tensor<([0-9x]+)x[a-z]", text))
+
+    def widths(text):
+        return [s for s in dims(text) if str(positions) in s.split("x")]
+
+    assert str(wide) in "x".join(dims(fn.lower(*args, toks * 0 + 9, on).as_text())).split("x")
+    assert not widths(fn.lower(*args, toks * 0 + 9, on).as_text())
+    out = [fn(*args, jnp.asarray(pos, jnp.int32), on)[0] for pos in ([9, 700, 0], [1500, 64, 0])]
+    assert fn._cache_size() == 1 and float(jnp.max(jnp.abs(out[0] - out[1]))) > 0
+    int8 = fn.lower(params, pool_of("int8", wide), tables, toks, toks * 0 + 9, on).as_text()
+    assert widths(int8) and "paged_step_attend" not in int8
+    close = fn(params, pool_of("int8", wide), tables, toks, jnp.asarray([9, 700, 0], jnp.int32), on)[0]
+    assert float(jnp.max(jnp.abs(close[:2] - out[0][:2]))) < 0.05
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    on_tpu = export.export(
+        jax.jit(partial(decode.paged_decode_step, cfg=cfg)), platforms=["tpu"]
+    )(*args, toks * 0 + 9, on).mlir_module()
+    assert 'kernel_name = "paged_step_attend"' in on_tpu and not widths(on_tpu)
+
+
+def test_the_step_program_forms_nothing_of_the_tables_width_and_compiles_once(tiny, monkeypatch):
+    cfg, params, _ = tiny
+    _step_program_checks(
+        cfg, params, lambda kv_dtype, wide: decode.init_block_pool(cfg, 1 + wide, BS, kv_dtype),
+        monkeypatch)
+
+
 def _every_expert_over_every_row(h, chosen, gates, valid, wi, wg, wd, offset):
     """The plain form: each held expert's MLP over all rows, weighted by its
     gate, which is zero where it was not chosen."""
@@ -470,6 +515,43 @@ def test_stats_count_the_keys_a_chunk_attended_against_its_tables_width(tiny, st
     assert stats["prefill_keys_table"] == 2 * W_TILES * BS  # chunks of 32 and 8 rows
     attended = 2 * TILE if stack == "latent" else 2 * W_TILES * BS
     assert stats["prefill_keys_attended"] == attended <= stats["prefill_keys_table"]
+
+
+@pytest.mark.parametrize("stack", ["latent", "window", "dense", "hybrid"])
+def test_stats_count_the_keys_a_step_attended_against_its_tables_width(tiny, stack):
+    """A table six compute blocks wide and a reply decoded inside its first:
+    the latent stack's steps and the window stack's full layers attend one
+    block an active lane, a dense or a hybrid model's the whole table."""
+    from polyaxon_tpu.parallel import flash
+
+    seq, kw = W_TILES * BS, {}
+    if stack == "latent":
+        cfg, params = make_cfg(TINY, seq=seq), tiny[1]
+    elif stack == "dense":
+        cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+                                d_ff=64, n_kv_heads=2, max_seq=seq, dtype=jnp.float32)
+    else:
+        from tests.test_serving import test_hybrid_serving, test_window_moe_serving
+
+        other = test_window_moe_serving if stack == "window" else test_hybrid_serving
+        cfg = other.make_cfg(other.TINY, seq=seq)
+        kw = {"state_snapshot_every": 32, "state_snapshots": 8}
+    if stack != "latent":
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    engine = _engine(cfg, params, slots=2, **kw)
+    try:
+        assert engine.stats()["decode_keys_table"] == engine.stats()["decode_keys_attended"] == 0
+        engine.generate(np.random.default_rng(6).integers(0, 64, 40).tolist(), 5, timeout=300)
+        stats = engine.stats()
+    finally:
+        engine.stop()
+    steps = stats["decode_keys_table"] // (W_TILES * BS)  # one active lane a step
+    assert steps >= 3 and stats["decode_keys_table"] == steps * W_TILES * BS
+    if stack in ("latent", "window"):
+        assert stats["decode_keys_attended"] == steps * flash.STEP_BLOCK_KEYS
+        assert stats["decode_keys_attended"] / stats["decode_keys_table"] < 0.2
+    else:
+        assert stats["decode_keys_attended"] == stats["decode_keys_table"]
 
 
 def test_a_dense_model_reports_row_bytes_and_no_expert_counters():

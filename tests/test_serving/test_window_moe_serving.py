@@ -358,6 +358,20 @@ def test_the_chunk_program_forms_no_scores_and_compiles_once(tiny, monkeypatch):
         assert scores not in found, scores
 
 
+def test_the_step_program_forms_nothing_of_the_tables_width_and_compiles_once(tiny, monkeypatch):
+    """The full layers' step through the kernel: see
+    ``test_latent_moe_serving._step_program_checks``."""
+    from tests.test_serving.test_latent_moe_serving import _step_program_checks
+
+    cfg, params, _ = tiny
+
+    def pool_of(kv_dtype, wide):
+        return {**decode.init_block_pool(cfg, 1 + wide, BS, kv_dtype),
+                **window_moe.init_rec_state(cfg, SLOTS, kv_dtype)}
+
+    _step_program_checks(cfg, params, pool_of, monkeypatch)
+
+
 # -- refusals ---------------------------------------------------------------------
 
 
