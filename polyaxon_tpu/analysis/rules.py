@@ -861,6 +861,10 @@ _SPAN_NAMES = {
     "serving.loop.decode_host", "serving.loop.device_wait",
     "serving.loop.emit", "serving.loop.bookkeeping", "serving.loop.other",
     "serving.loop.idle",
+    # ... the laps of a decode step's host side (serving/engine.py:STEP_LAPS;
+    # /v1/stats decode_host_<lap>_s, xplane rows between the phases')
+    "serving.loop.decode_host.inputs", "serving.loop.decode_host.key",
+    "serving.loop.decode_host.upload", "serving.loop.decode_host.dispatch",
     # ... and the two more of an engine whose model has recurrent layers
     # (serving/engine.py:STATE_PHASES)
     "serving.state.snapshot", "serving.state.restore",

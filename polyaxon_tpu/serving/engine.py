@@ -69,7 +69,7 @@ from polyaxon_tpu.serving.paging import (
 from polyaxon_tpu.stats import MemoryStats
 from polyaxon_tpu.stats.tsdb import RatioWindow
 from polyaxon_tpu.tracking.flightrec import get_progress
-from polyaxon_tpu.tracking.trace import TraceContext, get_tracer
+from polyaxon_tpu.tracking.trace import PhaseSnapshot, TraceContext, get_tracer
 
 
 #: The engine loop's phases (``tracking/trace.py:PhaseClock``): every instant
@@ -96,6 +96,20 @@ LOOP_PHASES = (
 PH_SNAPSHOT = "serving.state.snapshot"  # copying a slot's recurrent rows into the store
 PH_RESTORE = "serving.state.restore"  # copying a snapshot into an admitted slot's rows
 STATE_PHASES = (PH_SNAPSHOT, PH_RESTORE)
+#: The laps of a decode step's host side (``PhaseClock.lap``): parts of
+#: ``serving.loop.decode_host``'s seconds, in the order a step runs them.
+#: ``/v1/stats``: ``decode_host_<lap>_s``.
+LAP_INPUTS = "inputs"  # the fault loop's own time, participants, tables, key counts
+LAP_KEY = "key"  # jax.random.split: a dispatched program of its own every step
+LAP_UPLOAD = "upload"  # the jnp.asarray of the step's arguments
+LAP_DISPATCH = "dispatch"  # the step's (or the verify) call until it returns
+STEP_LAPS = (LAP_INPUTS, LAP_KEY, LAP_UPLOAD, LAP_DISPATCH)
+#: The phases the engine's thread does not compute in: the wall seconds of
+#: the others less the thread's CPU seconds outside these is ``host_off_cpu_s``.
+WAIT_PHASES = (PH_DEVICE_WAIT, PH_IDLE)
+#: A blocking read back sooner than this found its result ready: the device
+#: had finished before the host asked (``device_reads_ready``).
+READ_READY_S = 100e-6
 
 
 def _in_phase(phase: str):
@@ -112,9 +126,15 @@ def _in_phase(phase: str):
     return wrap
 
 
+def _phase_key(phase: str) -> str:
+    """``serving.paging.match`` -> ``paging_match``; a lap's
+    ``serving.loop.decode_host.key`` -> ``decode_host_key``."""
+    return phase[len("serving."):].replace("loop.", "").replace(".", "_")
+
+
 def _stats_key(phase: str) -> str:
     """``serving.paging.match`` -> ``loop_paging_match``."""
-    return "loop_" + phase[len("serving."):].replace("loop.", "").replace(".", "_")
+    return "loop_" + _phase_key(phase)
 
 
 class EngineDrainingError(RuntimeError):
@@ -860,8 +880,13 @@ class ServingEngine:
         # wall time goes, as whole-run counters in stats() and, during an
         # xplane capture, as annotations in the device trace.
         self._clock = get_tracer().phase_clock(
-            LOOP_PHASES + (STATE_PHASES if self._recurrent else ()), PH_OTHER
+            LOOP_PHASES + (STATE_PHASES if self._recurrent else ()),
+            PH_OTHER,
+            laps={PH_DECODE_HOST: STEP_LAPS},
+            waits=WAIT_PHASES,
         )
+        self._n_device_reads = 0  # the loop's blocking reads
+        self._n_device_reads_ready = 0  # ... back within READ_READY_S
         # Decode-side utilization ledger (armed in start()): the seconds
         # of prefill and decode ticks weighted by slot occupancy — the
         # serving analogue of train-side goodput/MFU.
@@ -891,16 +916,31 @@ class ServingEngine:
                 (tokens * self.cfg.num_experts_per_tok, counts[0])
             )
 
+    def _read_returned(self, t0: float) -> None:
+        """The blocking read begun at ``t0`` (the clock's reading at the
+        transition into ``serving.loop.device_wait``) is back: nothing is
+        dispatched any more."""
+        ready = time.perf_counter() - t0 < READ_READY_S
+        self._clock.drained()
+        with self._stats_lock:
+            self._n_device_reads += 1
+            self._n_device_reads_ready += ready
+
+    @_in_phase(PH_DEVICE_WAIT)
     def _host_read(self, result: Any) -> np.ndarray:
         """The loop's blocking read of a program's result.  The expert counts
         of the calls dispatched since the last one come to the host in the
         same fetch: they are ready when ``result`` is."""
+        t0 = self._clock.t
         if not self._moe_pending:
-            return np.asarray(result)
+            result = np.asarray(result)
+            self._read_returned(t0)
+            return result
         import jax
 
         pending, self._moe_pending = self._moe_pending, []
         result, counted = jax.device_get((result, [c for _, c in pending]))
+        self._read_returned(t0)
         with self._stats_lock:
             for (rows, _), got in zip(pending, counted):
                 got = dict(zip(self._moe_totals, map(int, got)))
@@ -1042,16 +1082,19 @@ class ServingEngine:
 
         fn = self._get_export()
         pending = [fn(self._pool, jnp.int32(b)) for b in blocks]
-        return [
+        payloads = [
             {name: np.asarray(leaf) for name, leaf in tree.items()}
             for tree in pending
         ]
+        self._clock.drained()
+        return payloads
 
     def _import_block(self, block: int, data: Dict[str, np.ndarray]) -> None:
         """Host→device copy of one payload into pool block ``block``."""
         import jax.numpy as jnp
 
         self._pool = self._get_import()(self._pool, data, jnp.int32(block))
+        self._clock.dispatched()
         with self._stats_lock:
             self._n_restored_blocks += 1
 
@@ -1554,7 +1597,9 @@ class ServingEngine:
         """Blocking convenience: submit + wait."""
         return self.submit(prompt, max_new_tokens, temperature).wait(timeout)
 
-    def _utilization_snapshot(self, clock: Optional[tuple] = None) -> Dict[str, float]:
+    def _utilization_snapshot(
+        self, clock: Optional[PhaseSnapshot] = None
+    ) -> Dict[str, float]:
         """Host-loop utilization from the phase clock: the share of the
         scheduler loop's wall time it was not idle (HOST-loop busy, which
         is not device busy: it holds paging and bookkeeping, and the device
@@ -1562,7 +1607,7 @@ class ServingEngine:
         occupancy of its prefill and decode ticks over that busy time, and
         their product — the serving equivalent of the train ledger's
         goodput × MFU."""
-        wall, seconds, _ = clock or self._clock.snapshot()
+        wall, seconds = (clock or self._clock.snapshot())[:2]
         with self._stats_lock:
             occw = self._occ_weighted_s
         busy = wall - seconds[PH_IDLE]
@@ -1575,16 +1620,36 @@ class ServingEngine:
         }
 
     @staticmethod
-    def _loop_snapshot(clock: tuple) -> Dict[str, Any]:
-        """The phase clock as flat monotone counters: ``loop_wall_s`` and,
-        per phase, its seconds and the times it was entered.  Differences
-        of two ``/v1/stats`` reads split the window between them."""
-        wall, seconds, counts = clock
-        out: Dict[str, Any] = {"loop_wall_s": round(wall, 6)}
-        for phase in seconds:
+    def _loop_snapshot(clock: PhaseSnapshot, cpu: Optional[float]) -> Dict[str, Any]:
+        """The phase clock as flat monotone counters.  ``loop_wall_s`` and,
+        per phase, its seconds and the times it was entered: the ``loop_*_s``
+        keys alone sum to ``loop_wall_s``.  Beside them, each a part of those
+        seconds: ``uncovered_<phase>_s`` (the phase's seconds with nothing
+        dispatched to the device; idling is no work, so it has none) and
+        their sum ``uncovered_s``, the laps ``decode_host_<lap>_s``, and
+        ``host_cpu_s`` / ``host_off_cpu_s`` (``cpu``: the thread's CPU
+        seconds outside ``WAIT_PHASES``, and the wall seconds there less them:
+        the thread waiting for a CPU or the interpreter lock; absent where
+        the platform has no per-thread CPU clock).  Differences of two
+        ``/v1/stats`` reads split the window between them."""
+        out: Dict[str, Any] = {"loop_wall_s": round(clock.wall, 6)}
+        for phase, seconds in clock.seconds.items():
             key = _stats_key(phase)
-            out[key + "_s"] = round(seconds[phase], 6)
-            out[key + "_n"] = counts[phase]
+            out[key + "_s"] = round(seconds, 6)
+            out[key + "_n"] = clock.counts[phase]
+        uncovered = {
+            f"uncovered_{_phase_key(phase)}_s": round(seconds, 6)
+            for phase, seconds in clock.uncovered.items()
+            if phase != PH_IDLE
+        }
+        out["uncovered_s"] = round(sum(uncovered.values()), 6)
+        out.update(uncovered)
+        for lap, seconds in clock.laps.items():
+            out[_phase_key(lap) + "_s"] = round(seconds, 6)
+        if cpu is not None:
+            host = clock.wall - sum(clock.seconds[p] for p in WAIT_PHASES)
+            out["host_cpu_s"] = round(cpu, 6)
+            out["host_off_cpu_s"] = round(host - cpu, 6)
         return out
 
     def _paging_snapshot(self) -> Dict[str, Any]:
@@ -1753,9 +1818,13 @@ class ServingEngine:
         led.maybe_flush()
 
     def stats(self) -> Dict[str, Any]:
+        # CPU first, so the snapshot's wall seconds hold the CPU seconds: the
+        # other way round the gap between the two reads would count against
+        # the time the thread waited for a CPU.
+        cpu = self._clock.cpu_seconds()
         clock = self._clock.snapshot()
         util = self._utilization_snapshot(clock)
-        loop = self._loop_snapshot(clock)
+        loop = self._loop_snapshot(clock, cpu)
         paging = self._paging_snapshot()
         spec = self._spec_snapshot()
         with self._stats_lock:
@@ -1789,6 +1858,8 @@ class ServingEngine:
                 "requests_finished": self._n_finished,
                 "tokens_generated": self._n_tokens,
                 "decode_steps": self._n_steps,
+                "device_reads": self._n_device_reads,
+                "device_reads_ready": self._n_device_reads_ready,
                 "tokens_per_s": round(tps, 1),
                 "max_len": self.max_len,
                 "trace_exemplars": self._exemplars.snapshot(),
@@ -2166,6 +2237,7 @@ class ServingEngine:
         self._pool = self._get_restore()(
             self._pool, self._snap_store, jnp.int32(place), jnp.int32(slot)
         )
+        self._clock.dispatched()
         with self._stats_lock:
             self._n_state_restores += 1
 
@@ -2183,6 +2255,7 @@ class ServingEngine:
         self._snap_store = self._get_snapshot()(
             self._snap_store, self._pool, jnp.int32(slot), jnp.int32(place)
         )
+        self._clock.dispatched()
         self._pending_snaps.setdefault(slot, {})[pos] = place
 
     def _release_pending_snapshots(self, slot: int) -> None:
@@ -2222,6 +2295,7 @@ class ServingEngine:
             self._pool = self._get_copy()(
                 self._pool, jnp.int32(shared), jnp.int32(fresh)
             )
+            clock.dispatched()
             with clock.phase(PH_ALLOC):
                 self.block_allocator.decref(shared)
             self._tables[slot, bi] = fresh
@@ -2257,6 +2331,7 @@ class ServingEngine:
             jnp.int32(n),
             *((jnp.int32(slot),) if self._recurrent else ()),
         )
+        clock.dispatched()
         self._note_counts(c_pad, counts)
         job.next_pos += n
         with self._stats_lock:
@@ -2289,8 +2364,7 @@ class ServingEngine:
             self._prefill.popleft()
             # Every chunk before this one was only dispatched: here
             # the host waits for the device to finish the prompt.
-            with clock.phase(PH_DEVICE_WAIT):
-                logits = self._host_read(logits)
+            logits = self._host_read(logits)
             self._finalize_prefill(job, logits)
         with bookkeeping:
             self._record_gauges()
@@ -2547,6 +2621,7 @@ class ServingEngine:
 
         clock = self._clock
         t0 = clock.t  # the transition into this phase
+        clock.lap(LAP_INPUTS)
         bs = self.block_size
         # Block-boundary faults: a slot whose next write crosses into an
         # unallocated block needs one now — or parks until the pool can
@@ -2560,39 +2635,45 @@ class ServingEngine:
                     self._park(slot)
                 else:
                     self._tables[slot, bi] = fresh
+                clock.lap(LAP_INPUTS)  # the fault was paging's; the loop goes on
         if not self._active.any():
             return
-        drafts = self._collect_drafts() if self.spec_decode else {}
+        drafts: Dict[int, List[int]] = {}
+        if self.spec_decode:
+            drafts = self._collect_drafts()
+            clock.lap(LAP_INPUTS)
         participants = [
             self._slot_req[int(s)]
             for s in np.nonzero(self._active)[0]
             if self._slot_req[int(s)] is not None
             and self._slot_req[int(s)].trace is not None
         ]
-        self._key, sub = jax.random.split(self._key)
         tables = np.where(self._tables >= 0, self._tables, 0).astype(np.int32)
         n_live = int(self._active.sum())
         with self._stats_lock:
             self._n_step_keys_attended += self._step_keys(self._pos[self._active] + 1)
             self._n_step_keys_table += n_live * self._table_width * bs
+        clock.lap(LAP_KEY)
+        self._key, sub = jax.random.split(self._key)
         emitted = 0
         if drafts:
             emitted = self._verify_once(drafts, tables, sub)
         else:
-            toks, self._pool, *counts = self._step_fn(
-                self._params,
-                self._pool,
+            clock.lap(LAP_UPLOAD)
+            inputs = (
                 jnp.asarray(tables),
                 jnp.asarray(self._tok),
                 jnp.asarray(self._pos),
                 jnp.asarray(self._active),
                 jnp.asarray(self._temps),
-                sub,
-                self._qweights,
             )
+            clock.lap(LAP_DISPATCH)
+            toks, self._pool, *counts = self._step_fn(
+                self._params, self._pool, *inputs, sub, self._qweights
+            )
+            clock.dispatched()
             self._note_counts(self.slots, counts)
-            with clock.phase(PH_DEVICE_WAIT):
-                toks = self._host_read(toks)  # host sync — the loop's one device read
+            toks = self._host_read(toks)  # host sync — the loop's one device read
             with clock.phase(PH_EMIT):
                 for slot in np.nonzero(self._active)[0]:
                     slot = int(slot)
@@ -2680,6 +2761,8 @@ class ServingEngine:
         emitted."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        clock.lap(LAP_INPUTS)
         width = self._width_for(max(len(p) for p in drafts.values()))
         tok_in = np.zeros((self.slots, width), np.int32)
         tok_in[:, 0] = self._tok
@@ -2687,22 +2770,24 @@ class ServingEngine:
         for slot, prop in drafts.items():
             tok_in[slot, 1 : 1 + len(prop)] = prop
             n_tok[slot] = 1 + len(prop)
-        out, n_emit, self._pool = self._get_verify(width)(
-            self._params,
-            self._pool,
+        clock.lap(LAP_UPLOAD)
+        inputs = (
             jnp.asarray(tables),
             jnp.asarray(tok_in),
             jnp.asarray(self._pos),
             jnp.asarray(n_tok),
             jnp.asarray(self._active),
             jnp.asarray(self._temps),
-            sub,
-            self._qweights,
         )
-        clock = self._clock
-        with clock.phase(PH_DEVICE_WAIT):
+        clock.lap(LAP_DISPATCH)
+        out, n_emit, self._pool = self._get_verify(width)(
+            self._params, self._pool, *inputs, sub, self._qweights
+        )
+        clock.dispatched()
+        with clock.phase(PH_DEVICE_WAIT) as t0:
             out = np.asarray(out)  # host sync — the loop's one device read
             n_emit = np.asarray(n_emit)
+            self._read_returned(t0)
         emitted = 0
         n_proposed = n_accepted = 0
         observe = getattr(self.stats_registry, "observe", None)

@@ -23,7 +23,9 @@ is the worker-side half of that answer:
 - :class:`PhaseClock` (``tracer.phase_clock(...)``) is the accounting of ONE
   thread's loop: an exclusive clock over a small fixed catalog of phases,
   one ``perf_counter`` read per transition, plain seconds and counts that
-  sum to the loop's wall time.  The serving engine's loop runs on one.
+  sum to the loop's wall time; on the same readings, the seconds a phase ran
+  with nothing dispatched to the device, the named laps of a phase, and the
+  thread's CPU seconds.  The serving engine's loop runs on one.
 - ``tracer.profiler_hook`` puts both on the device profiler's clock: the
   capture agent sets it to ``jax.profiler.TraceAnnotation`` while an xplane
   trace is on (this module never imports jax), and every span and phase is
@@ -60,9 +62,9 @@ from typing import (
     Iterable,
     List,
     Mapping,
+    NamedTuple,
     Optional,
     Sequence,
-    Tuple,
 )
 
 from polyaxon_tpu.conf.knobs import knob_float
@@ -70,6 +72,7 @@ from polyaxon_tpu.conf.knobs import knob_float
 __all__ = [
     "Tracer",
     "PhaseClock",
+    "PhaseSnapshot",
     "get_tracer",
     "configure",
     "chrome_trace",
@@ -269,15 +272,28 @@ class _Span:
         return False
 
 
+#: Of the visits to a phase the thread waits in, the one in this many whose
+#: CPU seconds the owner reads (two system calls).
+_WAIT_SAMPLE = 64
+
+
+def _thread_cpu_clock() -> Optional[int]:
+    """The calling thread's CPU-time clock, which any thread may read with
+    ``time.clock_gettime``; ``None`` where the platform has none."""
+    get = getattr(time, "pthread_getcpuclockid", None)
+    return None if get is None else get(threading.get_ident())
+
+
 class _Phase:
     """The reusable ``with`` target of one phase of a :class:`PhaseClock`;
     ``as`` gives the ``perf_counter`` reading of the transition into it."""
 
-    __slots__ = ("_clock", "name")
+    __slots__ = ("_clock", "name", "_waits")
 
-    def __init__(self, clock: "PhaseClock", name: str) -> None:
+    def __init__(self, clock: "PhaseClock", name: str, waits: bool) -> None:
         self._clock = clock
         self.name = name
+        self._waits = waits  # the thread does not compute in this phase
 
     def __enter__(self) -> float:
         clock = self._clock
@@ -285,12 +301,17 @@ class _Phase:
         if not stack:  # clock off
             return clock.t
         clock._seq += 1
-        now = time.perf_counter()
-        clock._seconds[stack[-1]] += now - clock.t
-        clock.t = now
+        now = clock._charge()
+        clock._lap = None
         stack.append(self.name)
         clock._counts[self.name] += 1
         clock._seq += 1
+        if (
+            self._waits
+            and clock._counts[self.name] % _WAIT_SAMPLE == 1
+            and clock._cpu_clock is not None
+        ):
+            clock._wait_cpu0 = time.clock_gettime(clock._cpu_clock)
         hook = clock._tracer.profiler_hook
         if hook is not None or clock._live is not None:
             clock._mark(hook, self.name)
@@ -301,15 +322,30 @@ class _Phase:
         stack = clock._stack
         if len(stack) < 2:  # clock off: the base phase is stop()'s to close
             return False
+        if self._waits and clock._wait_cpu0 is not None:
+            sample = clock._wait_cpu[self.name]
+            sample[1] += time.clock_gettime(clock._cpu_clock) - clock._wait_cpu0
+            sample[0] += 1
+            clock._wait_cpu0 = None
         clock._seq += 1
-        now = time.perf_counter()
-        clock._seconds[stack.pop()] += now - clock.t
-        clock.t = now
+        clock._charge()
+        clock._lap = None
+        stack.pop()
         clock._seq += 1
         hook = clock._tracer.profiler_hook
         if hook is not None or clock._live is not None:
             clock._mark(hook, stack[-1])
         return False
+
+
+class PhaseSnapshot(NamedTuple):
+    """A :class:`PhaseClock` as of one instant (:meth:`PhaseClock.snapshot`)."""
+
+    wall: float  #: seconds between ``start()`` and ``stop()``, or now
+    seconds: Dict[str, float]  #: by phase; they sum to ``wall``
+    counts: Dict[str, int]  #: by phase: the times it was entered
+    uncovered: Dict[str, float]  #: by phase: its seconds with nothing dispatched
+    laps: Dict[str, float]  #: by ``<phase>.<lap>``: a part of the phase's seconds
 
 
 class PhaseClock:
@@ -328,20 +364,71 @@ class PhaseClock:
     does nothing, so code that also runs without the loop (a test calling a
     tick by hand, the drain after the thread is joined) needs no guard.
 
+    Three more accounts ride on the same readings, and none changes a
+    phase's seconds or count:
+
+    - **Coverage.**  The owner says :meth:`dispatched` when a call that put
+      a program on the device has returned and :meth:`drained` when a
+      blocking read has; every interval charged while nothing is dispatched
+      is also added to its phase's ``uncovered`` seconds.
+    - **Laps** (``laps``: phase -> the names of its laps).  ``lap(name)``
+      names the part of the open phase from now to the next lap or
+      transition; its seconds are a subset of the phase's, its annotation
+      ``<phase>.<lap>``.
+    - **CPU** (``waits``: the phases the thread does not compute in).
+      ``start()`` takes the owner's CPU-time clock and :meth:`cpu_seconds`
+      reads it, from any thread and only when asked; the owner itself reads
+      it at both edges of one visit in 64 of each of ``waits``, for what the
+      waits burn.  (A read at every edge is a system call of 25 us in a
+      serving process on the chip machine, and cost 3-4 % of the served rate
+      at two a step.)  The wall seconds of the other phases, less the CPU
+      seconds outside the waits, is time the thread waited for a CPU or for
+      the interpreter lock.
+
     Only the owning thread enters phases.  Any thread may :meth:`snapshot`:
     the owner brackets each transition, its clock read included, with two
     increments of ``_seq`` (odd while one is in flight) and the reader retries
     until it has read, its own clock read included, between two.
     """
 
-    def __init__(self, tracer: "Tracer", names: Sequence[str], base: str) -> None:
+    def __init__(
+        self,
+        tracer: "Tracer",
+        names: Sequence[str],
+        base: str,
+        laps: Optional[Mapping[str, Sequence[str]]] = None,
+        waits: Sequence[str] = (),
+    ) -> None:
         if base not in names:
             raise ValueError(f"base phase {base!r} is not in the catalog")
+        unknown = [p for p in (*(laps or ()), *waits) if p not in names]
+        if unknown:
+            raise ValueError(f"phases {unknown!r} are not in the catalog")
         self._tracer = tracer
         self.base = base
-        self._phases = {name: _Phase(self, name) for name in names}
+        self._phases = {name: _Phase(self, name, name in waits) for name in names}
         self._seconds = {name: 0.0 for name in names}
         self._counts = {name: 0 for name in names}
+        self._uncovered = {name: 0.0 for name in names}
+        #: phase -> lap -> the lap's key and annotation, ``<phase>.<lap>``
+        self._lap_names = {
+            phase: {lap: f"{phase}.{lap}" for lap in of}
+            for phase, of in (laps or {}).items()
+        }
+        self._lap_seconds = {
+            full: 0.0 for of in self._lap_names.values() for full in of.values()
+        }
+        self._lap: Optional[str] = None  # the open lap's key
+        self._covered = False  # a program is dispatched and no read has returned since
+        #: The owner's CPU-time clock between start() and stop(), its reading
+        #: at start(), and the CPU seconds of the spans already closed.
+        self._cpu_clock: Optional[int] = None
+        self._cpu0 = 0.0
+        self._cpu = 0.0
+        #: By phase of ``waits``: [the visits read at both edges, their CPU
+        #: seconds]; the reading at the entry of the one in flight, if any.
+        self._wait_cpu = {name: [0, 0.0] for name in waits}
+        self._wait_cpu0: Optional[float] = None
         self._stack: List[str] = []
         self._live: Any = None  # the open profiler annotation, if any
         self._seq = 0
@@ -358,12 +445,31 @@ class PhaseClock:
         (the wall clock is slewed; call where the loop has time to spare)."""
         self.epoch = time.time() - time.perf_counter()
 
+    def _charge(self) -> float:
+        """Read the clock and charge the interval since the last reading to
+        the open phase, to its uncovered seconds while nothing is dispatched
+        and to the open lap.  Called inside the ``_seq`` bracket, the clock
+        on."""
+        now = time.perf_counter()
+        dt = now - self.t
+        top = self._stack[-1]
+        self._seconds[top] += dt
+        if not self._covered:
+            self._uncovered[top] += dt
+        if self._lap is not None:
+            self._lap_seconds[self._lap] += dt
+        self.t = now
+        return now
+
     def start(self) -> None:
         if self._stack:
             return
         self.anchor()
         self._seq += 1
         self._started = self.t = time.perf_counter()
+        self._cpu_clock = _thread_cpu_clock()
+        if self._cpu_clock is not None:
+            self._cpu0 = time.clock_gettime(self._cpu_clock)
         self._stack.append(self.base)
         self._counts[self.base] += 1
         self._seq += 1
@@ -373,13 +479,47 @@ class PhaseClock:
         if not self._stack:
             return
         self._seq += 1
-        now = time.perf_counter()
-        self._seconds[self._stack[-1]] += now - self.t
+        now = self._charge()
+        self._lap = None
         self._wall += now - self._started
-        self.t = now
+        if self._cpu_clock is not None:
+            self._cpu += time.clock_gettime(self._cpu_clock) - self._cpu0
+            self._cpu_clock = self._wait_cpu0 = None
         del self._stack[:]
         self._seq += 1
         self._mark(None, "")
+
+    def lap(self, name: str) -> None:
+        """The open phase's lap ``name`` begins now; it ends at the next lap
+        or transition."""
+        stack = self._stack
+        if not stack:
+            return
+        full = self._lap_names[stack[-1]][name]
+        self._seq += 1
+        self._charge()
+        self._lap = full
+        self._seq += 1
+        hook = self._tracer.profiler_hook
+        if hook is not None or self._live is not None:
+            self._mark(hook, full)
+
+    def dispatched(self) -> None:
+        """The call that put a program on the device has returned."""
+        self._cover(True)
+
+    def drained(self) -> None:
+        """A blocking read has returned: whatever was dispatched before it
+        has finished (programs run in the order they were dispatched)."""
+        self._cover(False)
+
+    def _cover(self, covered: bool) -> None:
+        if covered == self._covered or not self._stack:
+            return
+        self._seq += 1
+        self._charge()
+        self._covered = covered
+        self._seq += 1
 
     def _mark(self, hook: Optional[Callable[[str], Any]], name: str) -> None:
         """Close the open annotation and, while a capture is on, open
@@ -395,15 +535,43 @@ class PhaseClock:
         except Exception:
             pass
 
-    def snapshot(self) -> Tuple[float, Dict[str, float], Dict[str, int]]:
-        """``(wall_s, seconds, counts)`` as of now: the interval in flight is
-        charged to the open phase, so the seconds sum to ``wall_s``."""
+    def cpu_seconds(self) -> Optional[float]:
+        """The owner's CPU seconds outside ``waits``, between ``start()`` and
+        ``stop()`` or now: all of them off its clock, less what each wait
+        burns by the mean of the visits read at both edges, times its
+        visits.  ``None`` where the platform has no per-thread CPU clock."""
+        if not hasattr(time, "pthread_getcpuclockid"):
+            return None
+        while True:
+            seq = self._seq
+            cpu, cpu0, clock = self._cpu, self._cpu0, self._cpu_clock
+            waits = [
+                (self._counts[name], n, burnt)
+                for name, (n, burnt) in self._wait_cpu.items()
+            ]
+            if not seq & 1 and seq == self._seq:
+                break
+            time.sleep(0)
+        if clock is not None:
+            try:
+                cpu += time.clock_gettime(clock) - cpu0
+            except OSError:  # the owner ended between the two lines above
+                pass
+        return cpu - sum(burnt / n * visits for visits, n, burnt in waits if n)
+
+    def snapshot(self) -> PhaseSnapshot:
+        """The clock as of now: the interval in flight is charged to the open
+        phase (and to its uncovered seconds and the open lap, as the next
+        reading will), so the seconds sum to ``wall``."""
         while True:
             seq = self._seq
             seconds = dict(self._seconds)
             counts = dict(self._counts)
+            uncovered = dict(self._uncovered)
+            laps = dict(self._lap_seconds)
             top = self._stack[-1:]
             t, wall, started = self.t, self._wall, self._started
+            covered, lap = self._covered, self._lap
             # Read inside the bracket: were "now" taken after it, a phase
             # the owner closed meanwhile would be charged past its end and
             # read lower in the next snapshot.
@@ -412,9 +580,14 @@ class PhaseClock:
                 break
             time.sleep(0)  # let the owner finish its transition
         if top:
-            seconds[top[0]] += now - t
+            dt = now - t
+            seconds[top[0]] += dt
+            if not covered:
+                uncovered[top[0]] += dt
+            if lap is not None:
+                laps[lap] += dt
             wall += now - started
-        return wall, seconds, counts
+        return PhaseSnapshot(wall, seconds, counts, uncovered, laps)
 
 
 class Tracer:
@@ -512,10 +685,16 @@ class Tracer:
             return _NOOP
         return _Span(self, name, attrs, trace_id=trace_id, parent_id=parent_id)
 
-    def phase_clock(self, names: Sequence[str], base: str) -> PhaseClock:
+    def phase_clock(
+        self,
+        names: Sequence[str],
+        base: str,
+        laps: Optional[Mapping[str, Sequence[str]]] = None,
+        waits: Sequence[str] = (),
+    ) -> PhaseClock:
         """A :class:`PhaseClock` over ``names`` for the calling loop's
         thread, annotated through this tracer's ``profiler_hook``."""
-        return PhaseClock(self, names, base)
+        return PhaseClock(self, names, base, laps, waits)
 
     def next_span_id(self) -> str:
         """Allocate a span id unique within (and, when a process label is

@@ -240,6 +240,17 @@ def test_stats_carry_the_state_counters_and_the_phases_still_sum(served):
     assert phases == pytest.approx(stats["loop_wall_s"], abs=1e-4)
 
 
+def test_the_state_copies_count_as_dispatched_programs_on_the_clock(served):
+    stats = served[0].stats()
+    for phase in ("state_snapshot", "state_restore"):
+        assert 0.0 <= stats[f"uncovered_{phase}_s"] <= stats[f"loop_{phase}_s"] + 1e-6
+    parts = [v for k, v in stats.items() if k.startswith("uncovered_") and k != "uncovered_s"]
+    assert len(parts) == 12 and stats["uncovered_s"] == pytest.approx(sum(parts), abs=1e-9)
+    busy = stats["loop_wall_s"] - stats["loop_idle_s"]
+    assert 0.0 < stats["uncovered_s"] <= busy + 2e-5
+    assert stats["device_reads"] == stats["loop_device_wait_n"] > 0
+
+
 def test_without_a_prefix_cache_there_is_no_store_and_a_warm_engine_compiles_nothing_later(tiny):
     cfg, params, mine = tiny
     engine = _engine(cfg, params, prefix_cache=False, warmup=True, slots=2)

@@ -495,6 +495,15 @@ def test_stats_carry_the_expert_counters_and_the_row_bytes(served):
     assert phases == pytest.approx(stats["loop_wall_s"], abs=1e-4)
 
 
+def test_the_read_that_brings_the_expert_counts_is_a_counted_read(served):
+    stats = served[0].stats()
+    assert stats["device_reads"] == stats["loop_device_wait_n"] > 0
+    assert 0 <= stats["device_reads_ready"] <= stats["device_reads"]
+    assert 0.0 < stats["uncovered_s"] <= stats["loop_wall_s"] - stats["loop_idle_s"] + 2e-5
+    laps = sum(stats[f"decode_host_{lap}_s"] for lap in ("inputs", "key", "upload", "dispatch"))
+    assert 0.0 < laps <= stats["loop_decode_host_s"] + 1e-5
+
+
 @pytest.mark.parametrize("stack", ["latent", "dense"])
 def test_stats_count_the_keys_a_chunk_attended_against_its_tables_width(tiny, stack):
     """A table three tiles wide and a prompt of two chunks inside its first tile:

@@ -17,7 +17,9 @@ import pytest
 from polyaxon_tpu.analysis.rules import _SPAN_NAMES
 from polyaxon_tpu.models import TransformerConfig, init_params
 from polyaxon_tpu.serving import ServingEngine
-from polyaxon_tpu.serving.engine import LOOP_PHASES, _stats_key
+from polyaxon_tpu.serving.engine import (
+    LOOP_PHASES, PH_DECODE_HOST, PH_IDLE, STEP_LAPS, _phase_key, _stats_key,
+)
 from polyaxon_tpu.tracking.trace import get_tracer
 
 CFG = TransformerConfig(
@@ -26,6 +28,12 @@ CFG = TransformerConfig(
 )
 PHASE_KEYS = [_stats_key(p) for p in LOOP_PHASES]
 LOOP_KEYS = ["loop_wall_s"] + [k + s for k in PHASE_KEYS for s in ("_s", "_n")]
+#: What rides on the clock beside the phases: parts of their seconds.
+UNCOVERED_KEYS = [f"uncovered_{_phase_key(p)}_s" for p in LOOP_PHASES if p != PH_IDLE]
+LAP_KEYS = [f"decode_host_{lap}_s" for lap in STEP_LAPS]
+INSTRUMENT_KEYS = (["uncovered_s"] + UNCOVERED_KEYS + LAP_KEYS
+                   + ["host_cpu_s", "host_off_cpu_s", "device_reads", "device_reads_ready"])
+READS = ("before", "first", "second", "final")
 
 
 def _counting(calls, name, fn):
@@ -99,6 +107,18 @@ def test_stats_carry_every_loop_key_and_none_shrinks(served, key):
     assert isinstance(values[-1], int if key.endswith("_n") else float)
 
 
+@pytest.mark.parametrize("key", INSTRUMENT_KEYS)
+def test_stats_carry_every_instrument_key_and_none_shrinks(served, key):
+    values = [getattr(served, name)[key] for name in READS]
+    assert values[0] == 0
+    # The two CPU figures leave out what the waits burn by an estimate (one
+    # visit in 64 is read), and the CPU backend computes on the thread that
+    # waits for it: over a few dozen reads they may shrink.
+    assert values == sorted(values) or key in ("host_cpu_s", "host_off_cpu_s")
+    assert isinstance(values[-1], int if key.startswith("device_reads") else float)
+    assert not key.startswith("loop_")  # the loop_*_s keys alone sum to the wall
+
+
 @pytest.mark.parametrize("between", [("before", "first"), ("first", "second"),
                                      ("before", "final")])
 def test_phase_seconds_sum_to_the_loop_wall_between_two_reads(served, between):
@@ -108,6 +128,42 @@ def test_phase_seconds_sum_to_the_loop_wall_between_two_reads(served, between):
     assert wall > 0
     # Each key is rounded to the microsecond on its own.
     assert phases == pytest.approx(wall, rel=0.01, abs=2e-5)
+    # ... and every key that starts with loop_ and ends with _s is one of them.
+    named = {k for k in b if k.startswith("loop_") and k.endswith("_s")}
+    assert named == {"loop_wall_s"} | {k + "_s" for k in PHASE_KEYS}
+
+
+@pytest.mark.parametrize("read", READS[1:])
+def test_uncovered_seconds_are_a_part_of_each_phase_and_of_the_busy_time(served, read):
+    stats = getattr(served, read)
+    for phase in LOOP_PHASES:
+        if phase != PH_IDLE:
+            part = stats[f"uncovered_{_phase_key(phase)}_s"]
+            assert 0.0 <= part <= stats[_stats_key(phase) + "_s"] + 1e-6
+    assert "uncovered_idle_s" not in stats  # idling is no work
+    assert stats["uncovered_s"] == pytest.approx(
+        sum(stats[k] for k in UNCOVERED_KEYS), abs=1e-9)
+    busy = stats["loop_wall_s"] - stats["loop_idle_s"]
+    assert 0.0 < stats["uncovered_s"] <= busy + 2e-5
+    # A chunk's and a step's device time lie under the blocking reads.
+    assert stats["uncovered_device_wait_s"] < 0.5 * stats["loop_device_wait_s"]
+
+
+@pytest.mark.parametrize("read", READS[1:])
+def test_the_laps_are_a_part_of_the_decode_steps_host_side(served, read):
+    stats = getattr(served, read)
+    laps = [stats[k] for k in LAP_KEYS]
+    assert all(v > 0.0 for v in laps)
+    assert sum(laps) <= stats[_stats_key(PH_DECODE_HOST) + "_s"] + 1e-5
+
+
+def test_every_blocking_read_is_counted_and_cpu_time_is_host_time(served):
+    final = served.final
+    assert final["device_reads"] == final["loop_device_wait_n"]
+    assert 0 <= final["device_reads_ready"] <= final["device_reads"]
+    host = final["loop_wall_s"] - final["loop_idle_s"] - final["loop_device_wait_s"]
+    assert 0.0 < final["host_cpu_s"] <= final["loop_wall_s"]
+    assert final["host_cpu_s"] + final["host_off_cpu_s"] == pytest.approx(host, abs=1e-5)
 
 
 @pytest.mark.parametrize("key, made", [
@@ -140,7 +196,8 @@ def test_busy_fraction_comes_from_the_same_clock(served):
 def test_annotations_during_a_capture_are_the_catalog_on_the_engine_thread(served):
     threads = {t for t, _ in served.annotated}
     names = {n for _, n in served.annotated}
+    laps = {f"{PH_DECODE_HOST}.{lap}" for lap in STEP_LAPS}
     assert threads == {"serving-engine"}
-    assert names <= set(LOOP_PHASES) <= _SPAN_NAMES
+    assert names <= set(LOOP_PHASES) | laps <= _SPAN_NAMES
     # Everything a served request passes through showed up under its own name.
-    assert names >= set(LOOP_PHASES) - {"serving.loop.idle"}
+    assert names >= (set(LOOP_PHASES) | laps) - {"serving.loop.idle"}

@@ -441,12 +441,12 @@ class TestPhaseClock:
         clock = self._clock()
         body(clock)
         # Mid-run: the open phase is charged up to now.
-        wall, seconds, seen = clock.snapshot()
+        wall, seconds, seen = clock.snapshot()[:3]
         assert seen == counts
         assert clock._stack == [BASE]  # whatever happened inside
         assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
         clock.stop()
-        wall, seconds, _ = clock.snapshot()
+        wall, seconds, _ = clock.snapshot()[:3]
         assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
         assert all(v >= 0.0 for v in seconds.values()) and wall > 0.0
         assert clock.snapshot()[0] == wall  # stopped: the wall stands still
@@ -468,8 +468,8 @@ class TestPhaseClock:
             now[0] = 109.0  # one more of A
         now[0] = 110.0  # one more of the base phase
         assert (t_a, t_b, clock.t) == (101.0, 103.0, 109.0)
-        assert clock.snapshot() == (10.0, {A: 3.0, B: 5.0, C: 0.0, BASE: 2.0},
-                                    {A: 1, B: 1, C: 0, BASE: 1})
+        assert clock.snapshot()[:3] == (10.0, {A: 3.0, B: 5.0, C: 0.0, BASE: 2.0},
+                                        {A: 1, B: 1, C: 0, BASE: 1})
         clock.stop()
         now[0] = 200.0
         assert clock.snapshot()[0] == 10.0
@@ -477,19 +477,182 @@ class TestPhaseClock:
 
     @pytest.mark.parametrize("when", ["before_start", "after_stop"])
     def test_off_the_clock_a_phase_does_nothing(self, when):
-        clock = Tracer().phase_clock([A, BASE], BASE)
+        clock = Tracer().phase_clock([A, BASE], BASE, laps={A: ["x"]}, waits=[A])
         if when == "after_stop":
             clock.start()
             clock.stop()
-        frozen = clock.snapshot()
+        frozen = clock.snapshot(), clock.cpu_seconds()
         with clock.phase(A):
+            clock.lap("x")
+            clock.dispatched()
             with clock.phase(A):
-                pass
-        assert clock.snapshot() == frozen and clock._stack == []
+                clock.drained()
+        assert (clock.snapshot(), clock.cpu_seconds()) == frozen and clock._stack == []
 
     def test_unknown_base_is_refused(self):
         with pytest.raises(ValueError, match="base phase"):
             Tracer().phase_clock([A], BASE)
+
+    @pytest.mark.parametrize("more", [{"laps": {C: ["x"]}}, {"waits": [C]}],
+                             ids=["laps", "waits"])
+    def test_laps_and_waits_of_no_phase_are_refused(self, more):
+        with pytest.raises(ValueError, match="not in the catalog"):
+            Tracer().phase_clock([A, BASE], BASE, **more)
+
+    def test_a_lap_its_phase_does_not_have_is_refused(self):
+        clock = Tracer().phase_clock([A, B, BASE], BASE, laps={A: ["x"]})
+        clock.start()
+        with clock.phase(B), pytest.raises(KeyError):
+            clock.lap("x")
+        with clock.phase(A), pytest.raises(KeyError):
+            clock.lap("y")
+        wall, seconds, _ = clock.snapshot()[:3]
+        assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+
+    @pytest.fixture
+    def fake(self, monkeypatch):
+        """The module's clocks by hand: ``now`` is ``perf_counter``, ``cpu``
+        the owner's CPU-time clock."""
+        from types import SimpleNamespace
+
+        from polyaxon_tpu.tracking import trace as trace_mod
+
+        clocks = SimpleNamespace(now=100.0, cpu=7.0, cpu_reads=0)
+
+        def clock_gettime(clock_id):
+            clocks.cpu_reads += 1
+            return clocks.cpu
+
+        monkeypatch.setattr(trace_mod, "time", SimpleNamespace(
+            perf_counter=lambda: clocks.now, time=lambda: 1e9 + clocks.now, sleep=time.sleep,
+            pthread_getcpuclockid=lambda ident: 3, clock_gettime=clock_gettime))
+        return clocks
+
+    @pytest.mark.parametrize("with_laps", [False, True])
+    def test_laps_are_parts_of_their_phase_and_move_nothing_of_its_account(
+            self, fake, with_laps):
+        clock = Tracer().phase_clock([A, B, C, BASE], BASE, laps={A: ["x", "y"]})
+        lap = clock.lap if with_laps else lambda name: None
+        clock.start()
+        fake.now = 101.0
+        with clock.phase(A):
+            fake.now = 102.0  # one second of A before any lap: unnamed
+            lap("x")
+            fake.now = 104.0  # two of x
+            lap("y")
+            fake.now = 107.0  # three of y
+            with clock.phase(B):  # a transition ends the lap
+                fake.now = 111.0
+            fake.now = 112.0  # back in A, unnamed
+            lap("x")
+            fake.now = 112.5  # half a second more of x, to A's exit
+        fake.now = 113.0
+        snap = clock.snapshot()
+        assert snap[:3] == (13.0, {A: 7.5, B: 4.0, C: 0.0, BASE: 1.5},
+                            {A: 1, B: 1, C: 0, BASE: 1})
+        assert snap.laps == ({f"{A}.x": 2.5, f"{A}.y": 3.0} if with_laps
+                             else {f"{A}.x": 0.0, f"{A}.y": 0.0})
+        assert sum(snap.laps.values()) <= snap.seconds[A]
+        with clock.phase(A):
+            lap("y")
+            fake.now = 114.0
+            # Mid-lap: the interval in flight is the lap's too.
+            assert clock.snapshot().laps[f"{A}.y"] == (4.0 if with_laps else 0.0)
+            assert clock.snapshot().seconds[A] == 8.5
+        clock.stop()
+
+    def test_uncovered_seconds_stop_between_dispatched_and_drained(self, fake):
+        clock = Tracer().phase_clock([A, B, BASE], BASE)
+        clock.start()
+        fake.now = 101.0  # nothing dispatched yet: the base phase's second is uncovered
+        with clock.phase(A):
+            fake.now = 103.0  # two uncovered seconds of A
+            clock.dispatched()
+            clock.dispatched()  # a second program behind the first: no change
+            fake.now = 106.0  # three covered
+            with clock.phase(B):
+                fake.now = 110.0  # four covered seconds of B
+                clock.drained()
+                clock.drained()
+                fake.now = 110.5  # half an uncovered one
+            assert clock.snapshot().uncovered == {A: 2.0, B: 0.5, BASE: 1.0}
+            fake.now = 112.0  # in flight, uncovered
+            assert clock.snapshot().uncovered == {A: 3.5, B: 0.5, BASE: 1.0}
+            clock.dispatched()
+            fake.now = 120.0
+            assert clock.snapshot().uncovered == {A: 3.5, B: 0.5, BASE: 1.0}
+        clock.stop()
+        snap = clock.snapshot()
+        assert snap[:3] == (20.0, {A: 14.5, B: 4.5, BASE: 1.0}, {A: 1, B: 1, BASE: 1})
+        assert all(snap.uncovered[k] <= snap.seconds[k] for k in snap.seconds)
+
+    def test_cpu_seconds_leave_out_what_the_waits_burn_by_one_visit_in_64(self, fake):
+        clock = Tracer().phase_clock([A, B, BASE], BASE, waits=[B])
+        clock.start()  # one read
+        for _ in range(129):
+            fake.now += 1.0
+            fake.cpu += 1.0  # a second of host work, all of it on the CPU
+            with clock.phase(A):
+                pass
+            with clock.phase(B):
+                fake.now += 2.0
+                fake.cpu += 0.25  # what the wait burns
+        # The 1st, 65th and 129th visit were read at both edges, no other.
+        assert clock._wait_cpu == {B: [3, 0.75]} and fake.cpu_reads == 1 + 2 * 3
+        assert clock.cpu_seconds() == pytest.approx(129.0)
+        snap = clock.snapshot()
+        assert snap.wall - snap.seconds[B] == pytest.approx(129.0)  # never off the CPU
+        fake.now += 4.0
+        fake.cpu += 1.0  # four seconds of host work, three of them off the CPU
+        assert clock.cpu_seconds() == pytest.approx(130.0)
+        clock.stop()  # one more read; then the clock is the owner's no more
+        reads = fake.cpu_reads
+        assert clock.cpu_seconds() == pytest.approx(130.0) and fake.cpu_reads == reads
+
+    def test_cpu_seconds_are_the_owners_read_from_any_thread(self):
+        """The owner burns CPU, then sleeps: whoever asks reads the owner's
+        CPU seconds, not its own, and they stand still once the clock stops."""
+        box, burnt, done = {}, threading.Event(), threading.Event()
+
+        def owner():
+            clock = box["clock"] = self._clock()
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.05:
+                pass
+            box["own"] = time.thread_time() - t0
+            burnt.set()
+            done.wait(30)
+            clock.stop()
+
+        thread = threading.Thread(target=owner, daemon=True)
+        mine = time.thread_time()
+        thread.start()
+        assert burnt.wait(30)
+        time.sleep(0.05)  # the owner is off its CPU meanwhile
+        clock = box["clock"]
+        cpu, wall = clock.cpu_seconds(), clock.snapshot().wall
+        assert box["own"] <= cpu <= wall and cpu < box["own"] + 0.04
+        assert clock.cpu_seconds() - cpu < 0.01 < time.thread_time() - mine + 0.01
+        done.set()
+        thread.join(30)
+        assert not thread.is_alive()
+        stopped = clock.cpu_seconds()  # the owner is gone: the closed sum, no error
+        assert cpu <= stopped == clock.cpu_seconds() <= clock.snapshot().wall
+
+    def test_without_a_thread_cpu_clock_there_are_no_cpu_seconds(self, monkeypatch):
+        from types import SimpleNamespace
+
+        from polyaxon_tpu.tracking import trace as trace_mod
+
+        monkeypatch.setattr(trace_mod, "time", SimpleNamespace(
+            perf_counter=time.perf_counter, time=time.time, sleep=time.sleep))
+        clock = self._clock()
+        with clock.phase(A):
+            assert clock.cpu_seconds() is None
+        clock.stop()
+        assert clock.cpu_seconds() is None
+        wall, seconds, _ = clock.snapshot()[:3]
+        assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
 
     def test_snapshot_from_another_thread_is_consistent(self):
         """The reader retries around transitions in flight: whatever it
@@ -497,10 +660,17 @@ class TestPhaseClock:
         clock_box, stop = [], threading.Event()
 
         def owner():
-            clock = self._clock()
+            clock = Tracer().phase_clock([A, B, C, BASE], BASE, laps={A: ["x", "y"]})
+            clock.start()
             clock_box.append(clock)
             while not stop.is_set():
                 _nested(clock)
+                with clock.phase(A):
+                    clock.lap("x")
+                    clock.dispatched()
+                    clock.lap("y")
+                    with clock.phase(C):
+                        clock.drained()
             clock.stop()
 
         switch = sys.getswitchinterval()
@@ -510,15 +680,23 @@ class TestPhaseClock:
             thread.start()
             while not clock_box:
                 time.sleep(0.001)
-            clock, last = clock_box[0], None
+            clock, last, last_cpu = clock_box[0], None, 0.0
             for _ in range(2000):
-                wall, seconds, counts = clock.snapshot()
+                cpu, snap = clock.cpu_seconds(), clock.snapshot()
+                wall, seconds, counts = snap[:3]
                 assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
+                # The parts never exceed what they are parts of.
+                assert all(snap.uncovered[k] <= seconds[k] + 1e-9 for k in seconds)
+                assert sum(snap.laps.values()) <= seconds[A] + 1e-9
+                assert 0.0 <= cpu <= wall + 1e-3
                 if last is not None:
-                    assert wall >= last[0]
-                    assert all(seconds[k] >= last[1][k] for k in seconds)
-                    assert all(counts[k] >= last[2][k] for k in counts)
-                last = (wall, seconds, counts)
+                    assert wall >= last.wall
+                    assert all(seconds[k] >= last.seconds[k] for k in seconds)
+                    assert all(counts[k] >= last.counts[k] for k in counts)
+                    assert all(snap.uncovered[k] >= last.uncovered[k] for k in seconds)
+                    assert all(snap.laps[k] >= last.laps[k] for k in snap.laps)
+                    assert cpu >= last_cpu
+                last, last_cpu = snap, cpu
         finally:
             stop.set()
             thread.join(10)
@@ -572,6 +750,30 @@ class TestProfilerHook:
             assert depth in (0, 1)
         assert depth == 0
 
+    def test_a_lap_is_annotated_as_its_phase_dot_its_name(self):
+        t = Tracer()
+        clock = t.phase_clock([A, B, BASE], BASE, laps={A: ["x", "y"]})
+        clock.start()
+        with clock.phase(A):
+            clock.lap("x")  # no capture: nothing annotated
+        assert _Annotation.log == []
+        t.profiler_hook = _Annotation
+        with clock.phase(A):
+            clock.lap("x")
+            clock.dispatched()  # a flag is no annotation
+            clock.lap("y")
+            with clock.phase(B):
+                clock.drained()
+        t.profiler_hook = None
+        clock.stop()
+        names = [n for kind, n in _Annotation.log if kind == "open"]
+        assert names == [A, f"{A}.x", f"{A}.y", B, A, BASE]
+        depth = 0
+        for kind, _ in _Annotation.log:
+            depth += 1 if kind == "open" else -1
+            assert depth in (0, 1)  # exclusive on the profiler's clock too
+        assert depth == 0
+
     def test_a_profiler_that_raises_does_not_reach_the_loop(self):
         def broken(name):
             raise RuntimeError("no profiler")
@@ -583,7 +785,7 @@ class TestProfilerHook:
         with clock.phase(A):
             pass
         clock.stop()
-        wall, seconds, counts = clock.snapshot()
+        wall, seconds, counts = clock.snapshot()[:3]
         assert counts[A] == 1
         assert sum(seconds.values()) == pytest.approx(wall, rel=1e-6)
 
