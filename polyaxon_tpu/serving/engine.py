@@ -100,9 +100,9 @@ STATE_PHASES = (PH_SNAPSHOT, PH_RESTORE)
 #: ``serving.loop.decode_host``'s seconds, in the order a step runs them.
 #: ``/v1/stats``: ``decode_host_<lap>_s``.
 LAP_INPUTS = "inputs"  # the fault loop's own time, participants, tables, key counts
-LAP_KEY = "key"  # jax.random.split: a dispatched program of its own every step
-LAP_UPLOAD = "upload"  # the jnp.asarray of the step's arguments
-LAP_DISPATCH = "dispatch"  # the step's (or the verify) call until it returns
+LAP_KEY = "key"  # the step's key, drawn on the host (``_draw_key``)
+LAP_UPLOAD = "upload"  # packing the step's host arguments into one buffer
+LAP_DISPATCH = "dispatch"  # the call, the buffer's transfer included, until it returns
 STEP_LAPS = (LAP_INPUTS, LAP_KEY, LAP_UPLOAD, LAP_DISPATCH)
 #: The phases the engine's thread does not compute in: the wall seconds of
 #: the others less the thread's CPU seconds outside these is ``host_off_cpu_s``.
@@ -820,7 +820,11 @@ class ServingEngine:
                 alloc=self._alloc_block,
             )
 
-        self._key = jax.random.PRNGKey(seed)
+        # A step's key is drawn on the host and rides in the step's call
+        # with its other host arguments: no program of its own.  Its stream
+        # is apart from ``_rng``'s (the first tokens' picks).
+        self._key_spec = jax.eval_shape(jax.random.PRNGKey, 0)
+        self._key_rng = np.random.default_rng(np.random.SeedSequence(seed).spawn(1)[0])
         self._rng = np.random.default_rng(seed)
         self._chunk_fns: Dict[int, Any] = {}
         self._snapshot_fn: Optional[Any] = None
@@ -962,15 +966,53 @@ class ServingEngine:
         # (CPU included) honor donation for same-shape aliasing.
         return (1,)
 
+    def _draw_key(self) -> np.ndarray:
+        """A step's PRNG key, drawn on the host: the key's shape and dtype."""
+        spec = self._key_spec
+        return self._key_rng.integers(0, 2**32, spec.shape, dtype=spec.dtype)
+
+    def _pack_step(self, tables: np.ndarray, key: np.ndarray) -> np.ndarray:
+        """A decode step's host arguments in ONE int32 buffer, which the
+        step's program slices apart (``_build_step``): the tables, ``tok``,
+        ``pos``, ``active``, the bits of ``temps``, the key's words.  The
+        call's dispatch transfers each numpy argument on its own, about a
+        tenth of a millisecond each on the chip (PERF.md, PR 39)."""
+        S, n = self.slots, tables.size
+        buf = np.empty(n + 4 * S + key.size, np.int32)
+        buf[:n] = tables.reshape(-1)
+        buf[n : n + S] = self._tok
+        buf[n + S : n + 2 * S] = self._pos
+        buf[n + 2 * S : n + 3 * S] = self._active
+        buf[n + 3 * S : n + 4 * S] = self._temps.view(np.int32)
+        buf[n + 4 * S :] = key.reshape(-1).view(np.int32)
+        return buf
+
+    def _chunk_args(
+        self, table: np.ndarray, chunk: np.ndarray, start: int, length: int, slot: int
+    ) -> tuple:
+        """A prompt chunk's arguments after the pool, as the loop and the
+        warm-up pass them: host values, which the call's dispatch transfers;
+        a recurrent model's chunk also names its slot."""
+        tail = (np.int32(slot),) if self._recurrent else ()
+        return (table, chunk, np.int32(start), np.int32(length), *tail)
+
     def _build_step(self):
         import jax
         import jax.numpy as jnp
+        from jax import lax
 
         from polyaxon_tpu.models.decode import paged_decode_step
 
         cfg = self.cfg
+        S, W, kspec = self.slots, self._table_width, self._key_spec
 
-        def step(params, pool, tables, tokens, pos, active, temps, key, qweights):
+        def step(params, pool, packed, qweights):
+            n = S * W  # ``_pack_step``'s layout
+            tables = packed[:n].reshape(S, W)
+            tokens, pos = packed[n : n + S], packed[n + S : n + 2 * S]
+            active = packed[n + 2 * S : n + 3 * S] != 0
+            temps = lax.bitcast_convert_type(packed[n + 3 * S : n + 4 * S], jnp.float32)
+            words = lax.bitcast_convert_type(packed[n + 4 * S :], kspec.dtype)
             # ``counts``: what a model with routed experts routed, else nothing
             logits, pool, *counts = paged_decode_step(
                 params, pool, tables, tokens, pos, active, cfg,
@@ -979,7 +1021,7 @@ class ServingEngine:
             greedy_tok = jnp.argmax(logits, axis=-1)
             # Per-slot keys: a slot's sample must not depend on which
             # neighbors happen to be in flight.
-            keys = jax.random.split(key, logits.shape[0])
+            keys = jax.random.split(words.reshape(kspec.shape), logits.shape[0])
             safe = jnp.where(temps > 0, temps, 1.0)
             sampled = jax.vmap(jax.random.categorical)(
                 keys, logits / safe[:, None]
@@ -1078,10 +1120,8 @@ class ServingEngine:
         every block's slice is DISPATCHED before any is materialized, so
         block i+1's device-side copy overlaps block i's host conversion
         — the ``runtime/pipeline.py`` prefetch idea applied to spill."""
-        import jax.numpy as jnp
-
         fn = self._get_export()
-        pending = [fn(self._pool, jnp.int32(b)) for b in blocks]
+        pending = [fn(self._pool, np.int32(b)) for b in blocks]
         payloads = [
             {name: np.asarray(leaf) for name, leaf in tree.items()}
             for tree in pending
@@ -1091,9 +1131,7 @@ class ServingEngine:
 
     def _import_block(self, block: int, data: Dict[str, np.ndarray]) -> None:
         """Host→device copy of one payload into pool block ``block``."""
-        import jax.numpy as jnp
-
-        self._pool = self._get_import()(self._pool, data, jnp.int32(block))
+        self._pool = self._get_import()(self._pool, data, np.int32(block))
         self._clock.dispatched()
         with self._stats_lock:
             self._n_restored_blocks += 1
@@ -1230,14 +1268,16 @@ class ServingEngine:
         populate the jit dispatch cache — with arguments whose writes
         all land in the reserved trash block 0: the decode step with an
         all-inactive mask, each chunk bucket with ``length=0``, and the
-        COW copy as a trash self-copy.  A failure here (a compile the
+        COW copy as a trash self-copy.  Each call takes the KINDS of
+        argument the loop passes (host values: ``_pack_step``,
+        ``_chunk_args``): a numpy argument is a jit cache entry of its own,
+        apart from a device array's.  A failure here (a compile the
         backend refuses, an HBM overflow, a step that died after the
         pool was donated) is a failed start: the error is recorded, the
         readiness gate stays shut, ``stats()['state']`` reads
         ``failed`` and the scheduler loop exits.
         """
         import jax
-        import jax.numpy as jnp
 
         tracer = get_tracer()
         t0 = time.perf_counter()
@@ -1269,38 +1309,33 @@ class ServingEngine:
             self._preload_prefixes()
             if self._warmup:
                 with tracer.span("serving.warmup", buckets=len(buckets)):
-                    self._key, sub = jax.random.split(self._key)
                     tables = np.where(
                         self._tables >= 0, self._tables, 0
                     ).astype(np.int32)
                     toks, self._pool, *_ = self._step_fn(
                         self._params,
                         self._pool,
-                        jnp.asarray(tables),
-                        jnp.asarray(self._tok),
-                        jnp.asarray(self._pos),
-                        jnp.asarray(self._active),
-                        jnp.asarray(self._temps),
-                        sub,
+                        self._pack_step(tables, self._draw_key()),
                         self._qweights,
                     )
                     jax.block_until_ready(toks)
                     _tick()
-                    table0 = jnp.zeros(self._table_width, jnp.int32)
-                    # A recurrent model's chunk also names a slot: slot 0,
-                    # whose rows a chunk of length 0 leaves zero.
-                    slot0 = (jnp.int32(0),) if self._recurrent else ()
+                    zero = np.int32(0)
                     for c_pad in buckets:
                         if self._stop.is_set():
                             break
+                        # A recurrent model's chunk also names a slot: slot
+                        # 0, whose rows a chunk of length 0 leaves zero.
                         logits, self._pool, *_ = self._get_chunk(c_pad)(
                             self._params,
                             self._pool,
-                            table0,
-                            jnp.zeros(c_pad, jnp.int32),
-                            jnp.int32(0),
-                            jnp.int32(0),
-                            *slot0,
+                            *self._chunk_args(
+                                np.zeros(self._table_width, np.int32),
+                                np.zeros(c_pad, np.int32),
+                                0,
+                                0,
+                                0,
+                            ),
                         )
                         jax.block_until_ready(logits)
                         _tick()
@@ -1308,10 +1343,10 @@ class ServingEngine:
                         # Snapshot and restore through place 0 and slot 0
                         # (all zeros either way): both programs compiled.
                         self._snap_store = self._get_snapshot()(
-                            self._snap_store, self._pool, jnp.int32(0), jnp.int32(0)
+                            self._snap_store, self._pool, zero, zero
                         )
                         self._pool = self._get_restore()(
-                            self._pool, self._snap_store, jnp.int32(0), jnp.int32(0)
+                            self._pool, self._snap_store, zero, zero
                         )
                         jax.block_until_ready(self._pool)
                         _tick()
@@ -1321,24 +1356,21 @@ class ServingEngine:
                     for width in widths:
                         if self._stop.is_set():
                             break
-                        self._key, sub = jax.random.split(self._key)
                         out, n_emit, self._pool = self._get_verify(width)(
                             self._params,
                             self._pool,
-                            jnp.asarray(tables),
-                            jnp.zeros((self.slots, width), jnp.int32),
-                            jnp.asarray(self._pos),
-                            jnp.ones(self.slots, jnp.int32),
-                            jnp.asarray(self._active),
-                            jnp.asarray(self._temps),
-                            sub,
+                            tables,
+                            np.zeros((self.slots, width), np.int32),
+                            self._pos,
+                            np.ones(self.slots, np.int32),
+                            self._active,
+                            self._temps,
+                            self._draw_key(),
                             self._qweights,
                         )
                         jax.block_until_ready(out)
                         _tick()
-                    self._pool = self._get_copy()(
-                        self._pool, jnp.int32(0), jnp.int32(0)
-                    )
+                    self._pool = self._get_copy()(self._pool, zero, zero)
                     jax.block_until_ready(self._pool)
                     _tick()
                     if spillers:
@@ -1346,9 +1378,7 @@ class ServingEngine:
                         # block: compiles export+import so steady-state
                         # park-spill and demotion never mint a compile.
                         [data] = self._export_blocks([0])
-                        self._pool = self._get_import()(
-                            self._pool, data, jnp.int32(0)
-                        )
+                        self._pool = self._get_import()(self._pool, data, zero)
                         jax.block_until_ready(self._pool)
                         _tick()
         except Exception as e:
@@ -1373,19 +1403,10 @@ class ServingEngine:
     def _decode_hlo_text(self) -> str:
         """Lower the decode step against the engine's live shapes and
         render its HLO text (capture-time only; best-effort)."""
-        import jax.numpy as jnp
-
         tables = np.where(self._tables >= 0, self._tables, 0).astype(np.int32)
+        key = np.zeros(self._key_spec.shape, self._key_spec.dtype)
         lowered = self._step_fn.lower(
-            self._params,
-            self._pool,
-            jnp.asarray(tables),
-            jnp.asarray(self._tok),
-            jnp.asarray(self._pos),
-            jnp.asarray(self._active),
-            jnp.asarray(self._temps),
-            self._key,
-            self._qweights,
+            self._params, self._pool, self._pack_step(tables, key), self._qweights
         )
         return lowered.as_text()
 
@@ -2232,10 +2253,8 @@ class ServingEngine:
     def _restore_state(self, slot: int, place: int) -> None:
         """Copy snapshot ``place`` into ``slot``'s recurrent rows: the
         request's prefill goes on from there."""
-        import jax.numpy as jnp
-
         self._pool = self._get_restore()(
-            self._pool, self._snap_store, jnp.int32(place), jnp.int32(slot)
+            self._pool, self._snap_store, np.int32(place), np.int32(slot)
         )
         self._clock.dispatched()
         with self._stats_lock:
@@ -2247,13 +2266,11 @@ class ServingEngine:
         tokens (the chunk that got there is dispatched; the copy is ordered
         after it).  Pending until the prompt is in and its blocks are
         offered; skipped when every place of the store is pending."""
-        import jax.numpy as jnp
-
         place = self._snaps.alloc()
         if place is None:
             return
         self._snap_store = self._get_snapshot()(
-            self._snap_store, self._pool, jnp.int32(slot), jnp.int32(place)
+            self._snap_store, self._pool, np.int32(slot), np.int32(place)
         )
         self._clock.dispatched()
         self._pending_snaps.setdefault(slot, {})[pos] = place
@@ -2277,8 +2294,6 @@ class ServingEngine:
         """Run ONE chunk of the oldest pending prefill.  Returns True if
         the device did work; False means the job is blocked on the block
         pool (it stays at the head and retries next iteration)."""
-        import jax.numpy as jnp
-
         clock = self._clock
         t0 = clock.t  # the transition into this phase
         bookkeeping = clock.phase(PH_BOOKKEEPING)
@@ -2293,7 +2308,7 @@ class ServingEngine:
             bi = (t - 1) // bs
             shared = int(self._tables[slot, bi])
             self._pool = self._get_copy()(
-                self._pool, jnp.int32(shared), jnp.int32(fresh)
+                self._pool, np.int32(shared), np.int32(fresh)
             )
             clock.dispatched()
             with clock.phase(PH_ALLOC):
@@ -2325,11 +2340,7 @@ class ServingEngine:
         logits, self._pool, *counts = self._get_chunk(c_pad)(
             self._params,
             self._pool,
-            jnp.asarray(table.astype(np.int32)),
-            jnp.asarray(chunk),
-            jnp.int32(job.next_pos),
-            jnp.int32(n),
-            *((jnp.int32(slot),) if self._recurrent else ()),
+            *self._chunk_args(table.astype(np.int32), chunk, job.next_pos, n, slot),
         )
         clock.dispatched()
         self._note_counts(c_pad, counts)
@@ -2616,9 +2627,6 @@ class ServingEngine:
 
     @_in_phase(PH_DECODE_HOST)
     def _step_once(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
         clock = self._clock
         t0 = clock.t  # the transition into this phase
         clock.lap(LAP_INPUTS)
@@ -2654,22 +2662,16 @@ class ServingEngine:
             self._n_step_keys_attended += self._step_keys(self._pos[self._active] + 1)
             self._n_step_keys_table += n_live * self._table_width * bs
         clock.lap(LAP_KEY)
-        self._key, sub = jax.random.split(self._key)
+        key = self._draw_key()
         emitted = 0
         if drafts:
-            emitted = self._verify_once(drafts, tables, sub)
+            emitted = self._verify_once(drafts, tables, key)
         else:
             clock.lap(LAP_UPLOAD)
-            inputs = (
-                jnp.asarray(tables),
-                jnp.asarray(self._tok),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._active),
-                jnp.asarray(self._temps),
-            )
+            packed = self._pack_step(tables, key)
             clock.lap(LAP_DISPATCH)
             toks, self._pool, *counts = self._step_fn(
-                self._params, self._pool, *inputs, sub, self._qweights
+                self._params, self._pool, packed, self._qweights
             )
             clock.dispatched()
             self._note_counts(self.slots, counts)
@@ -2753,14 +2755,12 @@ class ServingEngine:
         return drafts
 
     def _verify_once(
-        self, drafts: Dict[int, List[int]], tables: np.ndarray, sub
+        self, drafts: Dict[int, List[int]], tables: np.ndarray, key: np.ndarray
     ) -> int:
         """One draft→verify→rollback iteration: score every lane's run
         in a single forward pass, append the accepted tokens, truncate
         each table past its rolled-back write position.  Returns tokens
         emitted."""
-        import jax.numpy as jnp
-
         clock = self._clock
         clock.lap(LAP_INPUTS)
         width = self._width_for(max(len(p) for p in drafts.values()))
@@ -2771,17 +2771,10 @@ class ServingEngine:
             tok_in[slot, 1 : 1 + len(prop)] = prop
             n_tok[slot] = 1 + len(prop)
         clock.lap(LAP_UPLOAD)
-        inputs = (
-            jnp.asarray(tables),
-            jnp.asarray(tok_in),
-            jnp.asarray(self._pos),
-            jnp.asarray(n_tok),
-            jnp.asarray(self._active),
-            jnp.asarray(self._temps),
-        )
+        args = (tables, tok_in, self._pos, n_tok, self._active, self._temps, key)
         clock.lap(LAP_DISPATCH)
         out, n_emit, self._pool = self._get_verify(width)(
-            self._params, self._pool, *inputs, sub, self._qweights
+            self._params, self._pool, *args, self._qweights
         )
         clock.dispatched()
         with clock.phase(PH_DEVICE_WAIT) as t0:

@@ -27,6 +27,12 @@ are the parent's letter for letter.  It gave the hybrid stack's two programs
 the same guard first: ``HYBRID_AT_PR_30``, at ``olmo-hybrid-7b-serve``'s toy
 sizes (4 KV heads in pool rows of 8, so the padding is in the text), taken at
 a66de81, the commit PR 31 started from, before ``models/`` was edited.
+
+PR 39 retook ``engine-step`` and only that one: the engine hands its decode step
+the tables, tokens, positions, mask, temperatures and key as ONE int32 buffer
+that the step slices apart (``ServingEngine._pack_step``), because the call
+transfers each host argument on its own; ``paged_decode_step``, whose six
+digests are above, did not move.
 """
 
 import hashlib
@@ -50,14 +56,14 @@ CFG = TransformerConfig(
     n_kv_heads=TOY["num_key_value_heads"], max_seq=ENGINE["seq"])
 S, W, T, C = ENGINE["slots"], ENGINE["seq"] // ENGINE["block_size"], 5, ENGINE["prefill_chunk"]
 
-AT_PR_28 = {  # "engine-step" at PR 30, see above
+AT_PR_28 = {  # "engine-step" at PR 39, see above
     "prefill-kv": "8d6c3d807ac758f5308f1aa7fda3e10c8601bfd49b7e11256e75111a43917b1b",
     "decode-kv": "e470b812870f85b1c395f5c6105195e840b8b11bdb17511c71de9e446a18d21b",
     "verify-kv": "765cf1829bc346595072cc19f9eb9976c9e4a15a164a89940b0300e2c98069d5",
     "prefill-int8": "e31f6c878ae26266b070d421f292c27cc427fbf91cf194f6ea73b0ebdf38bbd8",
     "decode-int8": "9064dff56c128f91c404b6354f18f4a7197f2a17525c2805079e164e41a8c07e",
     "verify-int8": "29619d31e1a8de1decf241e91d76f0bbbb44288ab829e42678ce8861306f3a26",
-    "engine-step": "38573862abd6a308fde14272f7b8179050eb8ed97dd0eae1664e87bef6cc7c33",
+    "engine-step": "fd75911210ac9d00b11eeadb20ecdec3eecbb31994002b4330e0910bacaf48d2",
 }
 
 
