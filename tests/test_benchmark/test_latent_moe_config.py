@@ -160,7 +160,7 @@ def test_the_new_entries_load_and_the_references_name_escapes_the_dense_glob():
     for name in ("serve.latent_moe_step_mfu", "serve.expert_matmul_roofline",
                  "serve.expert_busiest_over_mean"):
         entry = next(m for m in manifest.data["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "serve_tokens_per_s"
+        assert CELL in entry["workloads"] and entry["moves"] == "serve_tokens_per_s"
     entry = next(c for c in manifest.data["configs"] if c["name"] == NAME)
     assert entry["reduced"] == CONFIG["reduced"] and entry["source"] == CONFIG["source"]
     # test_rehearsal.py holds every configs/*_reference.py to the DENSE program

@@ -2,7 +2,10 @@
 traffic mix, a per-layer metric and a cell as files and entries only."""
 
 import json
+import os
 import shutil
+import subprocess
+import sys
 from pathlib import Path
 
 import pytest
@@ -88,19 +91,21 @@ def test_missing_reader_and_generator_are_refused(copy):
         Manifest(copy).check()
 
 
-def test_a_later_pr_adds_files_and_entries_only(copy):
-    """The worked example of benchmark/README.md: nothing that was there is edited."""
-    before = {p: p.read_bytes() for p in (copy / "benchmark").rglob("*") if p.is_file()}
-    cfg = json.loads((copy / "benchmark/configs/mistral-7b-v0.3-serve.json").read_text())
+def _add_the_worked_example(root):
+    """benchmark/README.md's worked example, in the copy at ``root``: a
+    configuration, a traffic mix, a served cell appended to every list that
+    holds ``serve-mistral7b-docqa``, and a per-layer metric at the END of
+    ``per_layer``."""
+    cfg = json.loads((root / "benchmark/configs/mistral-7b-v0.3-serve.json").read_text())
     cfg.update(name="mistral-7b-v0.3-serve-long", engine=dict(cfg["engine"], seq=32768),
                reference="benchmark/configs/mistral-7b-v0.3-serve-long_reference.py")
-    (copy / "benchmark/configs/mistral-7b-v0.3-serve-long.json").write_text(json.dumps(cfg))
-    shutil.copy(copy / "benchmark/configs/mistral-7b-v0.3-serve_reference.py",
-                copy / "benchmark/configs/mistral-7b-v0.3-serve-long_reference.py")
-    mix = json.loads((copy / "benchmark/traffic/docqa-closed8.json").read_text())
+    (root / "benchmark/configs/mistral-7b-v0.3-serve-long.json").write_text(json.dumps(cfg))
+    shutil.copy(root / "benchmark/configs/mistral-7b-v0.3-serve_reference.py",
+                root / "benchmark/configs/mistral-7b-v0.3-serve-long_reference.py")
+    mix = json.loads((root / "benchmark/traffic/docqa-closed8.json").read_text())
     mix.update(name="docqa-unshared", questions_per_document=1)
-    (copy / "benchmark/traffic/docqa-unshared.json").write_text(json.dumps(mix))
-    (copy / "benchmark/layer_metrics/serve.closed_ttft_p50_ms.json").write_text(json.dumps({
+    (root / "benchmark/traffic/docqa-unshared.json").write_text(json.dumps(mix))
+    (root / "benchmark/layer_metrics/serve.closed_ttft_p50_ms.json").write_text(json.dumps({
         "name": "serve.closed_ttft_p50_ms", "layer": "serving engine", "unit": "ms",
         "better": "lower", "source": "host_clock", "moves": "serve_tokens_per_s",
         "reader": "client_percentile", "args": {"field": "ttft_ms", "q": 50}}))
@@ -121,9 +126,44 @@ def test_a_later_pr_adds_files_and_entries_only(copy):
                                "layer": "serving engine", "moves": "serve_tokens_per_s",
                                "workloads": ["serve-mistral7b-unshared"]})
 
-    m = _edit(copy, add)
+    return _edit(root, add)
+
+
+def test_a_later_pr_adds_files_and_entries_only(copy):
+    """The worked example of benchmark/README.md: nothing that was there is edited."""
+    before = {p: p.read_bytes() for p in (copy / "benchmark").rglob("*") if p.is_file()}
+    m = _add_the_worked_example(copy)
     m.check()
     cell = m.cell("serve-mistral7b-unshared")
     assert cell.config["engine"]["seq"] == 32768 and cell.traffic["questions_per_document"] == 1
     assert "serve.closed_ttft_p50_ms" in {x["name"] for x in cell.per_layer}
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+#: The tests that read the manifest as committed: each configuration's own
+#: entries, the metrics that every served cell reports, the self-check.
+MANIFEST_READERS = [
+    "test_manifest.py::test_committed_manifest_passes_and_names_only_files_that_exist",
+    "test_manifest.py::test_every_layer_metric_lives_only_beside_the_metric_it_moves",
+    "test_manifest.py::test_each_cell_has_an_mfu_named_share_beside_its_kernel_rooflines",
+    "test_loop_instruments.py::test_the_file_agrees_with_the_manifest_and_lists_the_served_cells",
+    *(f"test_{name}_config.py::test_the_new_entries_load_and_the_references_name_escapes_the_dense_glob"
+      for name in ("hybrid", "latent_moe", "window_moe")),
+]
+
+
+def test_a_cell_added_as_files_and_entries_leaves_the_benchmarks_own_tests_passing(copy):
+    """The worked example, and then every test that reads the manifest, run on
+    the copy: none pins how many cells there are, what else a shared list
+    holds, or where an entry stands."""
+    shutil.copytree(ROOT / "tests/test_benchmark", copy / "tests/test_benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (copy / "tests/__init__.py").write_text("")
+    _add_the_worked_example(copy).check()
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         *(f"tests/test_benchmark/{t}" for t in MANIFEST_READERS)],
+        capture_output=True, text=True, timeout=120, cwd=copy,
+        env={**os.environ, "PYTHONPATH": os.pathsep.join([str(copy), str(ROOT)])})
+    assert proc.returncode == 0, proc.stdout[-3000:]
+    assert "13 passed" in proc.stdout.splitlines()[-1], proc.stdout[-500:]

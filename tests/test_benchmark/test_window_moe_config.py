@@ -187,25 +187,22 @@ def test_lm_server_takes_the_window_stacks_declarations():
 def test_the_new_entries_load_and_the_references_name_escapes_the_dense_glob():
     manifest = Manifest(ROOT)
     manifest.check()
-    assert len(manifest.data["workloads"]) == 5 and len(manifest.data["configs"]) == 5
+    assert CELL in {w["name"] for w in manifest.data["workloads"]}
+    assert NAME in {c["name"] for c in manifest.data["configs"]}
     cell = manifest.cell(CELL)
     assert (cell.chips, cell.traffic["name"], cell.config["name"]) == (1, TRAFFIC, NAME)
     assert [m["name"] for m in cell.end_to_end] == ["serve_tokens_per_s", "setup_s"]
     reported = {m["name"] for m in cell.per_layer}
     assert {"serve.window_moe_step_mfu", "serve.window_attention_roofline",
-            "serve.window_expert_matmul_roofline", "serve.state_floor_share",
-            "serve.loop_state_share", "serve.prefix_hit_share", "serve.evictions_per_s",
-            "serve.loop_paging_share", "setup.boot_to_chip_s"} <= reported
+            "serve.state_floor_share", "serve.loop_state_share", "serve.prefix_hit_share",
+            "serve.evictions_per_s", "serve.loop_paging_share", "setup.boot_to_chip_s"} <= reported
     assert not {"serve.closed_step_mfu", "serve.hybrid_step_mfu", "serve.latent_moe_step_mfu",
                 "serve.delta_rule_roofline"} & reported  # other decoders' counts
-    for name in ("serve.window_moe_step_mfu", "serve.window_attention_roofline",
-                 "serve.window_expert_matmul_roofline"):
+    for name in ("serve.window_moe_step_mfu", "serve.window_attention_roofline"):
         entry = next(m for m in manifest.data["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "serve_tokens_per_s"
-    # the expert product's roofline is the accepted reader under this cell's own name
-    mine, theirs = (json.loads((ROOT / f"benchmark/layer_metrics/{n}.json").read_text()) for n in (
-        "serve.window_expert_matmul_roofline", "serve.expert_matmul_roofline"))
-    assert {**mine, "name": theirs["name"]} == theirs
+        assert CELL in entry["workloads"] and entry["moves"] == "serve_tokens_per_s"
+    # the expert product's roofline is the one metric of every cell with routed experts
+    assert "serve.expert_matmul_roofline" in reported
     entry = next(c for c in manifest.data["configs"] if c["name"] == NAME)
     assert entry["reduced"] == CONFIG["reduced"] and entry["source"] == CONFIG["source"]
     # test_rehearsal.py holds every configs/*_reference.py to the DENSE program
