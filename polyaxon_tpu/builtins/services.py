@@ -500,7 +500,13 @@ def lm_server(ctx: Context) -> None:
     ``/v1/stats``, beside ``moe_rows_routed`` / ``moe_rows_held`` /
     ``moe_rows_busiest`` / ``moe_experts_hit`` / ``moe_call_shapes``).  It
     refuses ``spec_decode`` and a multi-chip mesh with a ``LatentStackError``
-    naming the option.
+    naming the option.  ``n_group`` / ``topk_group`` limit the router to its
+    best groups of experts; ``index_n_heads`` / ``index_head_dim`` /
+    ``index_topk`` add the lightning indexer, whose selection each query
+    attends through (``dsa_index_pairs`` / ``dsa_selected_pairs`` /
+    ``dsa_index_call_shapes`` / ``dsa_tie_keys`` in ``/v1/stats``; it refuses
+    ``kv_quantize``); ``rope_yarn_factor`` with the YaRN fields below and
+    ``rope_yarn_mscale_all_dim`` put YaRN on the latent attention.
 
     A model of window and full attention layers (``models/window_moe.py``) is
     declared by ``mlp_layer_types`` (``dense`` / ``sparse``, a layer each)
@@ -566,7 +572,8 @@ def lm_server(ctx: Context) -> None:
         "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
         "v_head_dim", "n_routed_experts", "num_experts_per_tok",
         "moe_intermediate_size", "n_shared_experts", "experts_held",
-        "expert_offset",
+        "expert_offset", "n_group", "topk_group", "index_n_heads",
+        "index_head_dim", "index_topk",
     ):
         if ctx.get_param(f) is not None:
             cfg_fields[f] = int(ctx.get_param(f))
@@ -588,7 +595,7 @@ def lm_server(ctx: Context) -> None:
         ("rope_yarn_original_max", int), ("partial_rotary_factor", float),
         ("rope_yarn_factor", float), ("rope_yarn_beta_fast", float),
         ("rope_yarn_beta_slow", float), ("rope_attention_factor", float),
-        ("sliding_rope_theta", float),
+        ("sliding_rope_theta", float), ("rope_yarn_mscale_all_dim", float),
     ):
         if ctx.get_param(f) is not None:
             cfg_fields[f] = kind(ctx.get_param(f))

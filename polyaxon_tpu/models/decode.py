@@ -375,7 +375,11 @@ def init_block_pool(
     a layer instead, ``[c | k_rope]``: leaf ``c [L, num_blocks, block_size,
     kv_lora_rank + qk_rope_head_dim]``, no head axis (the block's tokens are
     the second-minor dimension, a whole bfloat16 tile of 16).  Its int8 form
-    is ``c_q`` / ``c_scale``, one scale a row.
+    is ``c_q`` / ``c_scale``, one scale a row.  With a lightning indexer
+    (``index_n_heads``) a second block-addressed leaf beside it, ``ik [L,
+    num_blocks, block_size, index_head_dim]``, holds a token's indexer key; it
+    has no int8 form, and ``kv_dtype="int8"`` is refused by name
+    (``latent_moe.REFUSED``).
 
     With ``kv_dtype="int8"`` the pool instead stores symmetric-quantized
     rows plus their scales — ``k_q``/``v_q`` int8 [L, NB, bs, Hkv, d] and
@@ -394,6 +398,13 @@ def init_block_pool(
 
         shape = (c.n_layers, num_blocks, block_size, pool_row_width(c))
         names = ("c",)
+        if c.index_n_heads:
+            from polyaxon_tpu.models.latent_moe import LatentStackError
+
+            if kv_dtype is not None:
+                raise LatentStackError("kv_quantize")
+            return {"c": jnp.zeros(shape, c.dtype),
+                    "ik": jnp.zeros(shape[:-1] + (c.index_head_dim,), c.dtype)}
     else:
         shape = (c.n_kv_layers, num_blocks, block_size, c.pool_kv_heads, c.head_dim)
         names = ("k", "v")
@@ -442,7 +453,9 @@ def kv_block_bytes(
     if c.stack == "latent":  # one row a token a layer, no k+v pair
         from polyaxon_tpu.models.latent_moe import pool_row_width
 
-        rows, width = c.n_layers * block_size, pool_row_width(c)
+        # ... and the indexer's key beside it, where there is an indexer
+        rows, width = c.n_layers * block_size, pool_row_width(c) + c.index_head_dim * bool(
+            c.index_n_heads)
     else:
         rows = 2 * c.n_kv_layers * block_size * c.pool_kv_heads  # head-rows, k+v
         width = c.head_dim
@@ -594,8 +607,12 @@ def step_keys_attended(
     ``cfg``: the latent stack and the window stack's full layers read each
     lane's pages by whole compute blocks up to the one that holds its live end
     (``flash.paged_step_attend``); the dense and the hybrid stack, and any
-    stack over an int8 pool, gather the whole table a lane.  The host's count
-    for ``/v1/stats``, beside :func:`chunk_keys_attended`."""
+    stack over an int8 pool, gather the whole table a lane; a latent stack
+    with an indexer attends the ``index_topk`` rows it selected, or all while
+    a lane sees no more.  The host's count for ``/v1/stats``, beside
+    :func:`chunk_keys_attended`."""
+    if cfg.stack == "latent" and cfg.index_n_heads:
+        return sum(min(int(end), cfg.index_topk) for end in live_ends)
     if cfg.stack not in ("latent", "window") or kv_dtype is not None:
         return len(live_ends) * table_width * block_size
     block = flash.step_block_pages(table_width, block_size) * block_size

@@ -22,12 +22,14 @@ workload.  Design choices are all MXU/HBM-driven:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
@@ -175,6 +177,25 @@ class TransformerConfig:
     routed_scaling_factor: float = 1.0
     experts_held: int = 0
     expert_offset: int = 0
+    #: Group-limited routing (the published ``Gate``): the router's
+    #: ``n_routed_experts`` biased scores form ``n_group`` groups, each scored
+    #: by the sum of its two largest, and only the ``topk_group`` best groups'
+    #: experts may be chosen.  ``n_group`` 1: no limit.
+    n_group: int = 1
+    topk_group: int = 1
+    #: The lightning indexer of the latent stack (DeepSeek-V3.2's sparse
+    #: attention): ``index_n_heads`` heads of ``index_head_dim`` score every
+    #: key a query may see, and the query attends only its ``index_topk`` best
+    #: (all of them while it sees no more).  ``index_n_heads`` 0: no indexer,
+    #: every key attended.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    #: The latent stack's YaRN: with ``rope_yarn_factor`` > 0 its rotary
+    #: columns turn at the YaRN frequencies of the fields below, and the
+    #: score scale is multiplied by ``(0.1 x rope_yarn_mscale_all_dim x
+    #: ln(factor) + 1)^2`` (``rope_scaling.mscale_all_dim``; 0 = no factor).
+    rope_yarn_mscale_all_dim: float = 0.0
     #: The WINDOW stack of ``models/window_moe.py``: a layer is described by its
     #: mixer AND its MLP, one list each, as the published configs name them.
     #: ``layer_types``: ``"full_attention"`` or ``"sliding_attention"`` (a query
@@ -351,6 +372,48 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def yarn_inv_freq(cfg: TransformerConfig, dim: int) -> np.ndarray:
+    """Inverse frequencies ``[dim / 2]`` float32 of ``dim`` rotary columns at
+    ``rope_theta``; with ``rope_yarn_factor`` the YaRN blend (``transformers``'
+    ``_compute_yarn_parameters``, the DeepSeek-V3 family's
+    ``precompute_freqs_cis``): frequency ``i`` is ``theta^(-2i/dim)`` where it
+    turns more than ``rope_yarn_beta_fast`` times over
+    ``rope_yarn_original_max`` positions, that over the factor where it turns
+    fewer than ``rope_yarn_beta_slow`` times, a linear ramp between (the
+    correction dims truncated).  The window stack's full layers and the latent
+    stack call this one blend."""
+    c = cfg
+    base = float(c.rope_theta)
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not c.rope_yarn_factor:
+        return plain.astype(np.float32)
+
+    def correction_dim(rotations):
+        return dim * math.log(c.rope_yarn_original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(c.rope_yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(c.rope_yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    inv = plain / c.rope_yarn_factor * ramp + plain * (1.0 - ramp)
+    return inv.astype(np.float32)
+
+
+def rotate(x: jax.Array, positions: jax.Array, table) -> jax.Array:
+    """``x [B, T, H, d]`` rotated to ``positions [B, T]`` by ``table = (inverse
+    frequencies, factor on cos and sin)``: the first ``2 x len(inverse
+    frequencies)`` columns, pairs split by halves; the rest pass."""
+    inv, factor = table
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv)
+    cos = (jnp.cos(angles) * factor)[:, :, None, :].astype(x.dtype)
+    sin = (jnp.sin(angles) * factor)[:, :, None, :].astype(x.dtype)
+    half = inv.shape[0]
+    x1, x2, rest = x[..., :half], x[..., half : 2 * half], x[..., 2 * half :]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
 def _dense_attention(q, k, v, q_pos, k_pos):

@@ -61,7 +61,6 @@ share one scan, their ``W_q``, ``W_o`` and gate differ in shape.
 
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -71,7 +70,7 @@ from jax import lax
 
 from polyaxon_tpu.models import decode
 from polyaxon_tpu.models.latent_moe import _expert_mlp, _no_counts, experts_held
-from polyaxon_tpu.models.transformer import _rmsnorm
+from polyaxon_tpu.models.transformer import _rmsnorm, rotate, yarn_inv_freq
 from polyaxon_tpu.parallel import flash
 
 FULL = "full_attention"
@@ -335,53 +334,22 @@ def rope_table(cfg, kind: str) -> Tuple[np.ndarray, float]:
     """``(inverse frequencies [rotated columns / 2] float32, factor on cos and
     sin)`` of a layer of ``kind``.  Window layers: the whole head at
     ``sliding_rope_theta``.  Full layers: the first ``partial_rotary_factor``
-    of the head at ``rope_theta``; with ``rope_yarn_factor`` the YaRN blend
-    (``transformers``' ``_compute_yarn_parameters``): frequency ``i`` is
-    ``theta^(-2i/dim)`` where it turns more than ``rope_yarn_beta_fast`` times
-    over ``rope_yarn_original_max`` positions, that over the factor where it
-    turns fewer than ``rope_yarn_beta_slow`` times, a linear ramp between (the
-    correction dims truncated), and cos and sin carry
-    ``rope_attention_factor``."""
+    of the head at ``rope_theta``, under YaRN where ``rope_yarn_factor`` is set
+    (``transformer.yarn_inv_freq``, the blend the latent stack calls too), and
+    cos and sin carry ``rope_attention_factor``."""
     c = cfg
     if kind == WINDOW:
         half = c.head_dim // 2
         return (c.sliding_rope_theta ** (-np.arange(half, dtype=np.float64) / half)).astype(
             np.float32), 1.0
     dim = int(c.head_dim * c.partial_rotary_factor)
-    base = float(c.rope_theta)
-    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if not c.rope_yarn_factor:
-        return plain.astype(np.float32), float(c.rope_attention_factor)
-
-    def correction_dim(rotations):
-        return dim * math.log(c.rope_yarn_original_max / (rotations * 2 * math.pi)) / (
-            2 * math.log(base))
-
-    low = max(math.floor(correction_dim(c.rope_yarn_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(c.rope_yarn_beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
-    inv = plain / c.rope_yarn_factor * ramp + plain * (1.0 - ramp)
-    return inv.astype(np.float32), float(c.rope_attention_factor)
-
-
-def _rotate(x, positions, table):
-    """``x [B, T, H, d]`` rotated to ``positions [B, T]``: the first ``2 x
-    len(inverse frequencies)`` columns, pairs split by halves; the rest pass."""
-    inv, factor = table
-    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv)
-    cos = (jnp.cos(angles) * factor)[:, :, None, :].astype(x.dtype)
-    sin = (jnp.sin(angles) * factor)[:, :, None, :].astype(x.dtype)
-    half = inv.shape[0]
-    x1, x2, rest = x[..., :half], x[..., half : 2 * half], x[..., 2 * half :]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return yarn_inv_freq(c, dim), float(c.rope_attention_factor)
 
 
 def _qkv_rotated(h, layer, positions, cfg, kind):
     q, k, v = decode._qkv(h, layer)
     table = rope_table(cfg, kind)
-    return _rotate(q, positions, table), _rotate(k, positions, table), v
+    return rotate(q, positions, table), rotate(k, positions, table), v
 
 
 def _gated_out(attn, h, layer):

@@ -50,16 +50,27 @@ def _gates(s, chosen, scale: float):
     return chosen.astype(jnp.int32), gates
 
 
-def route(h, router, bias, k: int, scale: float):
-    """The sigmoid router with a selection bias (``noaux_tc``, one group).
+def route(h, router, bias, k: int, scale: float, n_group: int = 1, topk_group: int = 1):
+    """The sigmoid router with a selection bias (``noaux_tc``).
 
     ``h [N, D]``; ``router [D, E]`` and ``bias [E]`` float32.  Input, product
     and selection are float32 at ``highest``: a near-tie among the top ``k``
     must fall as it does in the plain reference.  The bias picks, the unbiased
     score weighs: returns ``(chosen [N, k] int32, gates [N, k] float32)`` with
-    ``gates = scale * s / (sum of the chosen s + 1e-20)``."""
+    ``gates = scale * s / (sum of the chosen s + 1e-20)``.  With ``n_group`` >
+    1 the group limit of the published ``Gate`` comes first: the ``E`` biased
+    scores form ``n_group`` groups of ``E / n_group``, a group scores the sum
+    of its two largest, and every expert outside the ``topk_group`` best groups
+    is masked to ``-inf``."""
     s = jax.nn.sigmoid(_router_logits(h, router))
-    _, chosen = lax.top_k(s + bias.astype(jnp.float32), k)
+    biased = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        N, E = biased.shape
+        grouped = biased.reshape(N, n_group, E // n_group)
+        _, best = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1), topk_group)
+        kept = jnp.zeros((N, n_group), jnp.bool_).at[jnp.arange(N)[:, None], best].set(True)
+        biased = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(N, E)
+    _, chosen = lax.top_k(biased, k)
     return _gates(s, chosen, scale)
 
 

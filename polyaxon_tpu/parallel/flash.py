@@ -97,17 +97,24 @@ def _pick_block(t: int, want: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False, window=None):
+def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False, window=None,
+                select=False):
     # With ``offset`` the first ref is a prefetched scalar: how far the first
     # query row lies past the first key in a causal block (see flash_block_fwd).
     # With ``window`` a row admits only the last ``window`` keys up to itself,
     # and a second prefetched scalar names the first key that is there at all.
+    # With ``select`` two more inputs follow v: the block's index scores and
+    # the rows' thresholds; a key scored under its row's threshold is masked,
+    # and the grid runs the heads inside the query tiles (see flash_block_fwd).
     if offset:
         q0_ref, *refs = refs
     if window is not None:
         k0_ref, *refs = refs
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
-    qi = pl.program_id(1)
+    if select:
+        q_ref, k_ref, v_ref, sc_ref, th_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = refs
+    qi = pl.program_id(0 if select else 1)
     ki = pl.program_id(2)
     row0 = qi * bq
     if offset:
@@ -139,6 +146,8 @@ def _fwd_kernel(*refs, sm_scale, causal, bq, bk, nk, offset=False, window=None):
             keep = rows >= cols
             if window is not None:
                 keep = keep & (rows - cols < window) & (cols >= k0_ref[0])
+            if select:
+                keep = keep & (sc_ref[...] >= th_ref[:, :1])
             s = jnp.where(keep, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -185,6 +194,7 @@ def flash_block_fwd(
     window: int | None = None,
     k_first: jax.Array | int = 0,
     group: int = 1,
+    select: Tuple[jax.Array, jax.Array] | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """One attention block: returns ``(o, lse)`` with o float32-normalized.
 
@@ -201,10 +211,17 @@ def flash_block_fwd(
     before it stale rows of a ring not yet filled.  With ``group`` > 1 k
     and v hold ``BH // group`` heads and query head ``b`` reads head ``b //
     group`` of them where it lies: grouped-query attention without a copy of
-    the keys at the query heads' count.
+    the keys at the query heads' count.  ``select = (scores [Tq, Tk] float32,
+    thresholds [Tq] float32)`` (causal only) is a key selection shared by
+    every head: a row admits only the keys scored at or above its threshold
+    (the lightning indexer's, :func:`dsa_index`).  The grid then runs the heads
+    inside each query tile, so that a tile's scores are fetched once for all
+    heads and not once a head.
     """
     if window is not None and not causal:
         raise ValueError("a window bounds a causal block")
+    if select is not None and not causal:
+        raise ValueError("a key selection masks a causal block")
     if interpret is None:
         interpret = pallas_interpret()
     BH, Tq, d = q.shape
@@ -218,25 +235,46 @@ def flash_block_fwd(
     scalars = [q_offset] if offset else []
     if window is not None:
         scalars.append(k_first)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset,
-        window=window,
-    )
+    if select is None:
+        kernel = functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset,
+            window=window,
+        )
+        grid, selected, at = (BH, nq, nk), [], lambda index: index  # noqa: E731
+    else:
+        kernel = functools.partial(
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk, nk=nk, offset=offset,
+            window=window, select=True,
+        )
+        # (query tile, head, key tile): a head's turn keeps the tile's scores.
+        grid = (nq, BH, nk)
+
+        def at(index):
+            return lambda i, b, j, *_: index(b, i, j)
+
+        scores, thresholds = select
+        selected = [scores.astype(jnp.float32),
+                    jnp.broadcast_to(thresholds.astype(jnp.float32)[:, None], (Tq, 128))]
     if group == 1:
         kv_at = lambda b, i, j, *_: (b, j, 0)  # noqa: E731
     else:
         kv_at = lambda b, i, j, *_: (b // group, j, 0)  # noqa: E731
+    select_specs = [
+        pl.BlockSpec((bq, bk), at(lambda b, i, j, *_: (i, j))),
+        pl.BlockSpec((bq, 128), at(lambda b, i, j, *_: (i, 0))),
+    ] if selected else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(BH, nq, nk),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_at),
-            pl.BlockSpec((1, bk, dv), kv_at),
+            pl.BlockSpec((1, bq, d), at(lambda b, i, j, *_: (b, i, 0))),
+            pl.BlockSpec((1, bk, d), at(kv_at)),
+            pl.BlockSpec((1, bk, dv), at(kv_at)),
+            *select_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), at(lambda b, i, j, *_: (b, i, 0))),
+            pl.BlockSpec((1, bq, 128), at(lambda b, i, j, *_: (b, i, 0))),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, dv), jnp.float32),
@@ -253,8 +291,63 @@ def flash_block_fwd(
         ],
         interpret=interpret,
         name=name,
-    )(*(jnp.reshape(x, (1,)).astype(jnp.int32) for x in scalars), q, k, v)
+    )(*(jnp.reshape(x, (1,)).astype(jnp.int32) for x in scalars), q, k, v, *selected)
     return o, lse_pad[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# The lightning indexer's scores: q[C,H,d], w[C,H] x k[N,d] -> I[C,N] f32
+# ---------------------------------------------------------------------------
+
+
+def _dsa_index_kernel(q_ref, w_ref, k_ref, o_ref, *, heads):
+    # One (row block, key block): every head's dot products in ONE product,
+    # [rows x heads, d] x [keys, d]^T, then ReLU, the head's weight and the sum
+    # over heads, all in VMEM.
+    s = lax.dot_general(
+        q_ref[...], k_ref[...], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    s = jnp.maximum(s, 0.0) * w_ref[...]
+    o_ref[...] = jnp.sum(s.reshape(-1, heads, s.shape[-1]), axis=1)
+
+
+def dsa_index(
+    q: jax.Array,
+    w: jax.Array,
+    k: jax.Array,
+    *,
+    block_rows: int = 16,
+    block_keys: int = 512,
+    interpret: bool | None = None,
+    name: str = "dsa_index",
+) -> jax.Array:
+    """The lightning indexer's scores of ``C`` query rows against ``N`` keys:
+    ``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])``, float32.
+
+    ``q [C, H, d]`` and ``k [N, d]`` at the compute dtype, ``w [C, H]`` float32
+    (the head weights with their scales in them).  A grid step multiplies
+    ``block_rows`` rows' ``H`` heads against ``block_keys`` keys at once; the
+    ``[rows, H, keys]`` intermediate and the ReLU stay in VMEM and only the
+    head sum leaves.  No mask: the caller masks what a row may not see."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    C, H, d = q.shape
+    N = k.shape[0]
+    br = next((b for b in range(min(block_rows, C), 0, -1) if C % b == 0 and b % 8 == 0), C)
+    bk = _pick_block(N, block_keys)
+    return pl.pallas_call(
+        functools.partial(_dsa_index_kernel, heads=H),
+        grid=(C // br, N // bk),
+        in_specs=[
+            pl.BlockSpec((br * H, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((br * H, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
+        ],
+        out_specs=pl.BlockSpec((br, bk), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((C, N), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )(q.reshape(C * H, d), w.astype(jnp.float32).reshape(C * H, 1), k)
 
 
 # ---------------------------------------------------------------------------

@@ -645,7 +645,7 @@ class ServingEngine:
             cfg, live_ends, self._table_width, self.block_size, self.kv_quantize
         )
         # Routed experts: each program returns what it routed in the call
-        # beside its result (``experts.COUNT_NAMES``).  The scheduler keeps
+        # beside its result (``latent_moe.count_names``).  The scheduler keeps
         # the counts of the calls it has dispatched and fetches them in the
         # blocking read it makes anyway (``_host_read``).
         self._moe = bool(cfg.n_routed_experts)
@@ -656,10 +656,20 @@ class ServingEngine:
         #: layers), rows held, experts that had a row]``.
         self._moe_shapes: Dict[int, List[int]] = {}
         if self._moe:
-            from polyaxon_tpu.parallel.experts import COUNT_NAMES
+            from polyaxon_tpu.models.latent_moe import count_names
 
-            self._moe_totals = dict.fromkeys(COUNT_NAMES, 0)
+            self._moe_totals = dict.fromkeys(count_names(cfg), 0)
             self._moe_layers = self._stack.expert_layers(cfg)
+        #: A latent model with a lightning indexer: (query, key) pairs scored
+        #: and keys selected, summed over layers, chunks and steps, and by the
+        #: rows of a chunk's shape (the ``dsa_index_<rows>`` kernel's)
+        #: ``[the kernel's calls (one a layer a key tile of the chunk's walk),
+        #: the pairs it scored in them, the keys it read]``; counted on the
+        #: host from each call's start, length and positions.
+        self._dsa_layers = cfg.n_layers if cfg.stack == "latent" and cfg.index_n_heads else 0
+        self._tile_keys = decode._tile_blocks(self._table_width, self.block_size) * self.block_size
+        self._dsa_pairs = [0, 0]
+        self._dsa_shapes: Dict[int, List[int]] = {}
         self._state_row_bytes = 0
         if self._recurrent:
             # The per-slot rows ride the pool dict: the paged programs
@@ -911,6 +921,13 @@ class ServingEngine:
         ):
             if asked and option in refused:
                 raise self._stack.refusal(option)
+
+    def _note_dsa(self, first: int, count: int, scored: int) -> None:
+        """The indexer's pairs for the queries at ``[first, first + count)``,
+        ``scored`` of them in one layer (under ``_stats_lock``)."""
+        self._dsa_pairs[0] += self._dsa_layers * scored
+        self._dsa_pairs[1] += self._dsa_layers * self._stack.selected_keys(
+            self.cfg, first, count)
 
     def _note_counts(self, tokens: int, counts: Sequence[Any]) -> None:
         """Keep what a dispatched program routed (a device array, not read
@@ -1706,6 +1723,15 @@ class ServingEngine:
                  }}
                 if self._window_layers else {}
             )
+            dsa = (
+                {"dsa_index_pairs": self._dsa_pairs[0],
+                 "dsa_selected_pairs": self._dsa_pairs[1],
+                 "dsa_index_call_shapes": {
+                     str(rows): dict(zip(("calls", "pairs", "keys"), v))
+                     for rows, v in sorted(self._dsa_shapes.items())
+                 }}
+                if self._dsa_layers else {}
+            )
             moe = dict(self._moe_totals)
             if self._moe:
                 moe["moe_call_shapes"] = {
@@ -1784,6 +1810,13 @@ class ServingEngine:
             # window layer a chunk; ``window_call_shapes`` the same by the rows
             # of a chunk's shape, which the kernel's name ends in.
             **window,
+            # A latent model with a lightning indexer (absent for one without):
+            # the (query, key) pairs its indexer scored (every key a query may
+            # see) and the keys its queries attended (min(t + 1, index_topk)),
+            # each over layers, chunks and steps; ``dsa_index_call_shapes`` by
+            # the rows of a chunk's shape, the ``dsa_index`` kernel's calls
+            # with the pairs scored and the keys read in them.
+            **dsa,
             "requests_cancelled": cancelled,
             # Recurrent state (all 0 for a dense model): snapshots taken,
             # snapshots restored into a slot, snapshots lost (the store's
@@ -2354,6 +2387,14 @@ class ServingEngine:
                 shape[1] += self._window_layers * self._stack.chunk_window_pairs(
                     self.cfg, job.next_pos - n, n
                 )
+            if self._dsa_layers:
+                scored = self._stack.scored_keys(job.next_pos - n, n)
+                self._note_dsa(job.next_pos - n, n, scored)
+                keys = self._chunk_keys(job.next_pos)
+                shape = self._dsa_shapes.setdefault(c_pad, [0, 0, 0])
+                shape[0] += self._dsa_layers * (keys // self._tile_keys)
+                shape[1] += self._dsa_layers * scored
+                shape[2] += self._dsa_layers * keys
         if self._snaps is not None and job.next_pos % self._snap_every == 0:
             self._snapshot_state(slot, job.next_pos)
         done = job.next_pos >= t
@@ -2661,6 +2702,9 @@ class ServingEngine:
         with self._stats_lock:
             self._n_step_keys_attended += self._step_keys(self._pos[self._active] + 1)
             self._n_step_keys_table += n_live * self._table_width * bs
+            if self._dsa_layers:
+                for p in self._pos[self._active]:
+                    self._note_dsa(int(p), 1, int(p) + 1)
         clock.lap(LAP_KEY)
         key = self._draw_key()
         emitted = 0

@@ -229,7 +229,7 @@ def test_a_chunk_walking_its_table_by_tiles_is_the_plain_rule_over_the_whole_tab
 
     @jax.jit
     def plain(h, pool):
-        q_nope, q_rope = latent_moe._queries(h, layer, qpos[None], cfg)
+        q_nope, q_rope = latent_moe._queries_of(latent_moe._q_latent(h, layer), layer, qpos[None], cfg)
         row = latent_moe._latent_row(h, layer, qpos[None], cfg)
         pool = decode._latent_append(pool, li, row[0], write_blk, write_off)
         rows = decode._latent_gather(pool, li, table, h.dtype, row.shape[-1])[None]

@@ -123,7 +123,7 @@ def _mixers(form, cfg, layer):
     def plain(h, pool, tables, pos, active):
         pos, write_blk, write_off = addresses(tables, pos, active)
         if form == "latent":
-            q_nope, q_rope = latent_moe._queries(h, layer, pos[:, None], cfg)
+            q_nope, q_rope = latent_moe._queries_of(latent_moe._q_latent(h, layer), layer, pos[:, None], cfg)
             row = latent_moe._latent_row(h, layer, pos[:, None], cfg)
             pool = decode._latent_append(pool, li, row[:, 0], write_blk, write_off)
             rows = decode._latent_gather(pool, li, tables, h.dtype, row.shape[-1])

@@ -86,3 +86,32 @@ def test_the_step_compiles_for_the_chip_and_leaves_the_pool_where_it_is(
     # the donated pool is the one that leaves, and no temporary is as large as one leaf of it
     assert memory.alias_size_in_bytes >= sum(leaf.size * leaf.dtype.itemsize for leaf in kv)
     assert memory.temp_size_in_bytes < smallest // 2, memory
+
+
+def test_the_indexers_kernel_and_the_selecting_flash_kernel_compile_for_the_chip(
+        one_chip, monkeypatch):
+    """DeepSeek-V3.2's widths (``benchmark/configs/deepseek-v3.2-serve.json``):
+    the indexer's 64 heads of 128 over a 1,024-row chunk and a 1,024-key tile,
+    and the flash kernel over 128 heads of 192 / 128 masked by the tile's
+    scores and the rows' thresholds, each taken by the chip's compiler."""
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    C, H, d, N = 1024, 64, 128, 1024
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    index = jax.jit(partial(flash.dsa_index, name=f"dsa_index_{C}")).lower(
+        sds((C, H, d), jnp.bfloat16), sds((C, H), jnp.float32), sds((N, d), jnp.bfloat16)
+    ).compile()
+    assert "tpu_custom_call" in index.as_text()
+
+    def masked(q, k, v, scores, thresholds):
+        return flash.flash_block_fwd(q, k, v, causal=True, sm_scale=0.1, q_offset=jnp.int32(0),
+                                     name="dsa_chunk_tile", select=(scores, thresholds),
+                                     block_q=512)
+
+    tile = jax.jit(masked).lower(
+        sds((128, C, 192), jnp.bfloat16), sds((128, N, 192), jnp.bfloat16),
+        sds((128, N, 128), jnp.bfloat16), sds((C, N), jnp.float32), sds((C,), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" in tile.as_text()

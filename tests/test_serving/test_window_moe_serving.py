@@ -22,7 +22,7 @@ import pytest
 
 from benchmark.reference import window_moe_decoder as ref
 from polyaxon_tpu.models import TransformerConfig, decode, init_params, latent_moe, window_moe
-from polyaxon_tpu.models.transformer import forward
+from polyaxon_tpu.models.transformer import forward, rotate
 from polyaxon_tpu.models.window_moe import FULL, WINDOW, WindowStackError
 from polyaxon_tpu.parallel import experts
 from polyaxon_tpu.serving import ServingEngine
@@ -256,7 +256,7 @@ def test_yarn_frequencies_and_the_partial_rotation_are_the_references(size):
         assert inv.shape == (int(d * groups[kind]["partial_rotary_factor"]) // 2,)
         np.testing.assert_allclose(inv, want_inv, rtol=1e-6)
         assert factor == want_factor
-        got = window_moe._rotate(x[None], pos[None], (inv, factor))[0]
+        got = rotate(x[None], pos[None], (inv, factor))[0]
         want = ref._rope(x, pos, want_inv, want_factor)
         # angles of up to 16,384 radians in float32: 1e-3 of rounding between the two tables
         assert float(jnp.max(jnp.abs(got - want))) < 5e-3
