@@ -556,22 +556,19 @@ def _run_stack(x, blk, pool, cfg, attend, valid):
     for kind, n in runs(cfg):
         name = "dense" if kind == DENSE else "experts"
         lo = first[kind]
-        # The routed experts' stacks stay out of the scanned inputs: the loop
-        # would cut a layer's copy out of them for every iteration.
+        # Every stack stays whole, closed over by the body, which reads its
+        # layer at ``li`` / ``ki``: a stack cut to the run before the loop is
+        # a copy of the run's layers written in every call.  The routed
+        # experts' stacks go whole to the expert kernel, which reads at ``ki``.
         stacks = {w: blk[name][w] for w in ("wi", "wg", "wd")} if kind == EXPERTS else {}
-        xs = (
-            jax.tree.map(lambda w: w[layer0 : layer0 + n], shared),
-            jax.tree.map(
-                lambda w: w[lo : lo + n],
-                {w: v for w, v in blk[name].items() if w not in stacks},
-            ),
-            layer0 + jnp.arange(n),
-            lo + jnp.arange(n),
-        )
+        mlps = {w: v for w, v in blk[name].items() if w not in stacks}
+        xs = (layer0 + jnp.arange(n), lo + jnp.arange(n))
 
-        def body(carry, inputs, kind=kind, stacks=stacks):
+        def body(carry, inputs, kind=kind, stacks=stacks, mlps=mlps):
             x, pool = carry
-            layer, mlp, li, ki = inputs
+            li, ki = inputs
+            layer = jax.tree.map(lambda w: w[li], shared)
+            mlp = jax.tree.map(lambda w: w[ki], mlps)
             mix, pool, *ties = attend(_rmsnorm(x, layer["attn_norm"]), layer, li, pool)
             x = x + mix
             h = _rmsnorm(x, layer["mlp_norm"])

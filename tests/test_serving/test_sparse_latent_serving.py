@@ -3,8 +3,9 @@ experts and YaRN through the paged programs and the engine: logits against the
 plain reference past ``index_topk``, the selection switched off against the
 same reference, the indexer's kernel and the selecting flash kernel against
 the plain rule, the exact radix select, the group limit against a loop over
-groups, the chip's share against the uncut layer, the counters, and the int8
-pool refused.
+groups, the chip's share against the uncut layer, the counters, the int8 pool
+refused, and the layer loop taking the stacked weights whole (this model's and
+JoyAI-LLM-Flash's toy).
 
 Sizes are tiny and compute is float32 on seeded random weights, so the program
 and the reference (``benchmark/reference/sparse_latent_decoder.py``: float32,
@@ -27,6 +28,7 @@ from polyaxon_tpu.models.latent_moe import LatentStackError
 from polyaxon_tpu.models.transformer import yarn_inv_freq
 from polyaxon_tpu.parallel import experts, flash
 from polyaxon_tpu.serving import ServingEngine
+from tests.test_serving.test_latent_window_programs_unchanged import JOYAI
 
 LOGIT_TOL = 5e-5
 TOPK = 12
@@ -399,3 +401,53 @@ def test_an_int8_pool_is_refused_by_name(tiny):
     with pytest.raises(LatentStackError) as err:
         ServingEngine(params, cfg, slots=2, block_size=BS, kv_quantize="int8", warmup=False)
     assert err.value.option == "kv_quantize" and "ik" in str(err.value)
+
+
+def _int8_pairs(w, axes):
+    """``decode.quantize_weights``'s pair for ``w``, as shapes."""
+    scale = tuple(1 if i in axes else d for i, d in enumerate(w.shape))
+    return jax.ShapeDtypeStruct(w.shape, jnp.int8), jax.ShapeDtypeStruct(scale, jnp.float32)
+
+
+@pytest.mark.parametrize("program", ["chunk", "step", "step-int8"])
+@pytest.mark.parametrize("model", ["tiny", "joyai-toy"])
+def test_every_stacked_block_weight_enters_the_layer_loop_whole(model, program):
+    """Each scan of the layer loop closes over the block's stacks as they are
+    and reads its layer inside; what it scans over is the layer indices alone.
+    A stack cut to a run of the pattern before the loop is a copy of it written
+    in every call (``wo`` of DeepSeek-V3.2's four expert layers is 939.5 MB).  Both models run a dense layer, then a run of two expert layers."""
+    cfg = make_cfg(TINY) if model == "tiny" else JOYAI[0]
+    assert latent_moe.runs(cfg) == [("dense_mlp", 1), ("expert_mlp", 2)]
+    i32, sds = jnp.int32, jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    qweights = latent_moe.quantize_weights(params, _int8_pairs) if program == "step-int8" else None
+    pool = jax.eval_shape(lambda: decode.init_block_pool(cfg, 1 + SLOTS * W, BS))
+    if program == "chunk":
+        def fn(params, pool, table, tokens, start, length, qweights):
+            return decode.paged_prefill_chunk(params, pool, table, tokens, start, length, cfg)
+
+        args = (sds((W,), i32), sds((16,), i32), sds((), i32), sds((), i32))
+    else:
+        def fn(params, pool, tables, tokens, pos, active, qweights):
+            return decode.paged_decode_step(params, pool, tables, tokens, pos, active, cfg,
+                                            qweights=qweights)
+
+        lanes = sds((SLOTS,), i32)
+        args = (sds((SLOTS, W), i32), lanes, lanes, sds((SLOTS,), jnp.bool_))
+    inputs = (params, pool, *args, qweights)
+    closed = jax.make_jaxpr(fn)(*inputs)
+    paths = [path for path, _ in jax.tree_util.tree_flatten_with_path(inputs)[0]]
+    block = {id(v) for v, path in zip(closed.jaxpr.invars, paths)
+             if path[0].idx in (0, len(inputs) - 1) and path[1].key == "block"}
+    assert len(block) == len(jax.tree.leaves(params["block"])) + len(
+        jax.tree.leaves(qweights["block"]) if qweights else ())
+    scans = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 2
+    for eqn in scans:  # the layer indices, global and within the kind
+        xs = eqn.invars[eqn.params["num_consts"] + eqn.params["num_carry"]:]
+        assert [(v.aval.ndim, v.aval.dtype) for v in xs] == [(1, jnp.int32)] * 2, xs
+    for eqn in closed.jaxpr.eqns:
+        read = [v for v in eqn.invars if id(v) in block]
+        if read:
+            assert eqn.primitive.name == "scan", (eqn.primitive, [v.aval for v in read])
+            assert {id(v) for v in read} <= {id(v) for v in eqn.invars[: eqn.params["num_consts"]]}
